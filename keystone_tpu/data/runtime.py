@@ -7,7 +7,7 @@ snapshot writes ran synchronously ON the fold loop
 (``data/durable.py`` — the fold stalled for the fsync of a ~1.2 GB
 carry at Amazon geometry), and the serving worker rolled its own
 thread. The measured cost is the gap between the Amazon fold floor
-(131.4 s of pure device time, ``BENCH_FULL_r05.json``) and the 223.8 s
+(131.4 s of pure device time, round-5 chip record — ROADMAP S2) and the 223.8 s
 measured wall: ~40% of the row is IO that never overlaps compute.
 
 This module centralizes the discipline instead of the threads' code:

@@ -2,22 +2,29 @@
 tier, loaded there via System.loadLibrary — utils/external/VLFeat.scala:4).
 
 The C++ sources here are built on demand with g++ into a shared library inside
-the package directory and bound via ctypes. Everything degrades gracefully:
-if no compiler is available the pure-NumPy/PIL paths are used instead, so the
-library never hard-fails at import.
+the package directory and bound via ctypes. The build is keyed on the
+sources' CONTENT: a SHA-256 of the sources is recorded beside the library and
+a library whose recorded digest differs (or is missing) is rebuilt — file
+times mean nothing in a copied checkout. With no compiler the pure-NumPy/PIL
+paths serve instead, and :func:`status` says which side is serving.
 """
 
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import logging
 import os
 import subprocess
 from typing import Optional, Tuple
 
 import numpy as np
 
+logger = logging.getLogger(__name__)
+
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _LIB_PATH = os.path.join(_DIR, "libkeystone_native.so")
+_DIGEST_PATH = _LIB_PATH + ".sha256"
 _SOURCES = [
     os.path.join(_DIR, "csv_loader.cpp"),
     os.path.join(_DIR, "data_plane.cpp"),
@@ -27,13 +34,48 @@ _lib: Optional[ctypes.CDLL] = None
 _tried = False
 
 
-def _build() -> bool:
-    cmd = ["g++", "-O3", "-shared", "-fPIC", "-pthread", "-o", _LIB_PATH] + _SOURCES
+def _sources_digest() -> str:
+    h = hashlib.sha256()
+    for src in _SOURCES:
+        with open(src, "rb") as f:
+            h.update(f.read())
+    return h.hexdigest()
+
+
+def _recorded_digest() -> Optional[str]:
+    try:
+        with open(_DIGEST_PATH) as f:
+            return f.read().strip()
+    except FileNotFoundError:
+        return None
+
+
+def _build(digest: str) -> bool:
+    """Compile to a private name, then rename into place and record the
+    digest — concurrent first users (fleet planes, prefetch readers) each
+    publish a complete library, never a half-written one."""
+    tmp = f"{_LIB_PATH}.{os.getpid()}.tmp"
+    cmd = ["g++", "-O3", "-shared", "-fPIC", "-pthread", "-o", tmp] + _SOURCES
     try:
         res = subprocess.run(cmd, capture_output=True, timeout=120)
-        return res.returncode == 0
-    except (OSError, subprocess.TimeoutExpired):
+    except (OSError, subprocess.TimeoutExpired) as e:
+        logger.warning("native data plane not built (%s)", e)
         return False
+    if res.returncode != 0:
+        logger.warning(
+            "native data plane not built (g++ exit %d): %s",
+            res.returncode, res.stderr.decode("utf-8", "replace")[-500:],
+        )
+        return False
+    os.replace(tmp, _LIB_PATH)
+    with open(_DIGEST_PATH, "w") as f:
+        f.write(digest + "\n")
+    return True
+
+
+def status() -> str:
+    """``"native"`` when the C++ data plane is serving, else ``"numpy"``."""
+    return "native" if get_lib() is not None else "numpy"
 
 
 def get_lib() -> Optional[ctypes.CDLL]:
@@ -43,9 +85,9 @@ def get_lib() -> Optional[ctypes.CDLL]:
         return _lib
     _tried = True
     try:
-        newest_src = max(os.path.getmtime(s) for s in _SOURCES)
-        if not os.path.exists(_LIB_PATH) or os.path.getmtime(_LIB_PATH) < newest_src:
-            if not _build():
+        digest = _sources_digest()
+        if not os.path.exists(_LIB_PATH) or _recorded_digest() != digest:
+            if not _build(digest):
                 return None
         lib = ctypes.CDLL(_LIB_PATH)
         lib.ks_parse_csv.restype = ctypes.c_long
@@ -79,21 +121,18 @@ def get_lib() -> Optional[ctypes.CDLL]:
             ctypes.POINTER(ctypes.c_long),
             ctypes.POINTER(ctypes.c_long),
         ]
-        # Bindings for symbols that may be absent from a stale .so are
-        # guarded so get_lib keeps its degrade-gracefully contract.
-        if hasattr(lib, "ks_decode_pnm_many"):
-            lib.ks_decode_pnm_many.restype = None
-            lib.ks_decode_pnm_many.argtypes = [
-                ctypes.POINTER(ctypes.c_char_p),
-                ctypes.POINTER(ctypes.c_long),
-                ctypes.c_long,
-                ctypes.POINTER(ctypes.POINTER(ctypes.c_float)),
-                ctypes.POINTER(ctypes.c_long),
-                ctypes.POINTER(ctypes.c_long),
-                ctypes.POINTER(ctypes.c_long),
-                ctypes.POINTER(ctypes.c_long),
-                ctypes.POINTER(ctypes.c_long),
-            ]
+        lib.ks_decode_pnm_many.restype = None
+        lib.ks_decode_pnm_many.argtypes = [
+            ctypes.POINTER(ctypes.c_char_p),
+            ctypes.POINTER(ctypes.c_long),
+            ctypes.c_long,
+            ctypes.POINTER(ctypes.POINTER(ctypes.c_float)),
+            ctypes.POINTER(ctypes.c_long),
+            ctypes.POINTER(ctypes.c_long),
+            ctypes.POINTER(ctypes.c_long),
+            ctypes.POINTER(ctypes.c_long),
+            ctypes.POINTER(ctypes.c_long),
+        ]
         lib.ks_decode_pnm.restype = ctypes.c_int
         lib.ks_decode_pnm.argtypes = [
             ctypes.c_char_p,
@@ -105,7 +144,8 @@ def get_lib() -> Optional[ctypes.CDLL]:
             ctypes.POINTER(ctypes.c_long),
         ]
         _lib = lib
-    except OSError:
+    except OSError as e:
+        logger.warning("native data plane not loaded (%s)", e)
         _lib = None
     return _lib
 
@@ -252,8 +292,6 @@ def decode_pnm_many(datas) -> Optional[list]:
     failed to decode), or None when the native library is unavailable."""
     lib = get_lib()
     if lib is None:
-        return None
-    if not hasattr(lib, "ks_decode_pnm_many"):
         return None
     n = len(datas)
     if n == 0:

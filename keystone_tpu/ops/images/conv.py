@@ -142,17 +142,9 @@ class Convolver(Transformer):
         # einsum's preferred_element_type alone would otherwise leave the
         # patch normalization running in f64 on the eager path.
         images = jnp.asarray(images, jnp.float32)
-        from keystone_tpu.ops import pallas_images
-
-        if pallas_images.conv_featurize_ok(images, self.filters):
-            return pallas_images.conv_featurize(
-                images,
-                self.filters,
-                self.whitener.means if self.whitener is not None else None,
-                patch_size=self.patch_size,
-                normalize_patches=self.normalize_patches,
-                var_constant=self.var_constant,
-            )
+        # The XLA path is the stated path: Mosaic refuses the fused Pallas
+        # form (ops/pallas_images.py — chip run, PR 21), so it is not
+        # dispatched from here.
         patches = im2col(images, self.patch_size)
         if self.normalize_patches:
             patches = normalize_patch_rows(patches, self.var_constant)

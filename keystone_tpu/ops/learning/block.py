@@ -14,14 +14,13 @@ from __future__ import annotations
 
 from typing import List, Optional, Sequence
 
-import jax
 import jax.numpy as jnp
-import numpy as np
 
 from keystone_tpu.data import Dataset
 from keystone_tpu.ops.stats import StandardScaler
 from keystone_tpu.ops.util import VectorSplitter
 from keystone_tpu.parallel import linalg
+from keystone_tpu.utils.startup import device_memory_limit
 from keystone_tpu.workflow import LabelEstimator, Transformer
 
 
@@ -133,24 +132,15 @@ def _stack_fits_memory(A_blocks, num_iter: int) -> bool:
     memory. At stack time up to THREE full-size copies of the feature blocks
     are live (the unscaled splits, the scaled list, and the stack), plus the
     multi-epoch Gramian stash (nb * d_b^2)."""
-    try:
-        sizes = [
-            int(a.nbytes) if hasattr(a, "nbytes") else int(np.asarray(a).nbytes)
-            for a in A_blocks
-        ]
-        total = sum(sizes)
-        stash = 0
-        if num_iter > 1 and A_blocks:
-            d_b = int(A_blocks[0].shape[1])
-            itemsize = getattr(A_blocks[0], "dtype", np.dtype(np.float32)).itemsize
-            stash = len(A_blocks) * d_b * d_b * max(int(itemsize), 4)
-        stats = jax.local_devices()[0].memory_stats() or {}
-        limit = stats.get("bytes_limit") or stats.get("bytes_reservable_limit")
-        if not limit:
-            return True  # backends without memory stats (CPU): no constraint
-        return 3 * total + stash < 0.6 * int(limit)
-    except Exception:
-        return True
+    limit = device_memory_limit()
+    if limit is None:
+        return True  # CPU test mesh reports no memory limit: no constraint
+    total = sum(int(a.nbytes) for a in A_blocks)
+    stash = 0
+    if num_iter > 1 and A_blocks:
+        d_b = int(A_blocks[0].shape[1])
+        stash = len(A_blocks) * d_b * d_b * max(A_blocks[0].dtype.itemsize, 4)
+    return 3 * total + stash < 0.6 * limit
 
 
 class BlockLeastSquaresEstimator(LabelEstimator):

@@ -1,4 +1,19 @@
-"""Pallas TPU kernels for the image-geometry pipeline's hot path.
+"""Pallas TPU kernel for the image-geometry pipeline's hot path — NOT
+DISPATCHED: Mosaic refuses it, so ``Convolver`` runs the XLA path.
+
+On a TPU v5e (jax 0.9.0 / libtpu 0.0.34, PR 21's chip run) at CIFAR
+geometry (32x32x3, 6x6 patches, 256 filters) the compiler answers
+``RESOURCE_EXHAUSTED: ... Scoped allocation with size 88.62M and limit
+16.00M exceeded scoped vmem limit by 72.62M``; with
+``vmem_limit_bytes`` raised to 100 MB (128 filters, two images) the
+compilation had not finished after 470 s. The likely cause is the layout,
+not a tile size: with channels last a (27, 27, 3) window puts 3 values in each
+128-lane register row, every one of the 36 window slices, the
+``(27, 27, 3) -> (729, 3)`` reshape and the 36-way lane concatenation is
+a relayout of ~97% padding. A version that can compile needs lane-dense
+patches (channels folded into the row axis before the kernel, or a
+strip-mined im2col over W·C) — a rewrite, ROADMAP S7. The kernel and its
+interpreter-equality tests stay as the reference for that rewrite.
 
 The reference's image featurizer is im2col into a reused patch-matrix
 buffer followed by one BLAS-3 GEMM per image (nodes/images/
@@ -42,23 +57,12 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from keystone_tpu.ops.pallas_ops import (
-    _COMPILER_PARAMS,  # noqa: F401  (re-exported for symmetry with pallas_ops)
-    _dot_kwargs,
-    _interpret,
-    pallas_direct_ok,
-)
+from keystone_tpu.ops.pallas_ops import _dot_kwargs, _pallas_call
 
 __all__ = [
     "conv_featurize",
     "conv_featurize_flops",
-    "conv_featurize_ok",
 ]
-
-# One image block + its patch matrix + the output tile must fit VMEM
-# (~16 MB/core) alongside the filter matrix. Past this budget the caller
-# should stay on the XLA path (which tiles freely through HBM).
-_VMEM_BUDGET_BYTES = 8 * 1024 * 1024
 
 
 def _conv_featurize_kernel(
@@ -97,24 +101,6 @@ def conv_featurize_flops(n: int, xo: int, yo: int, d: int, k: int) -> float:
     return 2.0 * n * xo * yo * d * k
 
 
-def conv_featurize_ok(images, filters) -> bool:
-    """True when the fused kernel may be dispatched directly on these
-    eager operands: Pallas on, operands unsharded, batch-of-images rank,
-    and the per-image working set within the VMEM budget."""
-    if not pallas_direct_ok(images, filters):
-        return False
-    if getattr(images, "ndim", 0) != 4:
-        return False
-    n, X, Y, C = images.shape
-    k, d = filters.shape
-    p = int(round((d / C) ** 0.5))
-    xo, yo = X - p + 1, Y - p + 1
-    if xo <= 0 or yo <= 0:
-        return False
-    working_set = 4 * (X * Y * C + xo * yo * d + xo * yo * k + d * k)
-    return working_set <= _VMEM_BUDGET_BYTES
-
-
 def conv_featurize(
     images,
     filters,
@@ -144,7 +130,8 @@ def conv_featurize(
     else:
         mn = jnp.asarray(means, dtype=jnp.float32).reshape(1, d)
 
-    return pl.pallas_call(
+    return _pallas_call(
+        "conv_featurize",
         functools.partial(
             _conv_featurize_kernel,
             patch_size=patch_size,
@@ -162,5 +149,5 @@ def conv_featurize(
         ],
         out_specs=pl.BlockSpec((1, xo, yo, k), lambda i: (i, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n, xo, yo, k), jnp.float32),
-        interpret=_interpret() if interpret is None else interpret,
+        interpret=interpret,
     )(images, ft, mn)

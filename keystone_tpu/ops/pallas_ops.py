@@ -20,15 +20,21 @@ cast before hitting the MXU while the accumulator and epilogue stay float32
 (preferred_element_type) — the standard TPU mixed-precision recipe.
 
 Wrappers pad inputs to tile multiples (zero rows/cols are exact for the dot
-contractions) and slice the result; `interpret=True` is used automatically on
-non-TPU backends so the same code paths are unit-testable on CPU.
+contractions) and slice the result. On the TPU backend every kernel is
+compiled by Mosaic unless a caller passes ``interpret=True``; off it the
+Pallas interpreter runs them (logged once at WARNING), so the same code
+paths are unit-testable on CPU. Every ``pallas_call`` goes through
+:func:`_pallas_call`, which names the kernel in the lowered program and
+reports the dispatch to :func:`record_dispatches` listeners.
 """
 
 from __future__ import annotations
 
+import contextlib
 import functools
+import logging
 import os
-from typing import Optional
+from typing import Iterator, List, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -37,6 +43,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 __all__ = [
+    "record_dispatches",
     "countsketch_scatter",
     "gaussian_kernel_block",
     "gaussian_resid_block",
@@ -53,16 +60,54 @@ _TILE_M = 256
 _TILE_N = 256
 _TILE_K = 512
 
-# jax >= 0.6 renamed TPUCompilerParams -> CompilerParams; support both so
-# the interpreter-mode tests run on either (the dev container pins the
-# older spelling, the TPU host the newer).
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) or getattr(
-    pltpu, "TPUCompilerParams"
-)
+logger = logging.getLogger(__name__)
+
+
+@functools.cache
+def _warn_interpreting(backend: str) -> None:
+    logger.warning(
+        "Pallas kernels are running in the INTERPRETER (backend %r is not "
+        "tpu): results are for correctness only, never a device timing",
+        backend,
+    )
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    _warn_interpreting(backend)
+    return True
+
+
+# Lists handed out by record_dispatches(); _pallas_call appends to each.
+_dispatch_logs: List[List[Tuple[str, bool]]] = []
+
+
+@contextlib.contextmanager
+def record_dispatches() -> Iterator[List[Tuple[str, bool]]]:
+    """Yield a list that receives ``(kernel_name, interpreted)`` for every
+    kernel this package dispatches while the block is open. Dispatch is a
+    TRACE-time event: a jitted caller reports its kernels when it is
+    traced, not on each cached execution — which is exactly what says
+    which side (kernel or XLA) a freshly built program took."""
+    log: List[Tuple[str, bool]] = []
+    _dispatch_logs.append(log)
+    try:
+        yield log
+    finally:
+        _dispatch_logs.remove(log)
+
+
+def _pallas_call(name: str, kernel, *, interpret: Optional[bool], **kwargs):
+    """The one ``pl.pallas_call`` site: resolves ``interpret`` (None =
+    Mosaic on TPU, interpreter elsewhere), stamps ``name`` on the custom
+    call (``kernel_name`` in the lowered text and in device traces), and
+    reports the dispatch."""
+    interpret = _interpret() if interpret is None else bool(interpret)
+    for log in _dispatch_logs:
+        log.append((name, interpret))
+    return pl.pallas_call(kernel, name=name, interpret=interpret, **kwargs)
 
 
 def _dot_kwargs(compute_dtype):
@@ -195,7 +240,8 @@ def gaussian_kernel_block(
     np_ = Yp.shape[0]
     nk = dp // tk
 
-    out = pl.pallas_call(
+    out = _pallas_call(
+        "gaussian_kernel_block",
         functools.partial(
             _gaussian_kernel_kernel,
             gamma=float(gamma),
@@ -212,7 +258,7 @@ def gaussian_kernel_block(
         out_specs=pl.BlockSpec((tm, tn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
         scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)],
-        interpret=_interpret() if interpret is None else interpret,
+        interpret=interpret,
     )(Xp, Yp, xnp, ynp)
     return out[:m, :n]
 
@@ -309,7 +355,8 @@ def gaussian_resid_block(
     np_ = Yp.shape[0]
     nk = dp // tk
 
-    out = pl.pallas_call(
+    out = _pallas_call(
+        "gaussian_resid_block",
         functools.partial(
             _gaussian_resid_kernel,
             gamma=float(gamma),
@@ -327,7 +374,7 @@ def gaussian_resid_block(
         out_specs=pl.BlockSpec((tn, tr), lambda j, i, k: (j, 0)),
         out_shape=jax.ShapeDtypeStruct((np_, tr), jnp.float32),
         scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)],
-        interpret=_interpret() if interpret is None else interpret,
+        interpret=interpret,
     )(Xp, Yp, xnp, ynp, Wp)
     return out[:n, :kdim]
 
@@ -419,7 +466,8 @@ def cosine_features(
     np_ = Wp.shape[0]
     nk = dp // tk
 
-    out = pl.pallas_call(
+    out = _pallas_call(
+        "cosine_features",
         functools.partial(_cosine_kernel, nk=nk, compute_dtype=compute_dtype),
         grid=(mp // tm, np_ // tn, nk),
         in_specs=[
@@ -430,7 +478,7 @@ def cosine_features(
         out_specs=pl.BlockSpec((tm, tn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), out_dtype),
         scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)],
-        interpret=_interpret() if interpret is None else interpret,
+        interpret=interpret,
     )(Xp, Wp, bp)
     return out[:m, :n]
 
@@ -512,7 +560,8 @@ def gram_corr(
     npad, dp = Ap.shape
     nk = npad // tk
 
-    gram, corr = pl.pallas_call(
+    gram, corr = _pallas_call(
+        "gram_corr",
         functools.partial(_gram_corr_kernel, nk=nk, compute_dtype=compute_dtype),
         grid=(dp // ti, dp // ti, nk),
         in_specs=[
@@ -532,7 +581,7 @@ def gram_corr(
             pltpu.VMEM((ti, ti), jnp.float32),
             pltpu.VMEM((ti, tr), jnp.float32),
         ],
-        interpret=_interpret() if interpret is None else interpret,
+        interpret=interpret,
     )(Ap, Ap, Rp)
     return gram[:d, :d], corr[:d, :kdim]
 
@@ -653,7 +702,8 @@ def gram_corr_sym(
             pl.BlockSpec((ti, tr), lambda p, k, ii, jj: (ii[p], 0)),
         ],
     )
-    gram_u, corr = pl.pallas_call(
+    gram_u, corr = _pallas_call(
+        "gram_corr_sym",
         functools.partial(
             _gram_corr_sym_kernel, nk=nk, compute_dtype=compute_dtype
         ),
@@ -662,7 +712,7 @@ def gram_corr_sym(
             jax.ShapeDtypeStruct((dp, dp), jnp.float32),
             jax.ShapeDtypeStruct((dp, tr), jnp.float32),
         ],
-        interpret=_interpret() if interpret is None else interpret,
+        interpret=interpret,
     )(ii, jj, Ap, Ap, Rp)
     # Mirror the (written) upper triangle; lower-triangle blocks are
     # undefined memory, so build from triu explicitly.
@@ -727,11 +777,12 @@ def block_gram_sym(F, col_start, block: int, interpret: Optional[bool] = None):
             (ti, ti), lambda p, k, ii, jj: (ii[p] - ii[0], jj[p] - ii[0])
         ),
     )
-    gram_u = pl.pallas_call(
+    gram_u = _pallas_call(
+        "block_gram_sym",
         functools.partial(_gram_sym_kernel, nk=nk, compute_dtype=compute_dtype),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((block, block), jnp.float32),
-        interpret=_interpret() if interpret is None else interpret,
+        interpret=interpret,
     )(ii, jj, F, F)
     upper = jnp.triu(gram_u)
     return upper + jnp.triu(gram_u, 1).T
@@ -806,7 +857,8 @@ def gram_sym_acc(G, F, interpret: Optional[bool] = None):
             (ti, ti), lambda p, k, ii, jj: (ii[p], jj[p])
         ),
     )
-    return pl.pallas_call(
+    return _pallas_call(
+        "gram_sym_acc",
         functools.partial(
             _gram_sym_acc_kernel, compute_dtype=compute_dtype
         ),
@@ -817,10 +869,10 @@ def gram_sym_acc(G, F, interpret: Optional[bool] = None):
         # conservative 16 MB default but well under the chip's 128 MB.
         # Raising the limit keeps the wide tiles (F is re-read (nt+1)
         # times per row tile, so halving nt halves that traffic).
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=48 * 1024 * 1024
         ),
-        interpret=_interpret() if interpret is None else interpret,
+        interpret=interpret,
     )(ii, jj, G, F, F)
 
 
@@ -932,7 +984,8 @@ def gram_corr_sym_acc(G, C, F, R, interpret: Optional[bool] = None):
             pl.BlockSpec((ti, tr), lambda p, k, ii, jj: (ii[p], 0)),
         ],
     )
-    gout, cout = pl.pallas_call(
+    gout, cout = _pallas_call(
+        "gram_corr_sym_acc",
         functools.partial(
             _gram_corr_sym_acc_kernel, compute_dtype=compute_dtype
         ),
@@ -945,10 +998,10 @@ def gram_corr_sym_acc(G, C, F, R, interpret: Optional[bool] = None):
         # ~22 MB scoped VMEM at 1024-wide bf16 tiles — past the compiler's
         # conservative 16 MB default, well under the chip's 128 MB (same
         # reasoning as gram_sym_acc, plus the ~3 MB corr ride).
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=64 * 1024 * 1024
         ),
-        interpret=_interpret() if interpret is None else interpret,
+        interpret=interpret,
     )(ii, jj, G, Cp, F, F, Rp)
     return gout, cout[:, :kdim]
 
@@ -1001,11 +1054,12 @@ def block_corr(F, col_start, block: int, R, interpret: Optional[bool] = None):
         ],
         out_specs=pl.BlockSpec((ti, tr), lambda p, k, b: (p, 0)),
     )
-    corr = pl.pallas_call(
+    corr = _pallas_call(
+        "block_corr",
         functools.partial(_block_corr_kernel, compute_dtype=compute_dtype),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((block, tr), jnp.float32),
-        interpret=_interpret() if interpret is None else interpret,
+        interpret=interpret,
     )(base, F, Rp)
     return corr[:, :kdim]
 
@@ -1060,11 +1114,12 @@ def block_residual_update(
         ],
         out_specs=pl.BlockSpec((tm, tr), lambda m, ds, b: (m, 0)),
     )
-    out = pl.pallas_call(
+    out = _pallas_call(
+        "block_residual_update",
         functools.partial(_block_resid_kernel, compute_dtype=compute_dtype),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n, tr), jnp.float32),
-        interpret=_interpret() if interpret is None else interpret,
+        interpret=interpret,
     )(base, F, Wp, Rp)
     return out[:, :kdim]
 
@@ -1153,7 +1208,8 @@ def countsketch_scatter(
     cp = idx_p.shape[0]
     nc = cp // tc
 
-    out = pl.pallas_call(
+    out = _pallas_call(
+        "countsketch_scatter",
         functools.partial(_countsketch_kernel, s=s, nc=nc),
         grid=(mp // tm, np_ // tn, nc),
         in_specs=[
@@ -1165,6 +1221,14 @@ def countsketch_scatter(
         out_specs=pl.BlockSpec((tm, tn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, np_), jnp.float32),
         scratch_shapes=[pltpu.VMEM((tm, tn), jnp.float32)],
-        interpret=_interpret() if interpret is None else interpret,
+        # At the Amazon chunk's tiles (tm=512, tn=tc=256, s=82) Mosaic
+        # asks for 18.1 MB of scoped VMEM — the unrolled s-slot densify
+        # keeps (tc, tn) temporaries live — against the 16 MB default
+        # (chip run, PR 21). Same remedy as the *_acc kernels: raise the
+        # limit, keep the tiles.
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=32 * 1024 * 1024
+        ),
+        interpret=interpret,
     )(bkt, sgn, idx_p, val_p)
     return out[:m, :d1]

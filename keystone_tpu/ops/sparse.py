@@ -253,8 +253,8 @@ def sparse_gram_stream(
 
     Returns (G, AtY, yty) at d_pad = :func:`gram_pad_dim` (slice [:d] to
     drop the padding). Traceable — call under jit. For dispatch-bounded
-    SEGMENTED folding (long chunk streams must not run as one multi-minute
-    program on hosts with dispatch watchdogs), use :func:`sparse_gram_fold`
+    SEGMENTED folding (a long chunk stream run as one multi-minute program
+    can be neither checkpointed nor cancelled), use :func:`sparse_gram_fold`
     over cid ranges and :func:`gram_finalize` once at the end.
     ``pipeline`` is the double-buffer knob of :func:`sparse_gram_fold` —
     pass False when an extra resident chunk slab would bust HBM (e.g. the

@@ -89,11 +89,9 @@ def init_distributed(
     """
     # NOTE: must not touch jax.devices()/process_count() here — querying the
     # backend initializes it, after which jax.distributed.initialize refuses
-    # to run. Check the distributed client state directly instead.
-    from jax._src import distributed as _distributed
-
-    if getattr(_distributed.global_state, "client", None) is not None:
-        return  # already initialized
+    # to run.
+    if jax.distributed.is_initialized():
+        return
     if coordinator_address is None and "JAX_COORDINATOR_ADDRESS" not in os.environ:
         return  # single-process run
     jax.distributed.initialize(
@@ -161,24 +159,11 @@ def replicate(x, mesh: Optional[Mesh] = None):
 
 
 def shard_map(f, mesh: Mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-portable ``shard_map`` entry point.
-
-    Newer JAX exposes ``jax.shard_map`` (replication checking named
-    ``check_vma``); the 0.4 line only has the experimental entry point
-    whose equivalent flag is ``check_rep``. Every shard_map program in
-    this package routes through here so one import site owns the
-    difference — call it exactly like ``jax.shard_map``.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=check_vma,
-        )
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(
+    """``jax.shard_map`` with this package's keyword order — the one
+    import site every shard_map program here routes through."""
+    return jax.shard_map(
         f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        check_rep=check_vma,
+        check_vma=check_vma,
     )
 
 

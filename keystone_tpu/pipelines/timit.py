@@ -78,6 +78,35 @@ def build_featurizer(config: TimitConfig) -> Pipeline:
     return Pipeline.gather(branches).and_then(VectorCombiner())
 
 
+def streaming_estimator(config: TimitConfig):
+    """The out-of-core tier for this configuration: the cosine bank lives
+    INSIDE the estimator, so the fit featurizes per row tile and the
+    feature matrix never materializes."""
+    import jax.numpy as jnp
+
+    from keystone_tpu.ops.learning.streaming_ls import (
+        StreamingFeaturizedLeastSquares,
+        cosine_bank_featurize,
+    )
+
+    rfs = [
+        CosineRandomFeatures(
+            NUM_INPUT_FEATURES, config.block_size, config.gamma,
+            seed=config.seed + i, cauchy=(config.rf_type == "cauchy"),
+        )
+        for i in range(config.num_cosines)
+    ]
+    bank = cosine_bank_featurize(
+        jnp.concatenate([rf.W for rf in rfs]),
+        jnp.concatenate([rf.b for rf in rfs]),
+    )
+    return StreamingFeaturizedLeastSquares(
+        bank, d_feat=config.num_cosines * config.block_size,
+        block_size=config.block_size, num_iter=config.num_epochs,
+        lam=config.lam,
+    )
+
+
 def run(config: TimitConfig):
     start = time.time()
     if config.train_data_location:
@@ -111,29 +140,7 @@ def run(config: TimitConfig):
 
     solver = "streaming" if config.streaming else config.solver
     if solver == "streaming":
-        import jax.numpy as jnp
-
-        from keystone_tpu.ops.learning.streaming_ls import (
-            StreamingFeaturizedLeastSquares,
-            cosine_bank_featurize,
-        )
-
-        rfs = [
-            CosineRandomFeatures(
-                NUM_INPUT_FEATURES, config.block_size, config.gamma,
-                seed=config.seed + i, cauchy=(config.rf_type == "cauchy"),
-            )
-            for i in range(config.num_cosines)
-        ]
-        bank = cosine_bank_featurize(
-            jnp.concatenate([rf.W for rf in rfs]),
-            jnp.concatenate([rf.b for rf in rfs]),
-        )
-        est = StreamingFeaturizedLeastSquares(
-            bank, d_feat=config.num_cosines * config.block_size,
-            block_size=config.block_size, num_iter=config.num_epochs,
-            lam=config.lam,
-        )
+        est = streaming_estimator(config)
         pipeline = est.with_data(train.data, labels).and_then(MaxClassifier())
     elif solver == "auto":
         # Cost-model-driven selection: at resident-friendly geometry this

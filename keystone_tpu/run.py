@@ -64,33 +64,12 @@ failure.
 
 from __future__ import annotations
 
+import json
 import os
 import sys
 from typing import Callable, Dict
 
-
-def _enable_compile_cache() -> None:
-    """Persistent XLA compilation cache for pipeline runs.
-
-    Example pipelines compile dozens of programs (per image scale, per
-    solver block); caching them across runs matters most on backends where
-    compilation is remote/slow. Default dir ~/.cache/keystone_tpu_xla;
-    disable with KEYSTONE_COMPILE_CACHE=0 or point it elsewhere.
-    """
-    setting = os.environ.get("KEYSTONE_COMPILE_CACHE", "")
-    if setting == "0":
-        return
-    cache_dir = setting or os.path.join(
-        os.path.expanduser("~"), ".cache", "keystone_tpu_xla"
-    )
-    try:
-        import jax
-
-        os.makedirs(cache_dir, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", cache_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except Exception:
-        pass  # cache is an optimization; never block the run on it
+from keystone_tpu.utils.startup import device_summary, enable_compile_cache
 
 
 def _mnist(argv):
@@ -154,7 +133,6 @@ def _serve(argv):
     ``--pipeline`` (MnistRandomFFT) on synthetic data first.
     """
     import argparse
-    import json
 
     parser = argparse.ArgumentParser("keystone-serve")
     parser.add_argument("--model", default="", help="FittedPipeline pickle")
@@ -433,6 +411,7 @@ def _serve(argv):
             exporter.close()
         server.close()
     summary = report.to_row_dict()
+    summary.update(device_summary())
     summary.update({
         "single_request_s": round(single_s, 6),
         "buckets": plan.buckets,
@@ -482,7 +461,19 @@ def _serve(argv):
             "breaker_state": stats.get("breaker_state"),
         })
     print(json.dumps(summary))
-    return 0
+    return _serve_exit_code(report.completed, report.num_offered)
+
+
+def _serve_exit_code(completed: int, offered: int) -> int:
+    """A server that completed nothing did not serve: exit non-zero with
+    the one-line diagnostic, whatever the books say."""
+    if completed > 0:
+        return 0
+    print(
+        f"serve: completed 0 of {offered} offered requests",
+        file=sys.stderr,
+    )
+    return 1
 
 
 def _serve_apply_plan_defaults(args, parser):
@@ -492,8 +483,6 @@ def _serve_apply_plan_defaults(args, parser):
     wins — the operator outranks the planner). Returns the provenance
     stamp the serve summary line carries, so the plane's configuration
     is auditable back to the trace it was sized from."""
-    import json
-
     from keystone_tpu.tools.plan import PLAN_ARTIFACT_KIND
 
     with open(args.from_plan) as f:
@@ -527,10 +516,8 @@ def _serve_fleet(args, fitted, plan, single_s, pool, plan_stamp):
     plane PROCESSES behind the FleetRouter's admission door, driven
     with the same open-loop Poisson storm, summarized with the fleet's
     exact books (docs/serving.md fleet section)."""
-    import json
-
     from keystone_tpu.serving import run_open_loop
-    from keystone_tpu.serving.fleet import FleetRouter
+    from keystone_tpu.serving.fleet import FleetBackendMismatch, FleetRouter
     from keystone_tpu.serving.fleet_plane import encode_plan_ship
 
     try:
@@ -541,17 +528,21 @@ def _serve_fleet(args, fitted, plan, single_s, pool, plan_stamp):
             file=sys.stderr,
         )
         return 1
-    fleet = FleetRouter(
-        ship,
-        num_planes=args.fleet,
-        replicas_per_plane=max(1, args.replicas),
-        max_outstanding=args.queue_depth,
-        restart_budget=args.restart_budget,
-        plane_cfg={
-            "max_wait_ms": args.max_wait_ms,
-            "max_queue_depth": args.queue_depth,
-        },
-    )
+    try:
+        fleet = FleetRouter(
+            ship,
+            num_planes=args.fleet,
+            replicas_per_plane=max(1, args.replicas),
+            max_outstanding=args.queue_depth,
+            restart_budget=args.restart_budget,
+            plane_cfg={
+                "max_wait_ms": args.max_wait_ms,
+                "max_queue_depth": args.queue_depth,
+            },
+        )
+    except FleetBackendMismatch as e:
+        print(f"serve: fleet boot failed: {e}", file=sys.stderr)
+        return 1
     try:
         report = run_open_loop(
             fleet.submit, lambda i: pool[i % len(pool)],
@@ -563,9 +554,11 @@ def _serve_fleet(args, fitted, plan, single_s, pool, plan_stamp):
     finally:
         fleet.close()
     summary = report.to_row_dict()
+    summary.update(device_summary())
     summary.update({
         "single_request_s": round(single_s, 6),
         "buckets": plan.buckets,
+        "plan_compiled": plan.compiled,
         "plan_fingerprint": plan.fingerprint,
         "max_wait_ms": args.max_wait_ms,
         "num_planes": stats["num_planes"],
@@ -596,7 +589,15 @@ def _serve_fleet(args, fitted, plan, single_s, pool, plan_stamp):
             file=sys.stderr,
         )
         return 1
-    return 0
+    if stats["healthy_planes"] == 0:
+        print(
+            "serve: no fleet plane is healthy and un-quarantined "
+            f"(quarantined {stats['quarantined_planes']}, evicted "
+            f"{stats['evicted_planes']})",
+            file=sys.stderr,
+        )
+        return 1
+    return _serve_exit_code(report.completed, report.num_offered)
 
 
 def _learn(argv):
@@ -608,7 +609,6 @@ def _learn(argv):
     publication counters and measured model staleness; exits non-zero
     with a one-line diagnostic on failure (the serve contract)."""
     import argparse
-    import json
 
     parser = argparse.ArgumentParser("keystone-learn")
     parser.add_argument("--input-dim", type=int, default=16)
@@ -794,6 +794,7 @@ def _learn(argv):
         )
         return 1
     summary = report.to_row_dict()
+    summary.update(device_summary())
     # The lifecycle claims (staleness*/rollbacks) ride in the SAME dict
     # as num_published and the offered rate — the make_row audit shape.
     summary.update({
@@ -876,8 +877,6 @@ def _serve_tenant_specs(args):
     """``[{"id", "weight", "rate_hz"}, ...]`` from --tenant-spec (the
     skewed-mix form) or --tenants N (uniform — --rate split evenly);
     None when serve should run the single-tenant path."""
-    import json
-
     if args.tenant_spec:
         with open(args.tenant_spec) as f:
             doc = json.load(f)
@@ -915,7 +914,6 @@ def _serve_zoo(args, fitted, d_in, tenant_specs, plan_stamp=None):
     per-tenant SLO tracker when an SLO is declared, skewed open-loop
     Poisson load, and a summary line with the per-tenant verdicts plus
     the zoo's paging/quarantine/cold-start counters."""
-    import json
     import pickle
 
     import numpy as np
@@ -1016,6 +1014,7 @@ def _serve_zoo(args, fitted, d_in, tenant_specs, plan_stamp=None):
             exporter.close()
         zoo.close()
     summary = report.to_row_dict()
+    summary.update(device_summary())
     # The summary line keeps the per-tenant report blocks under
     # ``per_tenant``; ``tenants`` is the headline COUNT (the satellite
     # counters an operator greps for).
@@ -1038,7 +1037,9 @@ def _serve_zoo(args, fitted, d_in, tenant_specs, plan_stamp=None):
     if plan_stamp is not None:
         summary["plan_artifact"] = plan_stamp
     print(json.dumps(summary))
-    return 0
+    return _serve_exit_code(
+        summary["completed_total"], summary["offered_total"]
+    )
 
 
 PIPELINES: Dict[str, Callable] = {
@@ -1111,7 +1112,7 @@ def main(argv=None):
         print(__doc__)
         print("Pipelines:", ", ".join(sorted(PIPELINES)))
         return 0
-    _enable_compile_cache()
+    enable_compile_cache()
     # The whole invocation runs under the obs tracer when KEYSTONE_TRACE
     # (or --trace=DIR above) names a directory — one flag turns any
     # pipeline or serve run into a Perfetto-loadable causal record
@@ -1125,6 +1126,9 @@ def main(argv=None):
             return _learn(argv[1:])
         runner = resolve(argv[0])
         runner(argv[1:])
+    # Where it ran, on the last line — serve and learn carry the same
+    # block inside their JSON summary.
+    print("device: " + json.dumps(device_summary()))
     return 0
 
 

@@ -70,6 +70,7 @@ from .fleet_plane import PlanShip, plane_main
 from .fleet_rpc import RpcClient
 
 __all__ = [
+    "FleetBackendMismatch",
     "FleetClosed",
     "FleetPlaneDied",
     "FleetRouter",
@@ -99,6 +100,19 @@ class FleetClosed(ServerClosed):
     """Submission after (or unresolved at) ``close()``."""
 
 
+class FleetBackendMismatch(RuntimeError):
+    """A plane came up on another JAX backend than the process that
+    exported the plan (or on none). One process owns one chip: a parent
+    that fit the model on the chip holds it, so planes spawned beside it
+    cannot have it — and a fleet quietly serving from the host CPU is
+    worse than no fleet. Raised at boot; never retried."""
+
+
+def _stop_process(proc) -> None:
+    proc.terminate()
+    proc.join(5.0)
+
+
 class _Plane:
     """Router-side state for one plane slot. All mutable fields are
     guarded by the router's lock except the RPC client (thread-safe)
@@ -113,6 +127,7 @@ class _Plane:
         self.pid: Optional[int] = None
         self.quarantined: Optional[str] = None
         self.fingerprint: Optional[str] = None
+        self.device: Dict[str, Any] = {}
         self.healthy = False
         self.evicted = False
         self.outstanding = 0
@@ -196,8 +211,16 @@ class FleetRouter:
         # degraded window's tail is never erased.
         self._retired_hist = BucketedHistogram()
 
-        for p in self._planes:
-            self._spawn_plane(p, initial=True)
+        try:
+            for p in self._planes:
+                self._spawn_plane(p, initial=True)
+        except BaseException:
+            # A fleet that cannot boot raises — without leaving the
+            # planes that did come up running behind it.
+            for p in self._planes:
+                if p.proc is not None:
+                    _stop_process(p.proc)
+            raise
 
         self._queue: "queue.Queue[Optional[Tuple]]" = queue.Queue()
         n_disp = dispatchers if dispatchers is not None \
@@ -232,14 +255,25 @@ class FleetRouter:
         proc.start()
         child_conn.close()
         if not parent_conn.poll(self.startup_timeout_s):
-            proc.terminate()
-            proc.join(5.0)
+            _stop_process(proc)
             raise OSError(
                 f"{plane.name}: no bootstrap handshake within "
                 f"{self.startup_timeout_s}s"
             )
         hello = parent_conn.recv()
         parent_conn.close()
+        if hello["backend"] != self.ship.backend:
+            _stop_process(proc)
+            raise FleetBackendMismatch(
+                f"{plane.name} came up on backend {hello['backend']!r} "
+                f"but the plan was exported on {self.ship.backend!r}"
+                + (f" ({hello['quarantined']})"
+                   if hello["quarantined"] else "")
+                + " — one process owns one chip: the process that fit "
+                "the model holds it, so plane processes spawned beside "
+                "it cannot. Serve in-process (--replicas) on a one-chip "
+                "host, or give each plane its own chip."
+            )
         with self._lock:
             plane.proc = proc
             plane.pid = hello["pid"]
@@ -247,6 +281,10 @@ class FleetRouter:
             plane.metrics_port = hello["metrics_port"]
             plane.quarantined = hello["quarantined"]
             plane.fingerprint = hello["fingerprint"]
+            plane.device = {
+                k: hello[k]
+                for k in ("backend", "device_kind", "device_count")
+            }
             plane.client = RpcClient("127.0.0.1", hello["rpc_port"])
             plane.healthy = True
             plane.last_heartbeat = time.monotonic()
@@ -281,7 +319,8 @@ class FleetRouter:
                 self._spawn_once(plane)
             except Exception as e:  # noqa: BLE001 — budgeted chaos path
                 attempt += 1
-                if initial and attempt > 3:
+                if initial and (attempt > 3
+                                or isinstance(e, FleetBackendMismatch)):
                     raise
                 logger.warning(
                     "fleet: spawn attempt %d for %s failed: %r",
@@ -560,6 +599,7 @@ class FleetRouter:
             planes = {
                 p.name: {
                     "pid": p.pid,
+                    **p.device,
                     "healthy": p.healthy,
                     "evicted": p.evicted,
                     "quarantined": p.quarantined,

@@ -63,12 +63,12 @@ class PlanShip:
     ``uint16`` planes + per-tensor CRC."""
 
     __slots__ = ("skeleton", "tensors", "item_shape", "dtype",
-                 "max_batch", "buckets", "fingerprint")
+                 "max_batch", "buckets", "fingerprint", "backend")
 
     def __init__(self, skeleton: bytes, tensors: List[Any],
                  item_shape: Tuple[int, ...], dtype: str,
                  max_batch: Optional[int], buckets: Sequence[int],
-                 fingerprint: str):
+                 fingerprint: str, backend: str):
         self.skeleton = skeleton
         self.tensors = tensors
         self.item_shape = tuple(item_shape)
@@ -76,6 +76,9 @@ class PlanShip:
         self.max_batch = max_batch
         self.buckets = tuple(buckets)
         self.fingerprint = str(fingerprint)
+        # The JAX backend of the process that exported the plan — what the
+        # (jax-free) router requires every plane to come up on.
+        self.backend = str(backend)
 
     def __getstate__(self):
         return {s: getattr(self, s) for s in self.__slots__}
@@ -93,10 +96,12 @@ class ShipRejected(RuntimeError):
 def encode_plan_ship(fitted, plan) -> PlanShip:
     """Encode ``fitted`` (the pipeline ``plan`` was exported from) for
     shipping. Runs in the jax-owning caller process (the process that
-    fit the model). The weight slots are walked in the zoo's sorted
+    fit the model), whose backend the ship records. The weight slots are walked in the zoo's sorted
     deterministic order and split-plane encoded (per-tensor CRC); the
     receiving plane re-walks the unpickled skeleton in the same order,
     so slot ``i`` on both sides names the same weight."""
+    import jax
+
     from keystone_tpu.serving.zoo import (
         _collect_weight_slots,
         _encode_tensor,
@@ -115,6 +120,7 @@ def encode_plan_ship(fitted, plan) -> PlanShip:
         max_batch=plan.max_batch,
         buckets=plan.buckets,
         fingerprint=plan.fingerprint,
+        backend=jax.default_backend(),
     )
 
 
@@ -265,21 +271,39 @@ def plane_main(name: str, conn, ship: PlanShip,
     from keystone_tpu.obs.metrics import BucketedHistogram
     from keystone_tpu.serving.lifecycle import LifecycleController
     from keystone_tpu.serving.replicas import ReplicatedServer
+    from keystone_tpu.utils.startup import (
+        device_summary,
+        enable_compile_cache,
+    )
 
+    enable_compile_cache()
     quarantined: Optional[str] = None
     plan = None
+    # Which device this process actually got. A chip belongs to one
+    # process: when the spawning parent holds it, backend start-up either
+    # raises here or lands on the host CPU — the hello reports which, and
+    # the router refuses a plane that is not on the ship's backend.
+    device = {"backend": None, "device_kind": None, "device_count": 0}
     try:
-        plan = decode_plan_ship(ship)
-    except ShipRejected as e:
-        quarantined = str(e)
+        device = device_summary()
+    except RuntimeError as e:
+        quarantined = f"backend init failed: {e}"
         logger.warning(
-            "fleet plane %s QUARANTINED on arrival: %s", name, e
+            "fleet plane %s QUARANTINED (no backend): %s", name, e
         )
-    except Exception as e:  # noqa: BLE001 — quarantine, never serve
-        quarantined = f"{type(e).__name__}: {e}"
-        logger.warning(
-            "fleet plane %s QUARANTINED (decode error): %r", name, e
-        )
+    if quarantined is None:
+        try:
+            plan = decode_plan_ship(ship)
+        except ShipRejected as e:
+            quarantined = str(e)
+            logger.warning(
+                "fleet plane %s QUARANTINED on arrival: %s", name, e
+            )
+        except Exception as e:  # noqa: BLE001 — quarantine, never serve
+            quarantined = f"{type(e).__name__}: {e}"
+            logger.warning(
+                "fleet plane %s QUARANTINED (decode error): %r", name, e
+            )
 
     server = None
     if quarantined is None:
@@ -328,6 +352,7 @@ def plane_main(name: str, conn, ship: PlanShip,
         doc: Dict[str, Any] = {
             "pid": os.getpid(),
             "name": name,
+            **device,
             "quarantined": state["quarantined"],
             "fingerprint": state["fingerprint"],
             "latency_hist": hist.state_dict(),
@@ -350,6 +375,7 @@ def plane_main(name: str, conn, ship: PlanShip,
             "pid": os.getpid(),
             "quarantined": quarantined,
             "fingerprint": ship.fingerprint,
+            **device,
         })
         conn.close()
         state["shutdown"].wait()

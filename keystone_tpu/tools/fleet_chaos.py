@@ -18,7 +18,10 @@ the accounting verdict as JSON:
   - the per-plane books and the fleet-merged latency tail (the exact
     cross-process histogram merge).
 
-Exit status: 0 when both hold, 1 otherwise — the drill IS the check,
+Exit status: 0 when both hold and the fleet completed at least one
+request, 1 otherwise (including a fleet that cannot boot because its
+planes are not on the parent's backend: one process owns one chip, so
+on a one-chip TPU host this drill fails by name) — the drill IS the check,
 mirroring ``bin/chaos``'s run-the-contract discipline. See
 docs/serving.md (fleet section) and docs/reliability.md
 (process-death contract).
@@ -99,9 +102,14 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     parser.add_argument("--seed", type=int, default=0)
     args = parser.parse_args(list(argv) if argv is not None else None)
 
-    from keystone_tpu.serving.fleet import FleetRouter
+    from keystone_tpu.serving.fleet import FleetBackendMismatch, FleetRouter
     from keystone_tpu.serving.loadgen import run_multi_tenant_open_loop
+    from keystone_tpu.utils.startup import (
+        device_summary,
+        enable_compile_cache,
+    )
 
+    enable_compile_cache()
     plan, ship = _fit_and_ship(
         d_in=args.input_dim, num_ffts=2, block_size=args.input_dim,
         n=args.fit_n, max_batch=32, seed=args.seed,
@@ -119,12 +127,16 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
 
     victim: Dict[str, Any] = {}
 
-    fleet = FleetRouter(
-        ship, num_planes=args.planes,
-        replicas_per_plane=max(1, args.replicas),
-        heartbeat_interval_s=0.1, heartbeat_timeout_s=3.0,
-        restart_budget=2,
-    )
+    try:
+        fleet = FleetRouter(
+            ship, num_planes=args.planes,
+            replicas_per_plane=max(1, args.replicas),
+            heartbeat_interval_s=0.1, heartbeat_timeout_s=3.0,
+            restart_budget=2,
+        )
+    except FleetBackendMismatch as e:
+        print(f"fleet-chaos: fleet boot failed: {e}", file=sys.stderr)
+        return 1
 
     def kill_one_plane() -> None:
         pids = fleet.plane_pids()
@@ -166,6 +178,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         fleet.close()
 
     verdict = {
+        **device_summary(),
         "books_balance": books_balance,
         "respawn_fired": respawn_fired,
         "loadgen_books_balance": report.accounting_ok(),
@@ -189,6 +202,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     print(json.dumps(verdict, indent=2, sort_keys=True))
     ok = (books_balance and respawn_fired
           and report.accounting_ok()
+          and stats["completed"] > 0
           and victim.get("pid") is not None
           and respawned_pid != victim.get("pid"))
     if not ok:

@@ -11,7 +11,8 @@ fit) — and reports parity and walls. Two deployment forms:
   (sharding, liveness masking, the psum) is exercised with no chips.
   Walls measured this way are NOT device evidence (N ways of one CPU);
   the runner says so rather than printing a fake speedup.
-- **Real chips** (default on a TPU backend): the measurement leg — the
+- **Real chips** (no ``--force-host-devices``: the run uses whatever
+  backend JAX finds, and prints it): the measurement leg — the
   walls are real, the layout decision (``cost.choose_mesh_layout``) is
   recorded as a ``mesh_layout`` CostDecision and stamped with the
   measured mesh wall, so ``bin/calibrate`` joins predicted-vs-measured
@@ -83,9 +84,10 @@ def run(args) -> int:
         run_lbfgs_gram_streamed,
     )
     from keystone_tpu.parallel import mesh as mesh_lib
+    from keystone_tpu.utils.startup import device_summary
 
-    backend = jax.default_backend()
-    avail = len(jax.devices())
+    device = device_summary()
+    backend, avail = device["backend"], device["device_count"]
     n, d, w, k, c = args.n, args.d, args.nnz, args.k, args.chunk
 
     if args.layout == "auto":
@@ -140,8 +142,8 @@ def run(args) -> int:
 
     parity = float(jnp.max(jnp.abs(W1 - Wm)))
     ok = parity <= args.tol
-    print(f"backend={backend} devices={avail} layout={p}x{q} "
-          f"({layout_src})")
+    print(f"backend={backend} device_kind={device['device_kind']!r} "
+          f"devices={avail} layout={p}x{q} ({layout_src})")
     print(f"geometry: n={n} d={d} nnz/row={w} k={k} chunk={c} "
           f"seg={args.seg} iters={args.iters}")
     print(f"single-device wall: {single_s:.3f}s (loss {float(loss1):.6f})")
@@ -185,9 +187,10 @@ def run_scaling(args) -> int:
         run_lbfgs_gram_streamed,
     )
     from keystone_tpu.parallel import mesh as mesh_lib
+    from keystone_tpu.utils.startup import device_summary
 
-    backend = jax.default_backend()
-    avail = len(jax.devices())
+    device = device_summary()
+    backend, avail = device["backend"], device["device_count"]
     legs_m = [m for m in (1, 2, 4, 8) if m <= avail]
     nchunks, operands = _synth_coo(args)
     n, d, k = args.n, args.d, args.k
@@ -195,7 +198,8 @@ def run_scaling(args) -> int:
         lam=args.lam, num_iterations=args.iters, convergence_tol=1e-8,
         n=n, val_dtype=jnp.float32,
     )
-    print(f"backend={backend} devices={avail} scaling legs={legs_m}")
+    print(f"backend={backend} device_kind={device['device_kind']!r} "
+          f"devices={avail} scaling legs={legs_m}")
     print(f"geometry: n={n} d={d} nnz/row={args.nnz} k={k} "
           f"chunk={args.chunk} seg={args.seg} iters={args.iters}")
 
@@ -291,7 +295,7 @@ def run_scaling(args) -> int:
     print(f"parity max|dW| (worst leg): {worst_parity:.3e} "
           f"({'OK' if ok else 'FAIL'}, tol {args.tol:.1e})")
     print("scaling: " + _json.dumps({
-        "backend": backend, "device_evidence": device_evidence,
+        **device, "device_evidence": device_evidence,
         "legs": legs, "bend": bend,
         "geometry": {"n": n, "d": d, "nnz_per_row": args.nnz, "k": k,
                      "chunk": args.chunk, "seg": args.seg,
@@ -350,7 +354,9 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                 f"{args.force_host_devices} "
                 + os.environ.get("XLA_FLAGS", "")
             )
-        os.environ.setdefault("JAX_PLATFORMS", "cpu")
+        # Forced host devices ARE the CPU platform: the flag is the
+        # request, whatever JAX_PLATFORMS the environment carries.
+        os.environ["JAX_PLATFORMS"] = "cpu"
         import jax
 
         if len(jax.devices()) < args.force_host_devices:
@@ -364,6 +370,9 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
             )
             return 1
 
+    from keystone_tpu.utils.startup import enable_compile_cache
+
+    enable_compile_cache()
     entry = run_scaling if args.scaling else run
     if args.trace:
         from keystone_tpu import obs

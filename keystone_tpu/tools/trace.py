@@ -30,6 +30,10 @@ order, under which weights" (docs/placement.md).
 ``--perfetto OUT.json`` (re-)emits the Chrome-trace projection from the
 JSONL rows (e.g. after post-processing, or when only the event log was
 shipped off-box). Exits non-zero on an unreadable/invalid trace dir.
+
+An offline reader: it does no device work, and ``bin/trace`` pins
+``JAX_PLATFORMS=cpu`` (unless set) so inspecting a trace never takes the
+chip from the run that is writing it.
 """
 
 from __future__ import annotations

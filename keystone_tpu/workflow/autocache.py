@@ -55,6 +55,8 @@ from typing import Dict, List, Optional, Sequence, Set, Tuple
 import jax
 import numpy as np
 
+from keystone_tpu.utils.startup import device_memory_limit
+
 from . import analysis
 from .env import Prefix
 from .graph import Graph, NodeId, SinkId
@@ -645,12 +647,7 @@ def _prefix_fingerprint(prefix: Prefix) -> str:
 
 
 def _default_mem_budget() -> int:
-    """75% of per-device memory (AutoCacheRule's default of 75% of free cluster mem)."""
-    try:
-        stats = jax.local_devices()[0].memory_stats()
-        limit = stats.get("bytes_limit") or stats.get("bytes_reservable_limit")
-        if limit:
-            return int(limit * 0.75)
-    except Exception:
-        pass
-    return 8 << 30
+    """75% of per-device memory (AutoCacheRule's default of 75% of free
+    cluster mem); 8 GiB on the CPU test mesh, which reports no limit."""
+    limit = device_memory_limit()
+    return 8 << 30 if limit is None else int(limit * 0.75)

@@ -222,7 +222,7 @@ class DeviceFit:
     ``operands``: arrays the fit needs as TRACED inputs (e.g. a random-
     feature bank, the ridge λ) — a fit that closes over concrete arrays
     embeds them as HLO constants, which recompiles per instance and
-    breaks the remote-compile transport at TIMIT bank sizes.
+    bakes a TIMIT-size bank (~360 MB) into every executable.
 
     ``program_key``: hashable logical identity of the TRACE (estimator
     family + every static config the fit function closes over). When

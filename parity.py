@@ -394,6 +394,9 @@ def amazon_shaped_parity():
 
 
 def main():
+    from keystone_tpu.utils.startup import enable_compile_cache
+
+    enable_compile_cache()
     results = {
         "rows": [
             digits_parity(),

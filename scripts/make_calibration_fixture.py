@@ -46,7 +46,7 @@ FIXTURE_DIR = os.path.join(
     "calibration_trace",
 )
 
-# The r05 recorded device times (BENCH_r05 / BENCH_FULL_r05.json — the
+# The r05 recorded device times (BENCH_r05.json / ROADMAP Queue 1 — the
 # same constants tests/test_cost_replay.py replays).
 TIMIT_RESIDENT = {"n": 262_144, "d": 16_384, "k": 147, "sparsity": 1.0,
                   "machines": 1}

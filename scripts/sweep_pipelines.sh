@@ -2,7 +2,9 @@
 # On-chip sweep of every CLI registry pipeline at its default demo config
 # (the round-4/5 acceptance pattern: TPU-only latent failures — scoped-VMEM
 # overflows, layout traps — are swept on hardware, not just asserted on the
-# CPU mesh). One process per pipeline; a failure does not stop the sweep.
+# CPU mesh). One process per pipeline (one process owns the chip at a time);
+# a failure does not stop the sweep, but the sweep exits non-zero if any
+# pipeline failed. Each run's last line names the device it ran on.
 set -u
 cd "$(dirname "$0")/.."
 out="${1:-/tmp/pipeline_sweep.log}"
@@ -25,3 +27,4 @@ else
   echo "FAIL TimitPipeline--solver-auto"; fail=$((fail+1))
 fi
 echo "SWEEP DONE ok=$ok fail=$fail (log: $out)"
+[ "$fail" -eq 0 ]

@@ -27,6 +27,7 @@ import pytest
 
 from keystone_tpu.serving.export import export_plan
 from keystone_tpu.serving.fleet import (
+    FleetBackendMismatch,
     FleetPlaneDied,
     FleetRouter,
     FleetSaturated,
@@ -107,6 +108,31 @@ class TestShipIntegrity:
             decode_plan_ship(bad)
 
 
+class TestBackendOwnership:
+    def test_plane_on_another_backend_fails_boot_by_name(self, shipment):
+        """One process owns one chip: a plane whose hello reports another
+        backend than the process that exported the plan (here: a ship
+        claiming a TPU export, planes coming up on the CPU — what a
+        one-chip host gives planes spawned beside the chip's owner)
+        makes the router raise at boot, by name, leaving no plane
+        behind. It never quietly serves from somewhere else."""
+        import multiprocessing
+
+        _fitted, _plan, _X, ship = shipment
+        assert ship.backend == "cpu"
+        elsewhere = copy.deepcopy(ship)
+        elsewhere.backend = "tpu"
+        with pytest.raises(FleetBackendMismatch,
+                           match="one process owns one chip") as err:
+            _fleet(elsewhere, num_planes=2)
+        assert "'cpu'" in str(err.value) and "'tpu'" in str(err.value)
+        deadline = time.monotonic() + 10.0
+        while multiprocessing.active_children() \
+                and time.monotonic() < deadline:
+            time.sleep(0.05)
+        assert multiprocessing.active_children() == []
+
+
 class TestFleetKill:
     def test_sigkill_respawn_books_balance(self, shipment):
         """The tier-1 core of the tentpole: SIGKILL one plane under
@@ -144,6 +170,8 @@ class TestFleetKill:
             assert s["healthy_planes"] == 2
             assert s["evicted_planes"] == []
             assert fleet.plane_pids()["plane0"] != victim
+            # Every plane's block says where it runs.
+            assert {p["backend"] for p in s["planes"].values()} == {"cpu"}
             # Books: exact, with every kill-window failure NAMED.
             assert _books_balance(s), s
             assert s["failed"] == named

@@ -1,8 +1,9 @@
 """Selector replay against recorded bench measurements (ISSUE 3 satellite).
 
 The cost model's job is to rank candidates the way the hardware ranks them.
-These tests replay geometries with MEASURED on-chip outcomes (BENCH_r05 /
-BENCH_FULL_r05.json device-time rows, provenance noted per case) through
+These tests replay geometries with MEASURED round-5 on-chip outcomes
+(BENCH_r05.json and the rows ROADMAP Queue 1 quotes; provenance noted
+per case) through
 the ACTIVE selector weights and assert the selector picks the
 measured-fastest feasible candidate:
 
@@ -259,7 +260,7 @@ class TestReplayAmazonSparse:
 
 
 class TestReplayAmazonCompressedResident:
-    # BENCH_FULL_r05 resident probe, promoted to a tier (ISSUE 8): the
+    # Round-5 resident probe, promoted to a tier (ISSUE 8): the
     # compressed int16+bf16 COO at n=30e6 is 9.8 GB measured on-chip
     # (fit-path folds ran from it in place), while the raw int32+f32
     # operand at the same n is 19.7 GB — past any 16 GB budget. The

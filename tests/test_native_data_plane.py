@@ -200,3 +200,45 @@ class TestBatchPnmDecode:
         out = list(iter_tar_images(str(tar)))
         assert len(out) == n
         assert [name for name, _ in out] == [f"img{i:03d}.ppm" for i in range(n)]
+
+
+class TestContentKeyedRebuild:
+    """The build is keyed on the sources' CONTENT (a digest recorded
+    beside the library), never on file times — a copied checkout carries
+    arbitrary mtimes and possibly a library built from other sources."""
+
+    def _fresh(self, monkeypatch):
+        monkeypatch.setattr(native, "_lib", None)
+        monkeypatch.setattr(native, "_tried", False)
+
+    def test_rebuilds_when_recorded_digest_differs(self, monkeypatch):
+        if native.get_lib() is None:
+            pytest.skip("native library unavailable")
+        builds = []
+        real_build = native._build
+
+        def counting_build(digest):
+            builds.append(digest)
+            return real_build(digest)
+
+        monkeypatch.setattr(native, "_build", counting_build)
+        # A library "newer" than its sources whose recorded digest is of
+        # OTHER sources: an mtime rule would load it as it stands.
+        with open(native._DIGEST_PATH, "w") as f:
+            f.write("0" * 64 + "\n")
+        self._fresh(monkeypatch)
+        assert native.get_lib() is not None
+        assert builds == [native._sources_digest()]
+        assert native._recorded_digest() == native._sources_digest()
+
+        # Matching digest: loaded as it stands, no rebuild.
+        self._fresh(monkeypatch)
+        assert native.get_lib() is not None
+        assert len(builds) == 1
+        assert native.status() == "native"
+
+    def test_no_compiler_says_numpy(self, monkeypatch):
+        monkeypatch.setattr(native, "_recorded_digest", lambda: None)
+        monkeypatch.setattr(native, "_build", lambda digest: False)
+        self._fresh(monkeypatch)
+        assert native.status() == "numpy"
