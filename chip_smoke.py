@@ -68,6 +68,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from keystone_tpu.utils.profiling import CompileClock, compile_ledger
+
 PHASES = ("kernels", "timit", "serve", "mesh")
 TIMING_NOTE = (
     "every *_s_smoke value is a smoke reading (one run, cold or cached as "
@@ -142,57 +144,6 @@ def check(cond: bool, message: str) -> None:
 # ---------------------------------------------------------------------------
 # Clocks and program text
 # ---------------------------------------------------------------------------
-
-
-class CompileClock:
-    """Seconds JAX spent in backend compilation (persistent-cache look-ups
-    included), from ``jax.monitoring`` — so a phase driven through a
-    public entry point still reports compilation apart from the rest."""
-
-    _COMPILE = "/jax/core/compile/backend_compile_duration"
-    _HIT = "/jax/compilation_cache/cache_hits"
-    _MISS = "/jax/compilation_cache/cache_misses"
-
-    def __init__(self) -> None:
-        self.compile_s = 0.0
-        self.programs = 0
-        self.cache_hits = 0
-        self.cache_misses = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event: str, duration: float, **_: Any) -> None:
-        if event == self._COMPILE:
-            self.compile_s += duration
-            self.programs += 1
-
-    def _event(self, event: str, **_: Any) -> None:
-        if event == self._HIT:
-            self.cache_hits += 1
-        elif event == self._MISS:
-            self.cache_misses += 1
-
-    @contextlib.contextmanager
-    def measure(self) -> Iterator[Dict[str, Any]]:
-        """Yield a dict that is filled in on exit with this block's wall
-        and its compile share."""
-        out: Dict[str, Any] = {}
-        before = (self.compile_s, self.programs, self.cache_hits,
-                  self.cache_misses)
-        t0 = time.perf_counter()
-        try:
-            yield out
-        finally:
-            wall = time.perf_counter() - t0
-            compile_s = self.compile_s - before[0]
-            out.update({
-                "wall_s_smoke": round(wall, 3),
-                "compile_s_smoke": round(compile_s, 3),
-                "non_compile_s_smoke": round(max(wall - compile_s, 0.0), 3),
-                "programs_compiled": self.programs - before[1],
-                "persistent_cache_hits": self.cache_hits - before[2],
-                "persistent_cache_misses": self.cache_misses - before[3],
-            })
 
 
 @contextlib.contextmanager
@@ -839,7 +790,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         parser.error(f"unknown phases {unknown}; choose from {PHASES}")
 
     device_report = phase_device()
-    clock = CompileClock()
+    clock = compile_ledger()
     t0 = time.perf_counter()
     ok, results = run_phases(phases, clock)
     summary = {

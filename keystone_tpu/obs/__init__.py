@@ -38,8 +38,10 @@ causally-linked record:
     drift (``bin/calibrate``).
 
 Activation (docs/observability.md): ``KEYSTONE_TRACE=dir`` env knob,
-``run.py --trace=dir``, or ``with obs.tracing(dir):`` in code. This
-package imports no jax — the data-plane runtime (which must stay
+``run.py --trace=dir``, ``with obs.tracing(dir):`` in code, or a jax
+profile taken of a running fit (``utils.profiling.follow_profiler``:
+the spans then ride the profile, and ``obs.last_session()`` keeps
+them). This package imports no jax at module level — the data-plane runtime (which must stay
 jax-free) reports into it from its IO workers.
 """
 
@@ -86,9 +88,12 @@ from keystone_tpu.obs.tracer import (
     active_tracer,
     counter_track,
     enabled,
+    end_session,
     event,
+    last_session,
     record_cost_decision,
     span,
+    start_session,
     tracing,
     tracing_from_env,
 )
@@ -112,10 +117,12 @@ __all__ = [
     "counter_track",
     "drift_gate",
     "enabled",
+    "end_session",
     "event",
     "flight_note",
     "flight_snapshot",
     "join_decisions",
+    "last_session",
     "load_calibration_artifact",
     "load_events",
     "record_cost_decision",
@@ -124,6 +131,7 @@ __all__ = [
     "render_flight_record",
     "render_prometheus",
     "span",
+    "start_session",
     "to_chrome_trace",
     "tracing",
     "tracing_from_env",

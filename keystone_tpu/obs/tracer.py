@@ -40,7 +40,7 @@ import time
 import uuid
 from collections import deque
 from dataclasses import dataclass, field
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Callable, Dict, List, Optional, Sequence
 
 logger = logging.getLogger("keystone_tpu.obs.tracer")
 
@@ -53,9 +53,12 @@ __all__ = [
     "active_tracer",
     "counter_track",
     "enabled",
+    "end_session",
     "event",
+    "last_session",
     "record_cost_decision",
     "span",
+    "start_session",
     "tracing",
     "tracing_from_env",
 ]
@@ -89,6 +92,8 @@ _NOOP = _NoopSpan()
 # THE one branch: every hook reads this module global. None = disabled.
 _ACTIVE: Optional["Tracer"] = None
 _ACTIVE_LOCK = threading.Lock()
+# The newest tracer that :func:`start_session` started, active or ended.
+_SESSION: Optional["Tracer"] = None
 
 
 def enabled() -> bool:
@@ -138,7 +143,7 @@ class Span:
     """
 
     __slots__ = ("tracer", "name", "args", "span_id", "parent_id",
-                 "_t0", "error")
+                 "_t0", "error", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, args: Dict[str, Any]):
         self.tracer = tracer
@@ -148,17 +153,26 @@ class Span:
         self.parent_id: Optional[int] = None
         self._t0 = 0.0
         self.error: Optional[str] = None
+        self._annotation: Any = None
 
     def set(self, **attrs) -> None:
         self.args.update(attrs)
 
     def __enter__(self) -> "Span":
         self.tracer._open(self)
+        annotate = self.tracer.annotate
+        if annotate is not None:
+            # The same span on the profiler's host line, on its clock:
+            # ``ks.<name>`` with the attributes known at open.
+            self._annotation = annotate("ks." + self.name, **self.args)
+            self._annotation.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> bool:
         t1 = time.perf_counter()
+        if self._annotation is not None:
+            self._annotation.__exit__(exc_type, exc, tb)
         if exc is not None:
             # The span carries its failure — a postmortem's flight
             # record names not just WHAT was in flight but what died.
@@ -248,13 +262,22 @@ class Tracer:
     long-lived traced serve keeps every slow/error/shed span but only a
     head sample of the healthy fast ones. Fit-path spans are never
     sampled (their volume is bounded by the fold, not the traffic).
+
+    ``annotate``: an optional factory ``annotate(name, **attrs)`` of
+    context managers (``jax.profiler.TraceAnnotation``, passed by
+    ``utils.profiling`` — this module imports no jax). With it every
+    span also enters an annotation ``ks.<span name>``, which puts the
+    program's spans on a jax profile's host line, on the clock of its
+    device lines.
     """
 
     def __init__(self, run_id: Optional[str] = None,
                  max_records: int = 1_000_000,
-                 serving_sampler: Optional[TailSampler] = None):
+                 serving_sampler: Optional[TailSampler] = None,
+                 annotate: Optional[Callable[..., Any]] = None):
         self.run_id = run_id or uuid.uuid4().hex[:12]
         self.serving_sampler = serving_sampler
+        self.annotate = annotate
         # Map perf_counter to wall-clock microseconds once, so every
         # record's ts_us is an epoch time Perfetto renders as absolute.
         self._epoch_us_at_zero = (
@@ -355,9 +378,14 @@ class Tracer:
         serving bridge: the micro-batcher knows a request's
         enqueue/complete times only after the fact, and its rolling
         ``RequestSpan``/``SpanLog`` stats must keep working unchanged.
-        Returns the span id (the exemplar reference a histogram bucket
-        can carry)."""
+        Its parent is the innermost span open on the calling thread
+        (None on a thread with none open), so work reported after the
+        fact — the compile ledger's ``jax.compile`` — takes part in its
+        parent's self time. Returns the span id (the exemplar reference
+        a histogram bucket can carry)."""
         th = threading.current_thread()
+        st = self._stack()
+        parent_id = st[-1] if st else None
         with self._lock:
             sid = next(self._ids)
             self._append_locked({
@@ -365,7 +393,7 @@ class Tracer:
                 "ts_us": self._us(t0),
                 "dur_us": max(int((t1 - t0) * 1e6), 0),
                 "tid": th.ident, "thread": th.name,
-                "span_id": sid, "parent_id": None,
+                "span_id": sid, "parent_id": parent_id,
                 "run_id": self.run_id, "args": dict(attrs),
             })
         return sid
@@ -528,6 +556,52 @@ def record_cost_decision(decision: CostDecision) -> Optional[CostOutcomeRef]:
 # ---------------------------------------------------------------------------
 
 
+def _activate(tracer: "Tracer") -> bool:
+    """Make ``tracer`` the active one unless one is active (then False).
+    The first activation in a process registers the compile ledger's
+    ``jax.monitoring`` listeners (``utils.profiling.compile_ledger``,
+    imported here so that this module stays jax-free): a process that
+    never traces never registers them."""
+    global _ACTIVE
+    from keystone_tpu.utils import profiling
+
+    with _ACTIVE_LOCK:
+        if _ACTIVE is not None:
+            return False
+        profiling.compile_ledger()
+        _ACTIVE = tracer
+        return True
+
+
+def start_session(annotate: Callable[..., Any]) -> Optional["Tracer"]:
+    """Activate an in-memory tracer that no ``with`` block owns — how
+    ``utils.profiling.follow_profiler`` makes the program's tracing
+    follow a jax profile. None when a tracer is active already: that
+    one is left alone."""
+    global _SESSION
+    t = Tracer(annotate=annotate)
+    if not _activate(t):
+        return None
+    _SESSION = t
+    return t
+
+
+def end_session() -> None:
+    """Deactivate the active tracer if :func:`start_session` started it
+    (a tracer of :func:`tracing` is never touched). It stays readable
+    through :func:`last_session`."""
+    global _ACTIVE
+    with _ACTIVE_LOCK:
+        if _ACTIVE is not None and _ACTIVE is _SESSION:
+            _ACTIVE = None
+
+
+def last_session() -> Optional["Tracer"]:
+    """The newest tracer :func:`start_session` started, active or
+    ended; None if there never was one."""
+    return _SESSION
+
+
 @contextlib.contextmanager
 def tracing(directory: Optional[str] = None, run_id: Optional[str] = None,
             xla_profile: bool = False,
@@ -543,8 +617,10 @@ def tracing(directory: Optional[str] = None, run_id: Optional[str] = None,
     ``xla_profile=True`` additionally wraps the block in the
     jax.profiler trace (``utils.profiling.trace`` — the XLA
     device-timeline deep-dive leg of this plane) writing under
-    ``directory/xla``; requires a directory. Imported lazily so this
-    module stays jax-free.
+    ``directory/xla``; requires a directory. Every span is then also a
+    ``ks.<name>`` annotation on that profile's host line, so the two
+    views share the profiler's clock. Imported lazily so this module
+    stays jax-free.
 
     ``serving_sampler``: a :class:`TailSampler` for the per-request
     serving spans — a traced long-lived serve keeps every slow/error/
@@ -553,21 +629,22 @@ def tracing(directory: Optional[str] = None, run_id: Optional[str] = None,
     Nested activation raises: one trace is one run's record.
     """
     global _ACTIVE
-    with _ACTIVE_LOCK:
-        if _ACTIVE is not None:
-            raise RuntimeError(
-                "tracing is already active; one trace per run "
-                "(nest work under the active tracer instead)"
-            )
-        t = Tracer(run_id=run_id, serving_sampler=serving_sampler)
-        _ACTIVE = t
+    annotate = None
     xla_cm = contextlib.nullcontext()
     if xla_profile:
         if directory is None:
             raise ValueError("xla_profile=True needs a trace directory")
         from keystone_tpu.utils import profiling
 
+        annotate = profiling.TraceAnnotation
         xla_cm = profiling.trace(os.path.join(directory, "xla"))
+    t = Tracer(run_id=run_id, serving_sampler=serving_sampler,
+               annotate=annotate)
+    if not _activate(t):
+        raise RuntimeError(
+            "tracing is already active; one trace per run "
+            "(nest work under the active tracer instead)"
+        )
     try:
         with xla_cm:
             yield t

@@ -16,6 +16,7 @@ from typing import List, Optional, Sequence
 
 import jax.numpy as jnp
 
+from keystone_tpu import obs
 from keystone_tpu.data import Dataset
 from keystone_tpu.ops.stats import StandardScaler
 from keystone_tpu.ops.util import VectorSplitter
@@ -216,16 +217,17 @@ class BlockLeastSquaresEstimator(LabelEstimator):
         return self.fit_blocks(blocks, labels)
 
     def fit_blocks(self, blocks: List[Dataset], labels: Dataset) -> BlockLinearMapper:
-        label_scaler = StandardScaler(normalize_std_dev=False).fit(labels)
-        B = jnp.asarray(label_scaler.batch_apply(labels).array)
+        with obs.span("solver.scale", blocks=len(blocks)):
+            label_scaler = StandardScaler(normalize_std_dev=False).fit(labels)
+            B = jnp.asarray(label_scaler.batch_apply(labels).array)
 
-        feature_scalers = [
-            StandardScaler(normalize_std_dev=False).fit(block) for block in blocks
-        ]
-        A_blocks = [
-            jnp.asarray(scaler.batch_apply(block).array)
-            for block, scaler in zip(blocks, feature_scalers)
-        ]
+            feature_scalers = [
+                StandardScaler(normalize_std_dev=False).fit(block) for block in blocks
+            ]
+            A_blocks = [
+                jnp.asarray(scaler.batch_apply(block).array)
+                for block, scaler in zip(blocks, feature_scalers)
+            ]
 
         def _is_multi(ds):
             return ds.mesh is not None and any(
@@ -243,12 +245,14 @@ class BlockLeastSquaresEstimator(LabelEstimator):
             # data keeps the stepwise path (per-block programs partition
             # cleanly and match the unsharded reduction order); so do fits
             # whose stacked copy would not fit beside the blocks in HBM.
-            stacked = jnp.stack(A_blocks)
-            del A_blocks  # the stack is a full second copy; drop the list
-            W_stack = linalg.bcd_least_squares_fused(
-                stacked, B, lam=self.lam, num_iter=self.num_iter
-            )
-            Ws = [W_stack[i] for i in range(W_stack.shape[0])]
+            with obs.span("solver.stack"):
+                stacked = jnp.stack(A_blocks)
+                del A_blocks  # the stack is a full second copy; drop the list
+            with obs.span("solver.bcd", epochs=self.num_iter):
+                W_stack = linalg.bcd_least_squares_fused(
+                    stacked, B, lam=self.lam, num_iter=self.num_iter
+                )
+                Ws = [W_stack[i] for i in range(W_stack.shape[0])]
         else:
             mesh = next(
                 (d.mesh for d in [labels, *blocks] if d.mesh is not None), None

@@ -825,6 +825,10 @@ class LeastSquaresEstimator(OptimizableLabelEstimator):
         return self._default.weight
 
     def optimize(self, sample: Dataset, labels_sample: Dataset):
+        with obs.span("cost.select"):
+            return self._select(sample, labels_sample)
+
+    def _select(self, sample: Dataset, labels_sample: Dataset):
         # total_n: the full dataset size attached by the sample collector;
         # sample.n is just the handful of sampled rows.
         n = getattr(sample, "total_n", sample.n)

@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from keystone_tpu import obs
 from keystone_tpu.data import Dataset
 from keystone_tpu.parallel import mesh as mesh_lib
 from keystone_tpu.parallel import streaming
@@ -212,12 +213,17 @@ class StreamingFeaturizedLeastSquares(LabelEstimator):
                 num_iter=self.num_iter,
                 valid=int(data.n) if data.n != X.shape[0] else None,
             )
-            if self.center:
-                W, fmean, ymean, _ = streaming.streaming_bcd_fit_centered(
-                    X, Y, **kw
-                )
-            else:
-                W, _, _ = streaming.streaming_bcd_fit(X, Y, **kw)
+            # The one program of a single-device streamed fit
+            # (``_streaming_fit_bank``): its dispatch, and whatever
+            # tracing or compiling it causes.
+            with obs.span("solver.stream_fit", rows=int(X.shape[0]),
+                          tile_rows=kw["tile_rows"]):
+                if self.center:
+                    W, fmean, ymean, _ = streaming.streaming_bcd_fit_centered(
+                        X, Y, **kw
+                    )
+                else:
+                    W, _, _ = streaming.streaming_bcd_fit(X, Y, **kw)
         return StreamingFeaturizedLinearModel(
             self.featurize, W, self.tile_rows, fmean=fmean, ymean=ymean,
         )

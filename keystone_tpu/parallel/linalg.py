@@ -391,28 +391,31 @@ def _bcd_block_update(Ab, R, Wb, lam: float, use_pallas: bool, sym: bool,
     # plain f32 would silently downcast double-precision accumulations).
     acc_dtype = jnp.promote_types(feat_dtype, jnp.float32)
     hi = _hi_kwargs(feat_dtype)
-    if gram is None and use_pallas and acc_dtype == jnp.float32:
-        # The Pallas kernels accumulate in f32; f64 inputs keep the XLA path
-        # so the double-precision promotion below is honored.
-        fn = pallas_ops.gram_corr_sym if sym else pallas_ops.gram_corr
-        gram, corr = fn(Ab, R)
-    else:
-        if gram is None:
-            gram = jax.lax.dot_general(
-                Ab, Ab, (((0,), (0,)), ((), ())),
-                preferred_element_type=acc_dtype, **hi,
-            )
-        corr = _corr(Ab, R)
-    lam_t = jnp.asarray(lam, dtype=gram.dtype)
-    if chol is None:
-        chol = _psd_factor(gram, lam_t)
-    rhs = corr + gram @ Wb
-    Wb_new = _solve_psd(gram, rhs, lam_t, chol=chol)
-    delta = jax.lax.dot_general(
-        Ab, (Wb_new - Wb).astype(feat_dtype), (((1,), (0,)), ((), ())),
-        preferred_element_type=acc_dtype, **hi,
-    )
-    return R - delta, Wb_new, gram, chol
+    # The scopes name the phases in a device profile (trace-time only).
+    with jax.named_scope("ks.gram_corr_fold"):
+        if gram is None and use_pallas and acc_dtype == jnp.float32:
+            # The Pallas kernels accumulate in f32; f64 inputs keep the XLA
+            # path so the double-precision promotion below is honored.
+            fn = pallas_ops.gram_corr_sym if sym else pallas_ops.gram_corr
+            gram, corr = fn(Ab, R)
+        else:
+            if gram is None:
+                gram = jax.lax.dot_general(
+                    Ab, Ab, (((0,), (0,)), ((), ())),
+                    preferred_element_type=acc_dtype, **hi,
+                )
+            corr = _corr(Ab, R)
+    with jax.named_scope("ks.bcd_step"):
+        lam_t = jnp.asarray(lam, dtype=gram.dtype)
+        if chol is None:
+            chol = _psd_factor(gram, lam_t)
+        rhs = corr + gram @ Wb
+        Wb_new = _solve_psd(gram, rhs, lam_t, chol=chol)
+        delta = jax.lax.dot_general(
+            Ab, (Wb_new - Wb).astype(feat_dtype), (((1,), (0,)), ((), ())),
+            preferred_element_type=acc_dtype, **hi,
+        )
+        return R - delta, Wb_new, gram, chol
 
 
 @functools.partial(

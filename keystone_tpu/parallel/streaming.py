@@ -110,27 +110,30 @@ def _tile_update(G, FY, yty, fsum, ysum, X_t, Y_t, featurize, use_pallas,
     """
     from keystone_tpu.ops import pallas_ops
 
-    F_t = featurize(X_t)
-    if valid is not None:
-        F_t = _row_mask(F_t, valid)
-        Y_t = _row_mask(Y_t, valid)
+    # The scopes name the phases in a device profile (trace-time only).
+    with jax.named_scope("ks.featurize"):
+        F_t = featurize(X_t)
+        if valid is not None:
+            F_t = _row_mask(F_t, valid)
+            Y_t = _row_mask(Y_t, valid)
     acc = jnp.promote_types(F_t.dtype, jnp.float32)
-    if use_pallas and pallas_ops.gram_acc_ok(F_t):
-        G = pallas_ops.gram_sym_acc(G, F_t)
-    else:
-        G = G + jax.lax.dot_general(
-            F_t, F_t, (((0,), (0,)), ((), ())), preferred_element_type=acc,
+    with jax.named_scope("ks.gram_fold"):
+        if use_pallas and pallas_ops.gram_acc_ok(F_t):
+            G = pallas_ops.gram_sym_acc(G, F_t)
+        else:
+            G = G + jax.lax.dot_general(
+                F_t, F_t, (((0,), (0,)), ((), ())), preferred_element_type=acc,
+            ).astype(jnp.float32)
+        FY = FY + jax.lax.dot_general(
+            F_t, Y_t.astype(F_t.dtype), (((0,), (0,)), ((), ())),
+            preferred_element_type=acc,
         ).astype(jnp.float32)
-    FY = FY + jax.lax.dot_general(
-        F_t, Y_t.astype(F_t.dtype), (((0,), (0,)), ((), ())),
-        preferred_element_type=acc,
-    ).astype(jnp.float32)
-    Yf = Y_t.astype(jnp.float32)
-    # dtype=f32 so bf16 feature slabs accumulate their column sums at the
-    # same precision as the G/FY folds (a bf16 reduction would bias the
-    # centered solve: cos features have near-zero means, all cancellation).
-    fsum = fsum + jnp.sum(F_t, axis=0, dtype=jnp.float32)
-    ysum = ysum + jnp.sum(Yf, axis=0)
+        Yf = Y_t.astype(jnp.float32)
+        # dtype=f32 so bf16 feature slabs accumulate their column sums at the
+        # same precision as the G/FY folds (a bf16 reduction would bias the
+        # centered solve: cos features have near-zero means, all cancellation).
+        fsum = fsum + jnp.sum(F_t, axis=0, dtype=jnp.float32)
+        ysum = ysum + jnp.sum(Yf, axis=0)
     return G, FY, yty + jnp.sum(Yf * Yf), fsum, ysum
 
 
@@ -277,6 +280,7 @@ def gram_stats(
     return G, FY, yty
 
 
+@jax.named_scope("ks.bcd")  # names the phase in a device profile
 def bcd_from_gram(
     G: Array,
     FY: Array,

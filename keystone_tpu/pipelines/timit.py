@@ -13,6 +13,7 @@ import logging
 import time
 from dataclasses import dataclass
 
+from keystone_tpu import obs
 from keystone_tpu.data.loaders import TimitFeaturesDataLoader, synthetic_timit
 from keystone_tpu.evaluation import MulticlassClassifierEvaluator
 from keystone_tpu.ops.learning.block import BlockLeastSquaresEstimator
@@ -22,6 +23,7 @@ from keystone_tpu.ops.util import (
     MaxClassifier,
     VectorCombiner,
 )
+from keystone_tpu.utils.profiling import follow_profiler
 from keystone_tpu.workflow import Pipeline
 
 logger = logging.getLogger("keystone_tpu.pipelines.timit")
@@ -65,17 +67,22 @@ class TimitConfig:
 def build_featurizer(config: TimitConfig) -> Pipeline:
     """numCosines branches of 4096 random features each
     (TimitPipeline.scala:61-78: numCosineFeatures = 4096 per batch)."""
-    branches = [
-        CosineRandomFeatures(
-            NUM_INPUT_FEATURES,
-            config.block_size,
-            config.gamma,
-            seed=config.seed + i,
-            cauchy=(config.rf_type == "cauchy"),
-        ).to_pipeline()
-        for i in range(config.num_cosines)
-    ]
-    return Pipeline.gather(branches).and_then(VectorCombiner())
+    follow_profiler()
+    # Drawing the banks and building the graph: what every new fit pays
+    # before ``pipeline.fit`` opens.
+    with obs.span("pipeline.build", entry="featurizer",
+                  branches=config.num_cosines):
+        branches = [
+            CosineRandomFeatures(
+                NUM_INPUT_FEATURES,
+                config.block_size,
+                config.gamma,
+                seed=config.seed + i,
+                cauchy=(config.rf_type == "cauchy"),
+            ).to_pipeline()
+            for i in range(config.num_cosines)
+        ]
+        return Pipeline.gather(branches).and_then(VectorCombiner())
 
 
 def streaming_estimator(config: TimitConfig):
@@ -89,22 +96,25 @@ def streaming_estimator(config: TimitConfig):
         cosine_bank_featurize,
     )
 
-    rfs = [
-        CosineRandomFeatures(
-            NUM_INPUT_FEATURES, config.block_size, config.gamma,
-            seed=config.seed + i, cauchy=(config.rf_type == "cauchy"),
+    follow_profiler()
+    with obs.span("pipeline.build", entry="streaming",
+                  branches=config.num_cosines):
+        rfs = [
+            CosineRandomFeatures(
+                NUM_INPUT_FEATURES, config.block_size, config.gamma,
+                seed=config.seed + i, cauchy=(config.rf_type == "cauchy"),
+            )
+            for i in range(config.num_cosines)
+        ]
+        bank = cosine_bank_featurize(
+            jnp.concatenate([rf.W for rf in rfs]),
+            jnp.concatenate([rf.b for rf in rfs]),
         )
-        for i in range(config.num_cosines)
-    ]
-    bank = cosine_bank_featurize(
-        jnp.concatenate([rf.W for rf in rfs]),
-        jnp.concatenate([rf.b for rf in rfs]),
-    )
-    return StreamingFeaturizedLeastSquares(
-        bank, d_feat=config.num_cosines * config.block_size,
-        block_size=config.block_size, num_iter=config.num_epochs,
-        lam=config.lam,
-    )
+        return StreamingFeaturizedLeastSquares(
+            bank, d_feat=config.num_cosines * config.block_size,
+            block_size=config.block_size, num_iter=config.num_epochs,
+            lam=config.lam,
+        )
 
 
 def run(config: TimitConfig):

@@ -9,6 +9,12 @@ ad-hoc per-phase nanosecond logs inside solvers (KernelRidgeRegression.scala:
     iterative solvers for per-phase breakdowns.
   - ``trace`` — context manager around ``jax.profiler`` emitting a TensorBoard
     trace directory (XLA device timelines), the deep-dive tool.
+  - ``follow_profiler`` — the bridge between a jax profile and the
+    program's own tracer (:mod:`keystone_tpu.obs`): while a profile is
+    being taken the program's spans are recorded and written into it.
+  - ``CompileClock`` / ``compile_ledger`` — what JAX traced, lowered and
+    compiled (or fetched), from ``jax.monitoring``: counts for a caller's
+    block, ``jax.compile`` spans for the active tracer.
   - ``compiled_cost`` — static cost extraction from a jitted function's
     compiled XLA executable (FLOPs / bytes accessed), the analog of the
     reference's analytic ``CostModel`` inputs but read from the compiler
@@ -30,11 +36,17 @@ import threading
 import time
 from collections import OrderedDict, deque
 from dataclasses import dataclass
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Dict, Iterator, List, Optional, Sequence
 
 import jax
 
+from keystone_tpu.obs import tracer as _tracer
+
 logger = logging.getLogger("keystone_tpu.profiling")
+
+# The annotation factory a :class:`~keystone_tpu.obs.Tracer` takes so that
+# its spans are written into a jax profile (the tracer itself imports no jax).
+TraceAnnotation = jax.profiler.TraceAnnotation
 
 
 class PhaseTimer:
@@ -360,8 +372,9 @@ def trace(log_dir: str):
     This is the XLA device-timeline leg of the obs plane (ISSUE 9
     satellite — previously orphaned): ``obs.tracing(dir,
     xla_profile=True)`` wraps the traced block in it, writing under
-    ``dir/xla`` beside the Perfetto span trace, so the deep-dive XLA
-    view and the host-side span view come from ONE activation."""
+    ``dir/xla`` beside the Perfetto span trace, and hands its tracer
+    :data:`TraceAnnotation`, so the program's spans are ``ks.*`` events
+    in this profile too: ONE activation, one clock."""
     started = False
     try:
         jax.profiler.start_trace(log_dir)
@@ -373,6 +386,112 @@ def trace(log_dir: str):
     finally:
         if started:
             jax.profiler.stop_trace()
+
+
+def follow_profiler() -> None:
+    """Make the program's tracing follow a jax profile; called at the
+    public fit entries (``Pipeline.fit``, ``pipelines/timit.py``).
+
+    A profile is being taken and no tracer is active: activate an
+    in-memory one whose spans are also ``ks.<name>`` annotations in the
+    profile (under whatever annotation the caller holds open, on the
+    clock of the device lines). No profile and the active tracer is one
+    this function started: deactivate it; ``obs.last_session()`` keeps
+    it readable. A tracer of ``obs.tracing`` / ``KEYSTONE_TRACE`` is
+    never touched. With no profile and no tracer this is two reads."""
+    if TraceAnnotation.is_enabled():
+        if not _tracer.enabled():
+            _tracer.start_session(TraceAnnotation)
+    elif _tracer.enabled():
+        _tracer.end_session()
+
+
+class CompileClock:
+    """What JAX compiled, from ``jax.monitoring``: programs handed to the
+    backend compiler (persistent-cache look-ups included) and the seconds
+    that took, cumulative, with :meth:`measure` for one block's share — so
+    a phase driven through a public entry point still reports compilation
+    apart from the rest.
+
+    ``record_spans`` (the process's ledger, :func:`compile_ledger`): each
+    trace, lowering and compile-or-fetch that JAX reports is also a
+    ``jax.compile`` span (``stage=trace|lower|backend``, ``fun`` the
+    program's name) of the active tracer, from ``now - duration`` to
+    ``now``, under the innermost span open on the calling thread: the
+    node or solver phase that caused it."""
+
+    _STAGES = {
+        "/jax/core/compile/jaxpr_trace_duration": "trace",
+        "/jax/core/compile/jaxpr_to_mlir_module_duration": "lower",
+        "/jax/core/compile/backend_compile_duration": "backend",
+    }
+    _HIT = "/jax/compilation_cache/cache_hits"
+    _MISS = "/jax/compilation_cache/cache_misses"
+
+    def __init__(self, record_spans: bool = False) -> None:
+        self.record_spans = record_spans
+        self.compile_s = 0.0
+        self.programs = 0
+        self.cache_hits = 0
+        self.cache_misses = 0
+        jax.monitoring.register_event_duration_secs_listener(self._duration)
+        jax.monitoring.register_event_listener(self._event)
+
+    def _duration(self, event: str, duration: float, **kwargs: Any) -> None:
+        stage = self._STAGES.get(event)
+        if stage is None:
+            return
+        if stage == "backend":
+            self.compile_s += duration
+            self.programs += 1
+        tracer = _tracer.active_tracer() if self.record_spans else None
+        if tracer is not None:
+            now = time.perf_counter()
+            tracer.add_span("jax.compile", now - duration, now, stage=stage,
+                            fun=kwargs.get("fun_name"))
+
+    def _event(self, event: str, **_: Any) -> None:
+        if event == self._HIT:
+            self.cache_hits += 1
+        elif event == self._MISS:
+            self.cache_misses += 1
+
+    @contextlib.contextmanager
+    def measure(self) -> Iterator[Dict[str, Any]]:
+        """Yield a dict that is filled in on exit with this block's wall
+        and its compile share."""
+        out: Dict[str, Any] = {}
+        before = (self.compile_s, self.programs, self.cache_hits,
+                  self.cache_misses)
+        t0 = time.perf_counter()
+        try:
+            yield out
+        finally:
+            wall = time.perf_counter() - t0
+            compile_s = self.compile_s - before[0]
+            out.update({
+                "wall_s_smoke": round(wall, 3),
+                "compile_s_smoke": round(compile_s, 3),
+                "non_compile_s_smoke": round(max(wall - compile_s, 0.0), 3),
+                "programs_compiled": self.programs - before[1],
+                "persistent_cache_hits": self.cache_hits - before[2],
+                "persistent_cache_misses": self.cache_misses - before[3],
+            })
+
+
+_LEDGER: Optional[CompileClock] = None
+_LEDGER_LOCK = threading.Lock()
+
+
+def compile_ledger() -> CompileClock:
+    """The process's compile ledger, built — and its listeners registered —
+    at the first call: the first activation of a tracer, or a caller that
+    wants the counts (``chip_smoke.py``). Never before."""
+    global _LEDGER
+    with _LEDGER_LOCK:
+        if _LEDGER is None:
+            _LEDGER = CompileClock(record_spans=True)
+        return _LEDGER
 
 
 def compiled_cost(fn, *args, **kwargs) -> Optional[Dict[str, Any]]:

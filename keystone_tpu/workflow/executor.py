@@ -168,16 +168,22 @@ class GraphExecutor:
         if key is None:
             return
 
+        from keystone_tpu import obs
+
         def drain(value):
-            """Wait out async JAX dispatch on a value's device arrays."""
+            """Wait out async JAX dispatch on a value's device arrays —
+            time the host waits for the device, on the record as an
+            ``executor.drain`` span."""
             try:
                 import jax
 
-                jax.block_until_ready(
-                    [x for x in jax.tree_util.tree_leaves(
-                        getattr(value, "data", value)
-                    ) if hasattr(x, "block_until_ready")]
-                )
+                leaves = [x for x in jax.tree_util.tree_leaves(
+                    getattr(value, "data", value)
+                ) if hasattr(x, "block_until_ready")]
+                if leaves:
+                    with obs.span("executor.drain", site="observe",
+                                  node=graph_id.id):
+                        jax.block_until_ready(leaves)
             except Exception:
                 pass
 

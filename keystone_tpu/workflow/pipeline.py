@@ -182,11 +182,13 @@ class Pipeline(Chainable[A, B]):
         not deep inside an estimator fit. ``KEYSTONE_VERIFY=off``
         disables the pre-pass."""
         from keystone_tpu import obs
+        from keystone_tpu.utils.profiling import follow_profiler
 
         from .env import PipelineEnv
         from .rules import UnusedBranchRemovalRule
         from .verify import verify_fit_graph
 
+        follow_profiler()
         with obs.span("pipeline.fit",
                       nodes=len(self.executor.graph.operators)):
             with obs.span("fit.verify"):
@@ -600,12 +602,18 @@ def _sync_fitted(fitted) -> None:
     state; a device error surfaces here, inside the fit that caused it.
     Results whose arrays hide in closures (chained transformers) are not
     reached — the calibrator's span-window join still sees their fold
-    spans."""
-    jax.block_until_ready(getattr(fitted, "__dict__", None))
+    spans. Only a traced fit holds this barrier; the wait is its own
+    ``executor.drain`` span (the ``executor.node`` above it names the
+    node)."""
+    from keystone_tpu import obs
+
+    with obs.span("executor.drain", site="estimator_sync"):
+        jax.block_until_ready(getattr(fitted, "__dict__", None))
 
 
 def _stamped_fit(est, thunk):
-    """Run one estimator fit, back-annotating a pending cost decision.
+    """Run one estimator fit under an ``estimator.fit`` span (the shared
+    no-op when tracing is off), back-annotating a pending cost decision.
 
     When the cost model selected ``est`` (``LeastSquaresEstimator.
     optimize`` left a ``CostOutcomeRef`` on it), the executor is the one
@@ -614,29 +622,31 @@ def _stamped_fit(est, thunk):
     record (obs/calibrate.py joins predicted-vs-measured from that).
     The ref is consumed BEFORE the fit so a failed fit never stamps a
     bogus measurement and a re-fit never double-stamps. Estimators with
-    no pending decision take the bare path — no span, no timing."""
-    ref = getattr(est, "_pending_cost_outcome", None)
-    if ref is None:
-        return thunk()
-    est._pending_cost_outcome = None
+    no pending decision get the span alone — no barrier, no stamp."""
     import time as _time
 
     from keystone_tpu import obs
 
+    ref = getattr(est, "_pending_cost_outcome", None)
+    if ref is not None:
+        est._pending_cost_outcome = None
     t0 = _time.perf_counter()
     with obs.span("estimator.fit", estimator=type(est).__name__) as sp:
         fitted = thunk()
-        _sync_fitted(fitted)
-    # timing="single_run_cold": a pipeline fits each estimator once, so
-    # this wall INCLUDES XLA compile — the calibrator surfaces the mix
-    # (calibration_report "timings") and the refit discipline prefers
-    # warm rows (docs/observability.md calibration section); the sweep
-    # harness stamps min_of_N_warm on its dispatch-subtracted points.
-    ref.stamp(
-        _time.perf_counter() - t0,
-        span_id=getattr(sp, "span_id", None),
-        timing="single_run_cold",
-    )
+        if ref is not None:
+            _sync_fitted(fitted)
+    if ref is not None:
+        # timing="single_run_cold": a pipeline fits each estimator once,
+        # so this wall INCLUDES XLA compile — the calibrator surfaces the
+        # mix (calibration_report "timings") and the refit discipline
+        # prefers warm rows (docs/observability.md calibration section);
+        # the sweep harness stamps min_of_N_warm on its
+        # dispatch-subtracted points.
+        ref.stamp(
+            _time.perf_counter() - t0,
+            span_id=getattr(sp, "span_id", None),
+            timing="single_run_cold",
+        )
     return fitted
 
 

@@ -629,7 +629,13 @@ class TestOutcomeStamping:
             chosen = est.optimize(s, ls)
             chosen.fit_datasets([data, labels])
             chosen.fit_datasets([data, labels])  # re-fit: no new stamp
-        assert len(t.spans("estimator.fit")) == 1
+        # every traced fit has its ``estimator.fit`` span (PR 26); the
+        # decision stays stamped with the first one's
+        first, second = sorted(t.spans("estimator.fit"), key=lambda s: s["ts_us"])
+        (decision,) = [e for e in t.events if e["name"] == "cost.decision"]
+        assert decision["args"]["outcome"]["span_id"] == first["span_id"]
+        assert decision["args"]["outcome"]["measured_s"] < (
+            second["ts_us"] - first["ts_us"]) / 1e6 + 1e-3
         assert getattr(chosen, "_pending_cost_outcome", None) is None
 
     def test_no_tracer_no_stamp(self):
