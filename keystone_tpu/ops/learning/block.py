@@ -18,6 +18,7 @@ import jax.numpy as jnp
 
 from keystone_tpu import obs
 from keystone_tpu.data import Dataset
+from keystone_tpu.ops.learning.linear import affine_apply
 from keystone_tpu.ops.stats import StandardScaler
 from keystone_tpu.ops.util import VectorSplitter
 from keystone_tpu.parallel import linalg
@@ -56,11 +57,10 @@ class BlockLinearMapper(Transformer):
             out = out + self.b_opt
         return out
 
-    def device_fn(self):
-        """Stage-fusion contract: the whole blockwise model as one
-        row-local array function — center by the concatenated means, one
-        flat GEMM, add the intercept. Lets the apply path fuse with an
-        upstream featurize program into a single dispatch."""
+    def _flat_params(self):
+        """``(W_flat, b, mean, std)`` of the whole blockwise model as one
+        flat affine map (``linear.affine_apply``), or None when a feature
+        scaler is not a mean/std scaler."""
         W_flat = jnp.concatenate(list(self.xs), axis=0)
         mean = std = None
         if self.feature_scalers is not None:
@@ -78,17 +78,27 @@ class BlockLinearMapper(Transformer):
                         for i in range(len(stds))
                     ]
                 )
-        b = self.b_opt
+        return W_flat, self.b_opt, mean, std
 
-        def fn(X):
-            if mean is not None:
-                X = X - mean
-            if std is not None:
-                X = X / std
-            out = X @ W_flat
-            return out if b is None else out + b
+    def device_fn(self):
+        """Stage-fusion contract: the whole blockwise model as one
+        row-local array function — center by the concatenated means, one
+        flat GEMM, add the intercept. Lets the apply path fuse with an
+        upstream featurize program into a single dispatch."""
+        params = self._flat_params()
+        if params is None:
+            return None
+        return lambda X: affine_apply(params, X)
 
-        return fn
+    def device_operands(self):
+        """Operand form: the flat model rides as arguments, so every refit
+        of one geometry applies through one compiled chain."""
+        params = self._flat_params()
+        return None if params is None else ((), params)
+
+    @staticmethod
+    def device_apply(static_key, params, X):
+        return affine_apply(params, X)
 
     def batch_apply(self, data: Dataset) -> Dataset:
         blocks = self.splitter.apply(data)

@@ -18,6 +18,19 @@ from keystone_tpu.parallel import linalg
 from keystone_tpu.workflow import LabelEstimator, Transformer
 
 
+def affine_apply(params, X):
+    """``((X - mean) / std) @ W + b`` with ``params = (W, b, mean, std)``,
+    any of the last three None: the operand-form computation
+    (``Transformer.device_apply``) of the dense linear models."""
+    W, b, mean, std = params
+    if mean is not None:
+        X = X - mean
+    if std is not None:
+        X = X / std
+    out = X @ W
+    return out if b is None else out + b
+
+
 class LinearMapper(Transformer):
     """x -> xᵀX + b, with optional feature scaling
     (reference: LinearMapper.scala:45-62)."""
@@ -43,6 +56,20 @@ class LinearMapper(Transformer):
         """Stage-fusion contract: center-scale + GEMM + intercept as one
         row-local array function, so apply chains fuse through the model."""
         return self.apply
+
+    def device_operands(self):
+        """Operand form: weights, intercept and the scaler's mean/std ride
+        as arguments (absent ones as None), so every refit of one
+        geometry applies through one compiled chain."""
+        scaler = self.feature_scaler
+        if scaler is not None and type(scaler) is not StandardScalerModel:
+            return None
+        mean, std = (None, None) if scaler is None else (scaler.mean, scaler.std)
+        return (), (self.x, self.b_opt, mean, std)
+
+    @staticmethod
+    def device_apply(static_key, params, X):
+        return affine_apply(params, X)
 
 
 class SparseLinearMapper(Transformer):

@@ -98,7 +98,7 @@ class CosineRandomFeaturesModel(Transformer):
         return jnp.cos(jnp.asarray(x) @ self.W.T + self.b)
 
     def _batch_fn(self, X):
-        return jnp.cos(X @ self.W.T + self.b)
+        return self.device_apply((), (self.W, self.b), X)
 
     def device_fn(self):
         """Stage-fusion contract (workflow/fusion.py): row-local cos-GEMM.
@@ -108,6 +108,17 @@ class CosineRandomFeaturesModel(Transformer):
         prefers the Pallas kernel, and fused STREAMED fits recover it via
         the bank extraction (streaming_ls._extract_bank)."""
         return self._batch_fn
+
+    def device_operands(self):
+        """Operand form: the bank rides into a fused program as arguments,
+        so every bank of one shape runs one compiled program (a λ-sweep
+        draws or rebuilds a ~7 MB bank per branch per fit)."""
+        return (), (self.W, self.b)
+
+    @staticmethod
+    def device_apply(static_key, params, X):
+        W, b = params
+        return jnp.cos(X @ W.T + b)
 
     def batch_apply(self, data: Dataset) -> Dataset:
         import jax.tree_util as jtu

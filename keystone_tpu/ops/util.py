@@ -143,6 +143,15 @@ class MaxClassifier(Transformer):
     def device_fn(self):
         return self._batch_fn
 
+    def device_operands(self):
+        # Operand form with nothing to hand over: lets a [model >
+        # MaxClassifier] apply chain keep its program across refits.
+        return (), ()
+
+    @staticmethod
+    def device_apply(static_key, params, X):
+        return jnp.argmax(X, axis=-1)
+
 
 @dataclass(frozen=True)
 class TopKClassifier(Transformer):
@@ -196,9 +205,16 @@ class VectorCombiner(Transformer):
     def device_combine_fn(self):
         """Gather-fusion contract: merge branch ARRAYS inside one program
         (workflow/fusion.py::GatherFusionRule)."""
-        return lambda arrays: jnp.concatenate(
-            [jnp.asarray(a) for a in arrays], axis=-1
-        )
+        return lambda arrays: self.device_combine_apply((), (), arrays)
+
+    def device_combine_operands(self):
+        """Operand form of ``device_combine_fn`` (the combiner's side of
+        ``Transformer.device_operands``): no setting, no array."""
+        return (), ()
+
+    @staticmethod
+    def device_combine_apply(static_key, params, arrays):
+        return jnp.concatenate([jnp.asarray(a) for a in arrays], axis=-1)
 
 
 @dataclass(frozen=True)

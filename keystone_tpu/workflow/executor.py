@@ -113,9 +113,14 @@ class GraphExecutor:
             return
         from keystone_tpu import obs
 
+        # A fused node says how it got its batch program (fusion.py):
+        # "hit" / "miss" of the kept table, or "closure".
+        how = getattr(operator, "fused_program", None)
+        fused = {} if how is None else {"fused_program": how}
+
         def traced():
             with obs.span("executor.node", node=graph_id.id,
-                          operator=type(operator).__name__):
+                          operator=type(operator).__name__, **fused):
                 return orig()
 
         expression._thunk = traced

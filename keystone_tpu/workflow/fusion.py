@@ -25,11 +25,32 @@ Row-local contract for ``device_fn``: output row i depends only on input row
 i (elementwise over the leading axis), so mesh zero-padding rows cannot leak
 into valid rows and a single trailing ``_rezero_padding`` is equivalent to
 per-stage rezeroing.
+
+Where a fused program lives. A member that holds arrays can hand them over
+(``Transformer.device_operands()`` -> ``(static_key, params)``, computed by
+the class-level ``device_apply(static_key, params, X)``; a combiner offers
+``device_combine_operands`` / ``device_combine_apply``). When EVERY member
+of a fused chain or gather does, the batch program takes the members'
+arrays as traced operands and is kept in one table of this module by its
+LOGICAL identity — the kind of fusion and each member's ``(type,
+static_key)`` in order — so a pipeline built afresh (a λ sweep builds one
+per fit, each with new bank arrays) calls the program the first one
+compiled: no trace, no lowering, no constants baked into a new executable.
+Shapes and dtypes are ``jax.jit``'s own cache key under that. The table
+holds callables only, never an array. If any member has only the closure
+form (``device_fn()``), the composition is built as one ``jax.jit`` per
+fused instance over the members' closures, whose arrays become HLO
+constants of that instance's executable. A fused node says which it got in
+``fused_program`` (``"hit"``, ``"miss"`` or ``"closure"``; the node's
+``executor.node`` span carries it) and :func:`fused_program_totals` counts
+them for the process.
 """
 
 from __future__ import annotations
 
-from typing import Dict, List, Sequence
+import functools
+import threading
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
 
@@ -49,6 +70,7 @@ __all__ = [
     "StreamedFitFusionRule",
     "fusable",
     "fused_members",
+    "fused_program_totals",
     "cache_would_split_fusion",
     "fusion_splitting_nodes",
 ]
@@ -151,6 +173,97 @@ def fusion_splitting_nodes(plan, prefixes) -> set:
     }
 
 
+# A handful of logical pipelines per process is the normal case; FIFO keeps
+# a process that fuses many distinct shapes of pipeline from retaining one
+# program (and its executables) per shape for ever.
+_KEPT_PROGRAMS_MAX = 16
+
+# logical key -> jitted ``composed(params, X)``. Callables only: nothing
+# here may pin a dead pipeline's arrays in device memory.
+_KEPT_PROGRAMS: Dict[tuple, Callable] = {}
+_PROGRAM_TOTALS = {"hit": 0, "miss": 0, "closure": 0}
+_KEPT_LOCK = threading.Lock()
+
+
+def fused_program_totals() -> Dict[str, int]:
+    """How the process's fused transformers got their batch program so
+    far: ``hit`` (the kept table had it), ``miss`` (built and kept now),
+    ``closure`` (a member has no operand form: one program per instance)."""
+    with _KEPT_LOCK:
+        return dict(_PROGRAM_TOTALS)
+
+
+def _step(member, combine: bool = False) -> tuple:
+    """One member of a fused program as ``(identity, run, params)``:
+    ``run(params, X)`` computes it and captures no array when the member
+    offers the operand form — ``identity`` is then its ``(type,
+    static_key)``. A member with only the closure form has identity None,
+    no operands, and a ``run`` closed over ``device_fn()``. ``combine``:
+    the member is a gather's combiner (``X`` is the list of branch
+    outputs)."""
+    offer = getattr(
+        member, "device_combine_operands" if combine else "device_operands",
+        None,
+    )
+    form = offer() if offer is not None else None
+    if form is None:
+        fn = member.device_combine_fn() if combine else member.device_fn()
+        return None, (lambda _params, X: fn(X)), ()
+    static_key, params = form
+    cls = type(member)
+    apply = cls.device_combine_apply if combine else cls.device_apply
+    return (cls, static_key), functools.partial(apply, static_key), tuple(params)
+
+
+def _batch_program(branches, combiner=None) -> Tuple[Callable, str]:
+    """THE composition routine of the fused transformers. ``branches``:
+    member chains over one input (a plain chain is one branch and no
+    combiner); ``combiner`` merges their outputs. Returns the X-only batch
+    function and how its program was obtained (``fused_program``).
+
+    When every member offers the operand form, the jitted ``composed(params,
+    X)`` is kept by the members' identities and the returned function only
+    binds this instance's arrays to it; otherwise it is jitted for this
+    instance, with the closures' arrays inside."""
+    steps = [[_step(m) for m in br] for br in branches]
+    combine = None if combiner is None else _step(combiner, combine=True)
+    runs = [[run for _, run, _ in br] for br in steps]
+    combine_run = None if combine is None else combine[1]
+
+    def composed(params, X):
+        branch_params, combine_params = params
+        outs = []
+        for branch_runs, ps in zip(runs, branch_params):
+            b = X
+            for run, p in zip(branch_runs, ps):
+                b = run(p, b)
+            outs.append(b)
+        if combine_run is None:
+            return outs[0]
+        return combine_run(combine_params, outs)
+
+    operands = (
+        tuple(tuple(p for _, _, p in br) for br in steps),
+        () if combine is None else combine[2],
+    )
+    idents = [ident for br in steps for ident, _, _ in br]
+    if combine is not None:
+        idents.append(combine[0])
+    closures = None in idents
+    key = (tuple(len(br) for br in steps), combine is not None, tuple(idents))
+    with _KEPT_LOCK:
+        program = None if closures else _KEPT_PROGRAMS.get(key)
+        how = "closure" if closures else "miss" if program is None else "hit"
+        if program is None:
+            program = jax.jit(composed)
+            if not closures:
+                if len(_KEPT_PROGRAMS) >= _KEPT_PROGRAMS_MAX:
+                    _KEPT_PROGRAMS.pop(next(iter(_KEPT_PROGRAMS)))
+                _KEPT_PROGRAMS[key] = program
+        _PROGRAM_TOTALS[how] += 1
+    return (lambda X: program(operands, X)), how
+
+
 class FusedBatchTransformer(Transformer):
     """A chain of row-local transformers compiled as one program.
 
@@ -170,14 +283,7 @@ class FusedBatchTransformer(Transformer):
         self._build_composed()
 
     def _build_composed(self) -> None:
-        fns = [m.device_fn() for m in self.members]
-
-        def composed(X):
-            for f in fns:
-                X = f(X)
-            return X
-
-        self._composed = jax.jit(composed)
+        self._composed, self.fused_program = _batch_program([self.members])
 
     # The jitted closure is not picklable; FittedPipeline.save() pickles the
     # whole transformer graph (the serializable-pipeline contract,
@@ -305,20 +411,13 @@ class FusedGatherTransformer(Transformer):
         self.uses_packed_fft = packed is not None
         if packed is not None:
             self._composed = jax.jit(packed)
+            self.fused_program = "closure"
+            with _KEPT_LOCK:
+                _PROGRAM_TOTALS["closure"] += 1
             return
-        branch_fns = [[m.device_fn() for m in br] for br in self.branches]
-        combine = self.combiner.device_combine_fn()
-
-        def composed(X):
-            outs = []
-            for fns in branch_fns:
-                b = X
-                for f in fns:
-                    b = f(b)
-                outs.append(b)
-            return combine(outs)
-
-        self._composed = jax.jit(composed)
+        self._composed, self.fused_program = _batch_program(
+            self.branches, self.combiner
+        )
 
     # Same pickling contract as FusedBatchTransformer: jitted closures are
     # rebuilt on load.

@@ -13,7 +13,7 @@ yields a serializable transformer-only pipeline.
 from __future__ import annotations
 
 import cloudpickle as pickle
-from typing import Any, Callable, Dict, Generic, List, Optional, Sequence, TypeVar, Union
+from typing import Any, Callable, Dict, Generic, List, Optional, Sequence, Tuple, TypeVar, Union
 
 import jax
 import jax.numpy as jnp
@@ -520,8 +520,31 @@ class Transformer(TransformerOperator, Chainable[A, B]):
         one. Implementing it opts the node into whole-pipeline stage fusion
         (workflow/fusion.py): chains of such nodes compile into ONE XLA
         program. Contract: row-local (output row i depends only on input
-        row i) and side-effect free."""
+        row i) and side-effect free.
+
+        A node that holds arrays should also offer the OPERAND form
+        (:meth:`device_operands` + :meth:`device_apply`): ``device_fn()``
+        is then that form closed over the node's own arrays, and a fused
+        program made of such nodes is kept across pipelines — a new node
+        with new arrays of the same shapes runs the program already
+        compiled, where a closure would bake its arrays into a new one."""
         return None
+
+    def device_operands(self) -> Optional[Tuple[Any, tuple]]:
+        """Operand form of ``device_fn``: ``(static_key, params)`` — a
+        hashable key holding every non-array setting the computation
+        depends on, and a tuple of the node's arrays — or None (default)
+        when the node only has the closure form. Contract:
+        ``type(self).device_apply(static_key, params, X)`` equals
+        ``self.device_fn()(X)``."""
+        return None
+
+    @staticmethod
+    def device_apply(static_key, params, X):
+        """The computation of the operand form: a pure function of its
+        arguments, resolved through the CLASS, that captures no array — so
+        one traced program serves every instance with an equal key."""
+        raise NotImplementedError
 
     def __call__(self, x: Any) -> Any:
         """Eager application to a datum or Dataset; lazy on pipeline handles."""
