@@ -92,6 +92,7 @@ from keystone_tpu.obs.tracer import (
     event,
     last_session,
     record_cost_decision,
+    set_on_open,
     span,
     start_session,
     tracing,
@@ -130,6 +131,7 @@ __all__ = [
     "write_calibration_artifact",
     "render_flight_record",
     "render_prometheus",
+    "set_on_open",
     "span",
     "start_session",
     "to_chrome_trace",

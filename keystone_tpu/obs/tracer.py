@@ -57,6 +57,7 @@ __all__ = [
     "event",
     "last_session",
     "record_cost_decision",
+    "set_on_open",
     "span",
     "start_session",
     "tracing",
@@ -113,6 +114,17 @@ def span(name: str, **attrs) -> Any:
     if t is None:
         return _NOOP
     return t.span(name, **attrs)
+
+
+def set_on_open(name: str, **attrs) -> None:
+    """Add attributes to the innermost span called ``name`` that is open
+    on the calling thread — for code that runs INSIDE a span someone else
+    opened (an estimator's ``fit`` under the executor's ``estimator.fit``)
+    and knows what the opener could not. No such span, or no tracer: a
+    no-op."""
+    t = _ACTIVE
+    if t is not None:
+        t.set_on_open(name, **attrs)
 
 
 def event(name: str, **attrs) -> None:
@@ -317,19 +329,27 @@ class Tracer:
             self.dropped += 1
         self._records.append(rec)
 
-    def _stack(self) -> List[int]:
+    def _stack(self) -> List[Span]:
+        """The spans open on the calling thread, outermost first."""
         st = getattr(self._tls, "stack", None)
         if st is None:
             st = []
             self._tls.stack = st
         return st
 
+    def set_on_open(self, name: str, **attrs) -> None:
+        """:func:`set_on_open` under this tracer."""
+        for sp in reversed(self._stack()):
+            if sp.name == name:
+                sp.set(**attrs)
+                return
+
     def _open(self, sp: Span) -> None:
         st = self._stack()
         with self._lock:
             sp.span_id = next(self._ids)
-        sp.parent_id = st[-1] if st else None
-        st.append(sp.span_id)
+        sp.parent_id = st[-1].span_id if st else None
+        st.append(sp)
         th = threading.current_thread()
         with self._lock:
             self._open_spans[sp.span_id] = {
@@ -339,12 +359,12 @@ class Tracer:
 
     def _close(self, sp: Span, t0: float, t1: float) -> None:
         st = self._stack()
-        # Pop our own id (tolerate a corrupted stack rather than
+        # Pop ourselves (tolerate a corrupted stack rather than
         # poisoning the traced code path with an assertion).
-        if st and st[-1] == sp.span_id:
+        if st and st[-1] is sp:
             st.pop()
-        elif sp.span_id in st:
-            st.remove(sp.span_id)
+        elif sp in st:
+            st.remove(sp)
         th = threading.current_thread()
         rec = {
             "type": "span",
@@ -385,7 +405,7 @@ class Tracer:
         a histogram bucket can carry)."""
         th = threading.current_thread()
         st = self._stack()
-        parent_id = st[-1] if st else None
+        parent_id = st[-1].span_id if st else None
         with self._lock:
             sid = next(self._ids)
             self._append_locked({

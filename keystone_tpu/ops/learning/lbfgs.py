@@ -18,6 +18,7 @@ optimizer pays (Breeze's Wolfe search in the reference, LBFGS.scala:87-103).
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import logging
 from typing import Optional
@@ -72,6 +73,7 @@ def run_lbfgs(
     convergence_tol: float = 1e-4,
     n: Optional[int] = None,
     W_init=None,
+    info: Optional[dict] = None,
 ):
     """Minimize the ridge least-squares loss with L-BFGS.
 
@@ -81,7 +83,8 @@ def run_lbfgs(
     through the gather/segment-sum sparse kernels and the dense design
     matrix never exists. Y: (n_pad, k) labels. Returns (d, k). The whole
     optimization loop (two-loop direction, exact quadratic step, convergence
-    test) is a single compiled while_loop on device.
+    test) is a single compiled while_loop on device. ``info``, when given,
+    receives ``loss`` and ``iterations`` (the steps the loop ran).
     """
     Y = jnp.asarray(Y)
     if isinstance(X, dict):
@@ -112,15 +115,29 @@ def run_lbfgs(
         else jnp.zeros((X.shape[1], Y.shape[1]), dtype=dtype)
     )
 
-    W, final_loss = _lbfgs_core(
+    W, final_loss, count = _lbfgs_core(
         X, Y, W0,
         jnp.asarray(lam, dtype=dtype),
         jnp.asarray(num_iterations),
         jnp.asarray(convergence_tol, dtype=dtype),
         jnp.asarray(n, dtype=dtype),
     )
-    logger.info("LBFGS final loss: %s", float(final_loss))
+    _report_solve(final_loss, count, info, "LBFGS")
     return W
+
+
+def _report_solve(final_loss, count, info: Optional[dict], what: str) -> None:
+    """Read a finished solve's loss and iteration count — the host's one
+    wait for the device in a sparse fit, spanned as such — and hand them
+    to the log, the ``lbfgs.iterations`` counter and the caller's ``info``."""
+    from keystone_tpu import obs
+
+    with obs.span("executor.drain", site="solver_loss"):
+        final_loss, count = jax.device_get((final_loss, count))
+    logger.info("%s final loss: %s", what, float(final_loss))
+    obs.counter_track("lbfgs.iterations", int(count))
+    if info is not None:
+        info.update(loss=float(final_loss), iterations=int(count))
 
 
 _LBFGS_HISTORY = 10  # standard L-BFGS memory
@@ -130,7 +147,8 @@ def _lbfgs_quad_loop(hvp, AtB, W0, lam, num_iterations, tol):
     """The L-BFGS loop on the ridge quadratic, generic over the Hessian
     apply: ``hvp`` may be the data-pass form Aᵀ(A·)/n + λ· or the
     Gramian form G·/n + λ· — algebraically identical operators, so the
-    iterate sequences coincide (up to summation order). Traceable."""
+    iterate sequences coincide (up to summation order). Traceable.
+    Returns ``(W, iterations run)``."""
     history = _LBFGS_HISTORY
     dtype = W0.dtype
 
@@ -199,8 +217,8 @@ def _lbfgs_quad_loop(hvp, AtB, W0, lam, num_iterations, tol):
     Y0 = jnp.zeros((history, d, k), dtype=dtype)
     rho0 = jnp.zeros((history,), dtype=dtype)
     carry = (W0, grad0, S0, Y0, rho0, 0, jnp.linalg.norm(grad0))
-    W, *_ = jax.lax.while_loop(cond, step, carry)
-    return W
+    W, _, _, _, _, count, _ = jax.lax.while_loop(cond, step, carry)
+    return W, count
 
 
 def _lbfgs_body(X, Y, W0, lam, num_iterations, tol, n):
@@ -215,8 +233,8 @@ def _lbfgs_body(X, Y, W0, lam, num_iterations, tol, n):
         return _rmatmul(X, _matmul(X, P), d) / n + lam * P
 
     AtB = _rmatmul(X, Y, d) / n  # constant term of the gradient
-    W = _lbfgs_quad_loop(hvp, AtB, W0, lam, num_iterations, tol)
-    return W, least_squares_loss(W, X, Y, lam, n)
+    W, count = _lbfgs_quad_loop(hvp, AtB, W0, lam, num_iterations, tol)
+    return W, least_squares_loss(W, X, Y, lam, n), count
 
 
 @jax.jit
@@ -240,14 +258,15 @@ def _lbfgs_gram_core(G, AtY, yty, W0, lam, num_iterations, tol, n):
             jnp.dot(G, P, precision=jax.lax.Precision.HIGHEST) / n + lam * P
         )
 
-    W = _lbfgs_quad_loop(hvp, AtY / n, W0, lam, num_iterations, tol)
-    # ½‖AW−Y‖²/n + ½λ‖W‖² expanded through G/AtY/yty (no data pass).
-    data_loss = 0.5 * (
-        jnp.sum(W * jnp.dot(G, W, precision=jax.lax.Precision.HIGHEST))
-        - 2.0 * jnp.sum(W * AtY)
-        + yty
-    ) / n
-    return W, data_loss + 0.5 * lam * jnp.sum(W * W)
+    with jax.named_scope("ks.lbfgs_gram"):  # names the phase in a device profile
+        W, count = _lbfgs_quad_loop(hvp, AtY / n, W0, lam, num_iterations, tol)
+        # ½‖AW−Y‖²/n + ½λ‖W‖² expanded through G/AtY/yty (no data pass).
+        data_loss = 0.5 * (
+            jnp.sum(W * jnp.dot(G, W, precision=jax.lax.Precision.HIGHEST))
+            - 2.0 * jnp.sum(W * AtY)
+            + yty
+        ) / n
+        return W, data_loss + 0.5 * lam * jnp.sum(W * W), count
 
 
 class DenseLBFGSwithL2(LabelEstimator):
@@ -281,7 +300,7 @@ class DenseLBFGSwithL2(LabelEstimator):
             Fc, Yc, fmean, ymean = masked_center(F, Y, n_true)
             dtype = jnp.result_type(Fc.dtype, Yc.dtype)
             W0 = jnp.zeros((Fc.shape[1], Yc.shape[1]), dtype=dtype)
-            W, _ = _lbfgs_body(
+            W, *_ = _lbfgs_body(
                 Fc.astype(dtype), Yc.astype(dtype), W0,
                 lam.astype(dtype),
                 jnp.asarray(self.num_iterations),
@@ -400,12 +419,19 @@ def run_lbfgs_gram_streamed(
     checkpoint=None,
     mesh=None,
     mesh_axis: Optional[str] = None,
+    info: Optional[dict] = None,
 ):
     """Streamed sparse ridge fit: fold G = AᵀA over COO chunks ONCE
     (``sparse.sparse_gram_stream`` — chunks may be regenerated/loaded per
     call, so the full dataset never exists on device), then run the SAME
     L-BFGS iterates as the gather path against G at one (d, d)×(d, k)
-    GEMM per iteration. Returns (W (d, k), final_loss).
+    GEMM per iteration. Returns (W (d, k), final_loss); ``info``, when
+    given, receives ``iterations`` — the steps the loop ran, a device
+    scalar like the loss (reading either waits for the fit).
+
+    ``lam``, ``num_iterations``, ``convergence_tol`` and ``n`` ride into
+    the compiled programs as OPERANDS: a ridge sweep reuses one program
+    (as constants every new ``lam`` compiled the whole chunk scan anew).
 
     ``operands``: arrays ``chunk_fn`` slices from, passed as
     ``chunk_fn(cid, *operands)``. Resident buffers MUST ride here — a
@@ -481,6 +507,7 @@ def run_lbfgs_gram_streamed(
     there so a global ``--checkpoint-dir`` drill never breaks
     single-dispatch fits.
     """
+    from keystone_tpu import obs
     from keystone_tpu.data.durable import (
         fingerprint_token,
         resolve_checkpoint,
@@ -489,6 +516,7 @@ def run_lbfgs_gram_streamed(
 
     if n is None:
         raise ValueError("streamed fit needs the true row count n")
+    hyper = _solve_operands(lam, num_iterations, convergence_tol, n)
     if mesh is not None:
         if checkpoint is not None:
             raise ValueError(
@@ -496,15 +524,14 @@ def run_lbfgs_gram_streamed(
                 "carry is a per-device partial on every chip (snapshot "
                 "would need a gather); drop checkpoint= or mesh="
             )
-        return _run_lbfgs_gram_streamed_mesh(
+        return _solved(info, _run_lbfgs_gram_streamed_mesh(
             chunk_fn, int(num_chunks), int(d), int(k), mesh,
-            mesh_axis=mesh_axis, lam=lam, num_iterations=num_iterations,
-            convergence_tol=convergence_tol, n=n, use_pallas=use_pallas,
+            mesh_axis=mesh_axis, hyper=hyper, use_pallas=use_pallas,
             val_dtype=val_dtype, operands=operands,
             max_chunks_per_dispatch=max_chunks_per_dispatch,
             segment_sources=segment_source, inflight=inflight,
             prefetch_depth=prefetch_depth, prefetch_stats=prefetch_stats,
-        )
+        ))
     explicit_checkpoint = checkpoint is not None
     checkpoint = resolve_checkpoint(checkpoint)
     seg = max_chunks_per_dispatch
@@ -539,11 +566,13 @@ def run_lbfgs_gram_streamed(
                 "are fold boundaries to snapshot at"
             )
         program = _gram_streamed_program(
-            chunk_fn, int(num_chunks), int(d), int(k), float(lam),
-            int(num_iterations), float(convergence_tol), int(n),
+            chunk_fn, int(num_chunks), int(d), int(k),
             bool(use_pallas), jnp.dtype(val_dtype), bool(pipeline),
         )
-        return program(tuple(operands))
+        # One dispatch holds the fold AND the solve: both spans' work.
+        with obs.span("solver.gram_fold", chunks=int(num_chunks),
+                      dispatches=1, with_solve=True):
+            return _solved(info, program(tuple(operands), hyper))
 
     from keystone_tpu.ops.sparse import sparse_gram_init
     from keystone_tpu.parallel.streaming import BoundedInflight
@@ -560,10 +589,7 @@ def run_lbfgs_gram_streamed(
             chunk_fn, int(num_chunks), int(d), int(k), int(seg),
             bool(use_pallas), jnp.dtype(val_dtype), bool(pipeline),
         )
-    solve = _gram_solve_program(
-        int(d), int(k), float(lam), int(num_iterations),
-        float(convergence_tol), int(n), jnp.dtype(val_dtype),
-    )
+    solve = _gram_solve_program(int(d), int(k), jnp.dtype(val_dtype))
     num_segs = -(-int(num_chunks) // int(seg))
     carry = None
     start_seg = 0
@@ -609,29 +635,32 @@ def run_lbfgs_gram_streamed(
                                   stats=prefetch_stats)
 
     def finish():
-        result = solve(carry)
+        with obs.span("solver.lbfgs", engine="gram"):
+            result = _solved(info, solve(carry, hyper))
         if checkpoint is not None:
             checkpoint.clear(fingerprint)  # this fit's snapshot only
         return result
 
-    if source is not None:
-        from keystone_tpu.data.prefetch import iter_segments
+    with obs.span("solver.gram_fold", chunks=int(num_chunks),
+                  dispatches=num_segs - start_seg, with_solve=False):
+        if source is not None:
+            from keystone_tpu.data.prefetch import iter_segments
 
-        for s, ops in iter_segments(
-            source, prefetch_depth=prefetch_depth, stats=prefetch_stats,
-            start=start_seg,
-        ):
-            folded(s * int(seg), ops)
-            maybe_snapshot(s)
-        return finish()
-    for s in range(start_seg, num_segs):
-        cid0 = s * int(seg)
-        if segment_source is not None:
-            ops = segment_source(int(cid0), int(seg))
+            for s, ops in iter_segments(
+                source, prefetch_depth=prefetch_depth, stats=prefetch_stats,
+                start=start_seg,
+            ):
+                folded(s * int(seg), ops)
+                maybe_snapshot(s)
         else:
-            ops = operands
-        folded(cid0, ops)
-        maybe_snapshot(s)
+            for s in range(start_seg, num_segs):
+                cid0 = s * int(seg)
+                if segment_source is not None:
+                    ops = segment_source(int(cid0), int(seg))
+                else:
+                    ops = operands
+                folded(cid0, ops)
+                maybe_snapshot(s)
     return finish()
 
 
@@ -743,11 +772,10 @@ def run_lbfgs_gram_hybrid(
                               seg):
                 folded(fold_tail, cid0, ())
 
-    solve = _gram_solve_program(
-        int(d), int(k), float(lam), int(num_iterations),
-        float(convergence_tol), int(n), jnp.dtype(val_dtype),
-    )
-    return solve(carry)
+    solve = _gram_solve_program(int(d), int(k), jnp.dtype(val_dtype))
+    return _solved(None, solve(
+        carry, _solve_operands(lam, num_iterations, convergence_tol, n)
+    ))
 
 
 @functools.lru_cache(maxsize=16)
@@ -806,26 +834,42 @@ def _gram_fold_program_rel(chunk_fn, num_chunks, d, k, seg, use_pallas,
     return fold
 
 
+def _solve_operands(lam, num_iterations, convergence_tol, n):
+    """(lam, num_iterations, tol, n) as the scalar operands of a compiled
+    solve: hyperparameters are traced, so they never trigger recompiles."""
+    return (
+        jnp.asarray(lam, jnp.float32),
+        jnp.asarray(num_iterations, jnp.int32),
+        jnp.asarray(convergence_tol, jnp.float32),
+        jnp.asarray(n, jnp.float32),
+    )
+
+
+def _solved(info: Optional[dict], result):
+    """(W, loss) of a compiled solve's (W, loss, iterations); the count
+    goes to the caller's ``info``, unread (a device scalar)."""
+    W, loss, count = result
+    if info is not None:
+        info["iterations"] = count
+    return W, loss
+
+
 @functools.lru_cache(maxsize=16)
-def _gram_solve_program(d, k, lam, num_iterations, convergence_tol, n,
-                        val_dtype):
-    """Compiled finalize + L-BFGS-on-G tail of the segmented fold."""
+def _gram_solve_program(d, k, val_dtype):
+    """Compiled finalize + L-BFGS-on-G tail of the segmented fold:
+    ``solve(carry, hyper)`` with ``hyper`` of :func:`_solve_operands`."""
     from keystone_tpu.ops.sparse import gram_finalize, gram_pad_dim
 
     d_pad = gram_pad_dim(d, val_dtype)
 
     @jax.jit
-    def solve(carry):
+    def solve(carry, hyper):
         G, AtY, yty = carry
-        W, loss = _lbfgs_gram_core(
+        W, loss, count = _lbfgs_gram_core(
             gram_finalize(G), AtY, yty,
-            jnp.zeros((d_pad, k), jnp.float32),
-            jnp.asarray(lam, jnp.float32),
-            jnp.asarray(num_iterations),
-            jnp.asarray(convergence_tol, jnp.float32),
-            jnp.asarray(n, jnp.float32),
+            jnp.zeros((d_pad, k), jnp.float32), *hyper,
         )
-        return W[:d], loss
+        return W[:d], loss, count
 
     return solve
 
@@ -924,8 +968,7 @@ def _gram_fold_program_mesh(chunk_fn, num_chunks, d, k, seg, use_pallas,
 
 
 @functools.lru_cache(maxsize=8)
-def _gram_mesh_solve_program(d, k, lam, num_iterations, convergence_tol, n,
-                             val_dtype, mesh, axis):
+def _gram_mesh_solve_program(d, k, val_dtype, mesh, axis):
     """The fit's ONE cross-device collective: ``lax.psum`` of the
     (G, AtY, yty) pytree over ``axis`` (a pytree psum lowers to a single
     fused all-reduce over the ICI), replicated out, then the standard
@@ -945,21 +988,18 @@ def _gram_mesh_solve_program(d, k, lam, num_iterations, convergence_tol, n,
         out_specs=(P(), P(), P()),
         check_vma=False,
     )
-    solve = _gram_solve_program(
-        d, k, lam, num_iterations, convergence_tol, n, val_dtype
-    )
+    solve = _gram_solve_program(d, k, val_dtype)
 
-    def run(carry):
-        return solve(reduce(*carry))
+    def run(carry, hyper):
+        return solve(reduce(*carry), hyper)
 
     return run
 
 
 def _run_lbfgs_gram_streamed_mesh(
-    chunk_fn, num_chunks, d, k, mesh, *, mesh_axis, lam, num_iterations,
-    convergence_tol, n, use_pallas, val_dtype, operands,
-    max_chunks_per_dispatch, segment_sources, inflight, prefetch_depth,
-    prefetch_stats,
+    chunk_fn, num_chunks, d, k, mesh, *, mesh_axis, hyper, use_pallas,
+    val_dtype, operands, max_chunks_per_dispatch, segment_sources, inflight,
+    prefetch_depth, prefetch_stats,
 ):
     """Mesh driver for :func:`run_lbfgs_gram_streamed` (ISSUE 16): the
     host loop dispatches one shard_map fold per LOCAL segment (all
@@ -999,8 +1039,7 @@ def _run_lbfgs_gram_streamed_mesh(
 
     carry = _mesh_gram_init(d, k, val_dtype, mesh, axis)
     solve = _gram_mesh_solve_program(
-        int(d), int(k), float(lam), int(num_iterations),
-        float(convergence_tol), int(n), jnp.dtype(val_dtype), mesh, axis,
+        int(d), int(k), jnp.dtype(val_dtype), mesh, axis,
     )
 
     if segment_sources is not None:
@@ -1034,7 +1073,7 @@ def _run_lbfgs_gram_streamed_mesh(
                 for i in range(len(payloads[0]))
             )
             carry = step(fold, carry, s * int(seg), ops)
-        return solve(carry)
+        return solve(carry, hyper)
 
     # Resident path: pad the chunk axis to m·cpd and shard it so each
     # device holds exactly its contiguous shard (8-chip chip-residency).
@@ -1058,7 +1097,7 @@ def _run_lbfgs_gram_streamed_mesh(
     )
     for cid0 in range(0, cpd, seg):
         carry = step(fold, carry, cid0, ops)
-    return solve(carry)
+    return solve(carry, hyper)
 
 
 def pipeline_ok(seg: int) -> bool:
@@ -1068,21 +1107,21 @@ def pipeline_ok(seg: int) -> bool:
 
 
 @functools.lru_cache(maxsize=16)
-def _gram_streamed_program(chunk_fn, num_chunks, d, k, lam, num_iterations,
-                           convergence_tol, n, use_pallas, val_dtype,
+def _gram_streamed_program(chunk_fn, num_chunks, d, k, use_pallas, val_dtype,
                            pipeline=True):
     """Compiled streamed-fit program, cached per (chunk_fn identity, fit
     geometry). Building the jit inside every call would make EVERY fit —
     including the timed second run of a warm benchmark — retrace and
     recompile the whole chunk scan (~30 s at Amazon geometry). Callers
     therefore pass a STABLE chunk_fn (module-level function or one object
-    reused across fits), with per-fit arrays in ``operands``."""
+    reused across fits), with per-fit arrays in ``operands`` and the
+    hyperparameters in ``hyper`` (:func:`_solve_operands`)."""
     from keystone_tpu.ops.sparse import gram_pad_dim, sparse_gram_stream
 
     d_pad = gram_pad_dim(d, val_dtype)
 
     @jax.jit
-    def _run(operands):
+    def _run(operands, hyper):
         def cf(cid):
             return chunk_fn(cid, *operands)
 
@@ -1093,16 +1132,61 @@ def _gram_streamed_program(chunk_fn, num_chunks, d, k, lam, num_iterations,
         # Solve at the padded width: padded rows of AtY are zero and G's
         # padded rows/cols are zero, so those W rows stay exactly zero
         # through every iterate (pure-λ ridge on a zero gradient).
-        W, loss = _lbfgs_gram_core(
-            G, AtY, yty, jnp.zeros((d_pad, k), jnp.float32),
-            jnp.asarray(lam, jnp.float32),
-            jnp.asarray(num_iterations),
-            jnp.asarray(convergence_tol, jnp.float32),
-            jnp.asarray(n, jnp.float32),
+        W, loss, count = _lbfgs_gram_core(
+            G, AtY, yty, jnp.zeros((d_pad, k), jnp.float32), *hyper,
         )
-        return W[:d], loss
+        return W[:d], loss, count
 
     return _run
+
+
+def _with_intercept_lane(indices, values, d: int, n: int):
+    """Append the ones column as one more active lane at index ``d``
+    (LBFGS.scala:208-281 learns the intercept jointly); padding rows past
+    ``n`` get an inactive (−1) lane."""
+    valid = jnp.arange(indices.shape[0]) < n
+    idx1 = jnp.concatenate(
+        [indices, jnp.where(valid, d, -1)[:, None].astype(indices.dtype)],
+        axis=1,
+    )
+    val1 = jnp.concatenate(
+        [values, valid.astype(values.dtype)[:, None]], axis=1
+    )
+    return idx1, val1
+
+
+@dataclasses.dataclass(frozen=True)
+class _LanedRowChunks:
+    """Chunk source over the caller's OWN padded-COO rows: chunk ``cid`` is
+    rows ``[cid·c, (cid+1)·c)`` sliced from the ``(n_pad, w)`` operands
+    inside the fold program, the intercept lane (index ``d``, value 1)
+    appended to the slice — a ``(c, w+1)`` transient where the laned and
+    tiled copies were two more datasets in HBM. A ragged last chunk
+    starts at ``n_pad − c`` (a slice never leaves the array) and masks
+    the rows an earlier chunk already folded; rows past the true ``n``
+    are masked dead like any padding. Frozen, so equal sources hash
+    alike and fits of one geometry share a compiled program."""
+
+    chunk_rows: int
+    d: int
+    n: int
+
+    def __call__(self, cid, indices, values, Y):
+        c = self.chunk_rows
+        start = jnp.minimum(cid * c, indices.shape[0] - c)
+        rows = start + jnp.arange(c)
+        live = (rows >= cid * c) & (rows < self.n)
+        idx = jax.lax.dynamic_slice_in_dim(indices, start, c, 0)
+        val = jax.lax.dynamic_slice_in_dim(values, start, c, 0)
+        Yc = jax.lax.dynamic_slice_in_dim(Y, start, c, 0)
+        idx1 = jnp.concatenate(
+            [jnp.where(live[:, None], idx, -1),
+             jnp.where(live, self.d, -1)[:, None].astype(idx.dtype)], axis=1,
+        )
+        val1 = jnp.concatenate(
+            [val, jnp.ones((c, 1), val.dtype)], axis=1
+        )
+        return idx1, val1, jnp.where(live[:, None], Yc, 0).astype(jnp.float32)
 
 
 class SparseLBFGSwithL2(LabelEstimator):
@@ -1199,74 +1283,84 @@ class SparseLBFGSwithL2(LabelEstimator):
         return self.num_iterations + 1
 
     def fit(self, data: Dataset, labels: Dataset):
+        from keystone_tpu import obs
         from keystone_tpu.ops.sparse import is_sparse_dataset
         from keystone_tpu.ops.learning.linear import SparseLinearMapper
 
         B = jnp.asarray(labels.array)
+        info: dict = {}
         if is_sparse_dataset(data):
             indices = jnp.asarray(data.data["indices"])
             values = jnp.asarray(data.data["values"])
             d = self.num_features or int(jnp.max(indices)) + 1
-            npad = indices.shape[0]
-            # Append-ones column at index d learns the intercept jointly
-            # (LBFGS.scala:208-281); padding rows get an inactive (−1) lane.
-            valid = jnp.arange(npad) < data.n
-            idx1 = jnp.concatenate(
-                [indices, jnp.where(valid, d, -1)[:, None].astype(indices.dtype)],
-                axis=1,
-            )
-            val1 = jnp.concatenate(
-                [values, valid.astype(values.dtype)[:, None]], axis=1
-            )
             if self.solver == "gram":
-                W1 = self._fit_gram(idx1, val1, B, d + 1, data.n)
+                W1 = self._fit_gram(indices, values, B, d, data.n, info)
             else:
-                dtype = jnp.result_type(values.dtype, B.dtype)
-                W1 = run_lbfgs(
-                    {"indices": idx1, "values": val1}, B, lam=self.lam,
-                    num_iterations=self.num_iterations,
-                    convergence_tol=self.convergence_tol,
-                    n=data.n,
-                    W_init=jnp.zeros((d + 1, B.shape[1]), dtype=dtype),
-                )
-            return SparseLinearMapper(W1[:-1], b_opt=W1[-1])
-
-        A = jnp.asarray(data.array)
-        npad = A.shape[0]
-        ones = (jnp.arange(npad) < data.n).astype(A.dtype)[:, None]
-        A1 = jnp.concatenate([A, ones], axis=1)
-        W1 = run_lbfgs(
-            A1, B, lam=self.lam,
-            num_iterations=self.num_iterations,
-            convergence_tol=self.convergence_tol,
-            n=data.n,
-        )
-        return LinearMapper(W1[:-1], b_opt=W1[-1])
-
-    def _fit_gram(self, idx1, val1, B, d1: int, n: int):
-        """Gram-engine fit over RESIDENT padded-COO buffers: pre-chunk the
-        rows host-side (padding chunks with inactive lanes), fold G once,
-        iterate on it. With ``compress="int16_bf16"`` the operands are
-        encoded through the compressed-resident tier
-        (``data/resident.py``) first — 4 bytes/nnz resident, decode
-        fused into the fold's densify casts."""
-        c = min(self.gram_chunk_rows, idx1.shape[0])
-        if self.compress == "int16_bf16":
-            from keystone_tpu.data.resident import CompressedCOOChunks
-
-            chunks = CompressedCOOChunks.encode(
-                np.asarray(idx1), np.asarray(val1), np.asarray(B),
-                chunk_rows=c, d=d1, n_true=n,
-            )
-            idx_t, val_t, Y_t = chunks.operands()
-            nchunks = chunks.num_chunks
+                obs.set_on_open("estimator.fit", engine="gather", d_pad=d + 1)
+                with obs.span("solver.gather_lbfgs"):
+                    idx1, val1 = _with_intercept_lane(indices, values, d, data.n)
+                    dtype = jnp.result_type(values.dtype, B.dtype)
+                    W1 = run_lbfgs(
+                        {"indices": idx1, "values": val1}, B, lam=self.lam,
+                        num_iterations=self.num_iterations,
+                        convergence_tol=self.convergence_tol,
+                        n=data.n,
+                        W_init=jnp.zeros((d + 1, B.shape[1]), dtype=dtype),
+                        info=info,
+                    )
+            mapper = SparseLinearMapper(W1[:-1], b_opt=W1[-1])
         else:
-            from keystone_tpu.data.resident import raw_chunk_tiles
+            A = jnp.asarray(data.array)
+            npad = A.shape[0]
+            ones = (jnp.arange(npad) < data.n).astype(A.dtype)[:, None]
+            A1 = jnp.concatenate([A, ones], axis=1)
+            W1 = run_lbfgs(
+                A1, B, lam=self.lam,
+                num_iterations=self.num_iterations,
+                convergence_tol=self.convergence_tol,
+                n=data.n, info=info,
+            )
+            mapper = LinearMapper(W1[:-1], b_opt=W1[-1])
+        # What the solve ran, for whoever audits the fit (plain numbers).
+        mapper.lbfgs_iterations = info.get("iterations")
+        mapper.lbfgs_loss = info.get("loss")
+        return mapper
 
-            idx_t, val_t, Y_t = raw_chunk_tiles(idx1, val1, B, c)
-            nchunks = int(idx_t.shape[0])
-
+    def _fit_gram(self, indices, values, B, d: int, n: int, info: dict):
+        """Gram-engine fit over RESIDENT padded-COO buffers: fold G once
+        over chunks of ``gram_chunk_rows`` rows, iterate on it. The raw
+        tier folds straight from the caller's arrays — each chunk is
+        sliced, and its intercept lane appended, INSIDE the fold program
+        (:class:`_LanedRowChunks`), so no laned or tiled copy of the
+        dataset is ever made (at the Amazon cell's 4.2M rows each such
+        copy is 2.8 GB beside the caller's own). With
+        ``compress="int16_bf16"`` the operands are encoded through the
+        compressed-resident tier (``data/resident.py``) first — 4
+        bytes/nnz resident, decode fused into the fold's densify casts."""
+        from keystone_tpu import obs
         from keystone_tpu.ops import pallas_ops
+        from keystone_tpu.ops.sparse import gram_pad_dim
+
+        d1 = d + 1
+        npad, lanes = int(indices.shape[0]), int(indices.shape[1]) + 1
+        c = min(self.gram_chunk_rows, npad)
+        with obs.span("solver.chunk_tiles", compress=self.compress):
+            if self.compress == "int16_bf16":
+                from keystone_tpu.data.resident import CompressedCOOChunks
+
+                idx1, val1 = _with_intercept_lane(indices, values, d, n)
+                chunks = CompressedCOOChunks.encode(
+                    np.asarray(idx1), np.asarray(val1), np.asarray(B),
+                    chunk_rows=c, d=d1, n_true=n,
+                )
+                del idx1, val1
+                operands = chunks.operands()
+                chunk_fn = _resident_chunk_fn  # stable identity -> program reuse
+                nchunks = chunks.num_chunks
+            else:
+                operands = (indices, values, B)
+                chunk_fn = _LanedRowChunks(c, d, int(n))  # equal across fits
+                nchunks = -(-npad // c)
 
         if self.gram_dtype == "f32":
             # Explicit f32 wins even over bf16-compressed values: the
@@ -1276,27 +1370,37 @@ class SparseLBFGSwithL2(LabelEstimator):
         elif (
             self.compress is not None
             or self.gram_dtype == "bf16"
-            or val1.dtype == jnp.bfloat16
+            or values.dtype == jnp.bfloat16
         ):
             val_dtype = jnp.bfloat16
         else:
             val_dtype = jnp.float32
+        use_pallas = pallas_ops.pallas_direct_ok(*operands)
+        obs.set_on_open(
+            "estimator.fit", engine="gram", compress=self.compress,
+            slab_dtype=jnp.dtype(val_dtype).name, chunks=nchunks,
+            d_pad=gram_pad_dim(d1, val_dtype), pallas=bool(use_pallas),
+        )
+        solved: dict = {}
         W, final_loss = run_lbfgs_gram_streamed(
-            _resident_chunk_fn,  # stable identity -> compiled-program reuse
-            nchunks, d1, B.shape[1],
+            chunk_fn, nchunks, d1, B.shape[1],
             lam=self.lam, num_iterations=self.num_iterations,
             convergence_tol=self.convergence_tol, n=n,
-            use_pallas=pallas_ops.pallas_direct_ok(val_t),
+            use_pallas=use_pallas,
             val_dtype=val_dtype,
-            operands=(idx_t, val_t, Y_t),
+            operands=operands,
             # Resident operands already hold the whole dataset: the
             # double-buffered second slab would be pure extra HBM beside
             # them (the measured resident-capacity cliff sits at n=30e6 /
             # 9.8 GB — bench.py's probe), and there is no regen work to
             # overlap — chunks are slices of the resident buffers.
             pipeline=False,
+            info=solved,
         )
-        logger.info("LBFGS(gram) final loss: %s", float(final_loss))
+        obs.counter_track("sparse.rows_folded", nchunks * c)
+        obs.counter_track("sparse.nnz_folded", nchunks * c * lanes)
+        with obs.span("solver.lbfgs", engine="gram", stage="read"):
+            _report_solve(final_loss, solved["iterations"], info, "LBFGS(gram)")
         return W
 
     # Measured on-chip calibration (BENCH_r04 amazon row): the gram

@@ -325,6 +325,7 @@ def sparse_gram_fold(
         carry = sparse_gram_init(d, k, val_dtype)
     d_pad = carry[0].shape[0]
 
+    @jax.named_scope("ks.sparse_densify")  # names the phase in a device profile
     def densify_chunk(cid):
         indices, values, Yc = chunk_fn(cid)
         c, w = indices.shape
@@ -342,6 +343,7 @@ def sparse_gram_fold(
     )[0]
     fused = use_pallas and pallas_ops.gram_corr_acc_ok(slab_shape)
 
+    @jax.named_scope("ks.sparse_gram_acc")
     def fold_slab(G, AtY, yty, dense, Yc):
         if fused:
             G, AtY = pallas_ops.gram_corr_sym_acc(G, AtY, dense, Yc)
