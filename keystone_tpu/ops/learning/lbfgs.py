@@ -1140,6 +1140,43 @@ def _gram_streamed_program(chunk_fn, num_chunks, d, k, use_pallas, val_dtype,
     return _run
 
 
+# Largest per-row sum of |value| the range probe lets through: with the
+# intercept lane's 1 every scatter-added slab entry, and every partial sum
+# on the way to it, is an integer of magnitude <= 256 — the last integer
+# below which bfloat16 (8 significant bits) has no gaps.
+_BF16_EXACT_ROW_SUM = 255.0
+
+
+@jax.jit
+def _slabs_exact_in_bf16(values, Y):
+    """Whether a bfloat16 slab fold of these rows and targets is the SAME
+    fold as the float32 one (a device scalar; one pass over the whole
+    arrays, nothing sampled, no array made). Sufficient, not necessary:
+
+    - every value is an integer and no row's |value|s sum past
+      ``_BF16_EXACT_ROW_SUM`` — what binary and count term frequencies
+      are. The densify scatter-ADDS a row's lanes in the slab's type, so a
+      row that repeats an id sums its values there: under this bound every
+      entry and every partial sum is an integer of magnitude <= 256, which
+      bfloat16 holds exactly, even when a stray id lands on the intercept
+      column's 1. Masked lanes are counted as if live (refuses more, never
+      wrongly admits); a NaN fails the integer test, an infinity the sum.
+    - the targets survive the round trip through bfloat16, because the
+      accumulate kernels round them to the slab's type.
+
+    A product of two such slab entries, or of one and a target, is then
+    exact in the float32 accumulator (<= 17 significant bits), so G and
+    AᵀY come out as the float32 slabs would give them: bit for bit where
+    the sums are themselves exact in float32 (counts below 2²⁴), and to
+    the order of summation beyond."""
+    v = values.astype(jnp.float32)
+    y = Y.astype(jnp.float32)
+    rows_ok = jnp.all(v == jnp.round(v)) & (
+        jnp.max(jnp.sum(jnp.abs(v), axis=1)) <= _BF16_EXACT_ROW_SUM
+    )
+    return rows_ok & jnp.all(y.astype(jnp.bfloat16).astype(jnp.float32) == y)
+
+
 def _with_intercept_lane(indices, values, d: int, n: int):
     """Append the ones column as one more active lane at index ``d``
     (LBFGS.scala:208-281 learns the intercept jointly); padding rows past
@@ -1210,6 +1247,16 @@ class SparseLBFGSwithL2(LabelEstimator):
         Amazon geometry when iterations > ~2, at the cost of a (d_pad)²
         f32 Gramian in HBM — prefer it whenever d ≲ 40k.
 
+    ``gram_dtype`` (gram solver only) is the type of the densified slabs.
+    ``None`` (default) follows the values' range: float32 values that are
+    exact in bfloat16 — integers whose |value|s sum to at most 255 a row,
+    with targets that survive the round trip (one device reduction over
+    the whole arrays, read by the host before the fold program is chosen)
+    — fold in ONE MXU pass through bfloat16 slabs, to the same Gramian;
+    any other float32 values fold through float32 slabs (six passes).
+    ``"f32"`` forces the latter, ``"bf16"`` forces bfloat16 slabs on any
+    values (lossy: the data is rounded inside the fold).
+
     ``compress`` (gram solver only) selects the COMPRESSED-RESIDENT
     storage class (``data/resident.py``, ISSUE 8): ``"int16_bf16"``
     encodes the padded-COO operands at 4 bytes/nnz (int16 index + bf16
@@ -1264,12 +1311,13 @@ class SparseLBFGSwithL2(LabelEstimator):
         self.solver = solver
         self.compress = compress
         self.gram_chunk_rows = gram_chunk_rows
-        # Densified-slab dtype for the gram fold. None follows the input
-        # values' dtype; "bf16" folds f32 inputs through bf16 slabs — the
-        # MXU-native single-pass recipe (~6x the 6-pass f32 syrk), at the
-        # cost of bf16-quantizing the DATA inside the fold (G error ~0.4%
-        # relative — the iterates shift by the same order; quantified in
-        # tests/test_sparse_gram.py).
+        # Densified-slab dtype for the gram fold (class docstring). None
+        # follows the input values' RANGE (_slabs_exact_in_bf16): bf16
+        # slabs — the MXU-native single-pass recipe, ~6x the 6-pass f32
+        # syrk — only where they give the same Gramian. "bf16" folds ANY
+        # f32 input through them, at the cost of bf16-quantizing the DATA
+        # inside the fold (G error ~0.4% relative — the iterates shift by
+        # the same order; quantified in tests/test_sparse_gram.py).
         self.gram_dtype = gram_dtype
         # Resolved at CONSTRUCTION like the selector's cpu/mem/network
         # weights (cost.py) — a mid-process KEYSTONE_COST_WEIGHTS flip
@@ -1362,6 +1410,7 @@ class SparseLBFGSwithL2(LabelEstimator):
                 chunk_fn = _LanedRowChunks(c, d, int(n))  # equal across fits
                 nchunks = -(-npad // c)
 
+        probed = {}  # slab_exact, where the range probe decided and no flag
         if self.gram_dtype == "f32":
             # Explicit f32 wins even over bf16-compressed values: the
             # slabs upcast losslessly and the syrk runs the exact 6-pass
@@ -1373,13 +1422,23 @@ class SparseLBFGSwithL2(LabelEstimator):
             or values.dtype == jnp.bfloat16
         ):
             val_dtype = jnp.bfloat16
+        elif isinstance(values, jax.core.Tracer) or isinstance(B, jax.core.Tracer):
+            val_dtype = jnp.float32  # a fit under a trace: nothing to observe
         else:
-            val_dtype = jnp.float32
+            # No flag decides: the slab type follows the values' RANGE.
+            # The verdict is a static key of the fold program, so the host
+            # reads it first — its one other wait for the device.
+            verdict = _slabs_exact_in_bf16(values, B)
+            with obs.span("executor.drain", site="slab_probe"):
+                exact = probed["slab_exact"] = bool(jax.device_get(verdict))
+            val_dtype = jnp.bfloat16 if exact else jnp.float32
+            obs.counter_track("sparse.exact_bf16_fits", int(exact))
         use_pallas = pallas_ops.pallas_direct_ok(*operands)
         obs.set_on_open(
             "estimator.fit", engine="gram", compress=self.compress,
             slab_dtype=jnp.dtype(val_dtype).name, chunks=nchunks,
             d_pad=gram_pad_dim(d1, val_dtype), pallas=bool(use_pallas),
+            **probed,
         )
         solved: dict = {}
         W, final_loss = run_lbfgs_gram_streamed(

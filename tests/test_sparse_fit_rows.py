@@ -1,7 +1,9 @@
 """The sparse L-BFGS fit as the Amazon cell drives it (PR 28): chunks sliced
 and laned inside the fold program instead of copied, hyperparameters as
 operands of the compiled solve, the iteration count on the fitted mapper,
-and the spans, attributes and counters the fit leaves under a tracer."""
+the spans, attributes and counters the fit leaves under a tracer, and the
+slab type that follows the values' range (PR 29): rows and targets that
+bfloat16 holds exactly fold through bfloat16 slabs to the same Gramian."""
 
 import jax
 import jax.numpy as jnp
@@ -12,6 +14,7 @@ from keystone_tpu import obs
 from keystone_tpu.data import Dataset
 from keystone_tpu.ops.learning import lbfgs
 from keystone_tpu.ops.learning.lbfgs import SparseLBFGSwithL2
+from keystone_tpu.ops.sparse import sparse_gram_stream
 
 
 def rows(n=700, d=96, w=5, seed=0):
@@ -102,13 +105,16 @@ def test_spans_attributes_and_counters_under_a_tracer_and_nothing_without():
         assert name in names, names
     gram, gather = tracer.spans("estimator.fit")
     assert gram["args"] == {"estimator": "SparseLBFGSwithL2", "engine": "gram", "compress": None,
-                            "slab_dtype": "float32", "chunks": 4, "d_pad": 512, "pallas": False}
+                            "slab_dtype": "float32", "slab_exact": False, "chunks": 4, "d_pad": 512,
+                            "pallas": False}  # normal values: the probe ran and refused
     assert gather["args"]["engine"] == "gather"
-    drains = [s for s in tracer.spans("executor.drain") if s["args"].get("site") == "solver_loss"]
-    assert len(drains) == 2  # the one wait of each fit, filed as a wait
+    sites = [s["args"].get("site") for s in tracer.spans("executor.drain")]
+    assert sites.count("solver_loss") == 2  # the one wait of each fit, filed as a wait
+    assert sites.count("slab_probe") == 1  # and the gram fit's read of the probe's verdict
     counters = [(e["name"], e["value"]) for e in tracer.events if e["type"] == "counter"]
     assert ("sparse.rows_folded", 512.0) in counters and ("sparse.nnz_folded", 512.0 * 6) in counters
     assert counters.count(("lbfgs.iterations", 6.0)) == 2
+    assert counters.count(("sparse.exact_bf16_fits", 0.0)) == 1
 
 
 def test_set_on_open_reaches_the_innermost_span_of_that_name():
@@ -130,3 +136,153 @@ def test_name_scopes_of_the_sparse_fold_are_in_the_lowered_program():
                          lbfgs._solve_operands(1e-3, 5, 1e-4, 512)).as_text(debug_info=True)
     for scope in ("ks.sparse_densify", "ks.sparse_gram_acc", "ks.lbfgs_gram"):
         assert scope in text, scope
+
+
+# -- the slab type follows the values' range (PR 29) -------------------------
+
+GRAM = dict(lam=1e-3, num_iterations=60, convergence_tol=1e-6, num_features=96, solver="gram",
+            gram_chunk_rows=256)  # 700 rows = 2 x 256 + a ragged 188
+
+
+def exact_rows(kind="binary", n=700, d=96, w=5, seed=0):
+    """Rows as sparse text featurizers emit them — binary or count (1-3)
+    term frequencies, float32 — with +-1 targets; row 3 repeats an id, so
+    the densify scatter-ADDS two of its lanes into one slab entry."""
+    data, _ = rows(n, d, w, seed)
+    rng = np.random.default_rng(seed + 1)
+    idx = np.array(data.data["indices"])
+    idx[3, 1] = idx[3, 0]
+    val = np.ones((n, w), np.float32) if kind == "binary" else rng.integers(1, 4, (n, w)).astype(np.float32)
+    Y = np.where(rng.normal(size=(n, 2)) > 0, 1.0, -1.0).astype(np.float32)
+    return Dataset({"indices": jnp.asarray(idx), "values": jnp.asarray(val)}, n=n), Dataset.of(jnp.asarray(Y))
+
+
+def with_values(data, fill):
+    """``data`` with row 5's values replaced by ``fill`` (one per lane)."""
+    val = np.array(data.data["values"])
+    val[5] = fill
+    return Dataset({"indices": data.data["indices"], "values": jnp.asarray(val)}, n=data.n)
+
+
+def folded(data, labels, val_dtype, d=96, chunk_rows=256):
+    """(G, AtY) of the fit's own fold on the common (d + 1) block."""
+    source = lbfgs._LanedRowChunks(chunk_rows, d, data.n)
+    fold = jax.jit(lambda i, v, y: sparse_gram_stream(
+        lambda cid: source(cid, i, v, y), -(-data.n // chunk_rows), d + 1, 2,
+        val_dtype=val_dtype, pipeline=False))
+    G, AtY, _ = fold(data.data["indices"], data.data["values"], labels.array)
+    return np.asarray(G)[:d + 1, :d + 1], np.asarray(AtY)[:d + 1]
+
+
+def traced_fit(data, labels, **how):
+    with obs.tracing() as tracer:
+        model = SparseLBFGSwithL2(**{**GRAM, **how}).fit_datasets([data, labels])
+    (span,) = tracer.spans("estimator.fit")
+    return model, span["args"], tracer
+
+
+@pytest.mark.parametrize("kind", ["binary", "counts"])
+def test_rows_exact_in_bfloat16_fold_to_the_same_gramian_in_one_pass(kind):
+    data, labels = exact_rows(kind)
+    G16, AtY16 = folded(data, labels, jnp.bfloat16)
+    G32, AtY32 = folded(data, labels, jnp.float32)
+    np.testing.assert_array_equal(G16, G32)
+    np.testing.assert_array_equal(AtY16, AtY32)
+    assert G32[:96, :96].max() > 2  # the repeated id and the counts are in it
+
+
+@pytest.mark.parametrize("kind", ["binary", "counts"])
+def test_no_flag_and_exact_rows_fit_through_bfloat16_slabs(kind):
+    data, labels = exact_rows(kind)
+    got, attrs, tracer = traced_fit(data, labels)
+    want, flagged, _ = traced_fit(data, labels, gram_dtype="f32")
+    assert (attrs["slab_dtype"], attrs["slab_exact"], attrs["d_pad"]) == ("bfloat16", True, 1024)
+    # (c) the explicit flag wins, and no probe runs for it
+    assert (flagged["slab_dtype"], flagged["d_pad"]) == ("float32", 512) and "slab_exact" not in flagged
+    np.testing.assert_allclose(weights(got), weights(want), rtol=0, atol=1e-6)
+    assert got.lbfgs_iterations == want.lbfgs_iterations
+    counters = [(e["name"], e["value"]) for e in tracer.events if e["type"] == "counter"]
+    assert ("sparse.exact_bf16_fits", 1.0) in counters
+    assert [s["args"]["site"] for s in tracer.spans("executor.drain")] == ["slab_probe", "solver_loss"]
+
+
+REFUSED = {
+    "normal_values": lambda data, labels: (rows()[0], labels),
+    "a_value_of_a_tenth": lambda data, labels: (with_values(data, 0.1), labels),
+    "a_row_summing_to_257": lambda data, labels: (with_values(data, [253, 1, 1, 1, 1]), labels),
+    # 256 + the intercept's 1 would be 257 should a stray id land on the ones column
+    "a_row_summing_to_256": lambda data, labels: (with_values(data, [-252, 1, 1, 1, 1]), labels),
+    "a_nan": lambda data, labels: (with_values(data, np.nan), labels),
+    "targets_of_a_tenth": lambda data, labels: (data, Dataset.of(0.1 * labels.array)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(REFUSED))
+def test_the_probe_refuses_what_bfloat16_would_round(case):
+    """float32 slabs, and weights equal to the bit with the flagged float32
+    fit's — the parent's path, the same compiled program."""
+    data, labels = REFUSED[case](*exact_rows())
+    got, attrs, _ = traced_fit(data, labels, num_iterations=8)
+    want, _, _ = traced_fit(data, labels, num_iterations=8, gram_dtype="f32")
+    assert (attrs["slab_dtype"], attrs["slab_exact"], attrs["d_pad"]) == ("float32", False, 512)
+    np.testing.assert_array_equal(weights(got), weights(want))
+
+
+@pytest.mark.parametrize("fill,exact", [([251, 1, 1, 1, 1], True), ([-251, 1, -1, 1, 1], True),
+                                         ([-0.0, 0, 0, 0, 0], True), ([252, 1, 1, 1, 1], False),
+                                         ([np.inf, 0, 0, 0, 0], False), ([1e30, 0, 0, 0, 0], False),
+                                         ([0.5, 0.5, 0, 0, 0], False)])
+def test_the_probe_draws_its_line_at_a_row_sum_of_255(fill, exact):
+    data, labels = exact_rows()
+    verdict = lbfgs._slabs_exact_in_bf16(with_values(data, fill).data["values"], labels.array)
+    assert bool(verdict) is exact
+
+
+def test_a_wider_rule_would_be_caught_a_repeated_id_summing_to_257_rounds_in_bfloat16():
+    """Every VALUE of this row is exact in bfloat16 (128, 129 and ones);
+    their sum in one slab entry is not: a probe that looked at the values
+    alone would change the Gramian."""
+    data, labels = exact_rows()
+    idx = np.array(data.data["indices"])
+    idx[5, 1] = idx[5, 0]
+    data = with_values(Dataset({"indices": jnp.asarray(idx), "values": data.data["values"]}, n=data.n),
+                       [128, 129, 1, 1, 1])
+    assert not bool(lbfgs._slabs_exact_in_bf16(data.data["values"], labels.array))
+    col = idx[5, 0]
+    assert folded(data, labels, jnp.float32)[0][col, col] - folded(data, labels, jnp.bfloat16)[0][col, col] \
+        == 257.0 ** 2 - 256.0 ** 2
+
+
+def test_values_under_a_trace_cannot_be_observed_and_keep_float32_slabs(monkeypatch):
+    data, labels = exact_rows()
+    seen = {}
+
+    def no_fold(*args, val_dtype, info, **kwargs):
+        seen.update(val_dtype=val_dtype)
+        info.update(iterations=0)
+        return jnp.zeros((97, 2)), 0.0
+
+    monkeypatch.setattr(lbfgs, "run_lbfgs_gram_streamed", no_fold)
+    monkeypatch.setattr(lbfgs, "_report_solve", lambda *a, **k: None)  # its read cannot be traced either
+    est = SparseLBFGSwithL2(**GRAM)
+    jax.make_jaxpr(lambda v: est._fit_gram(data.data["indices"], v, labels.array, 96, 700, {}))(
+        data.data["values"])
+    assert seen["val_dtype"] == jnp.float32
+
+
+def test_the_selectors_gram_choice_folds_binary_rows_in_bfloat16_and_counts_the_fits():
+    """Through the public entry under the sparse cell's rehearsal budget
+    (16 MB, one machine): no engine and no slab type named anywhere."""
+    from keystone_tpu.ops.learning.cost import LeastSquaresEstimator
+    from keystone_tpu.workflow import PipelineEnv
+
+    data, labels = exact_rows(n=4096, d=1024, w=12)
+    with obs.tracing() as tracer:
+        for lam in (1e-3, 1e-4):
+            PipelineEnv.get_or_create().reset()
+            LeastSquaresEstimator(lam=lam, hbm_bytes=16e6, num_machines=1).with_data(data, labels).fit()
+    fits = [s["args"] for s in tracer.spans("estimator.fit")]
+    assert [(a["engine"], a["slab_dtype"], a["slab_exact"]) for a in fits] == [("gram", "bfloat16", True)] * 2
+    counted = [e["value"] for e in tracer.events
+               if e["type"] == "counter" and e["name"] == "sparse.exact_bf16_fits"]
+    assert sum(counted) == 2.0
