@@ -135,42 +135,43 @@ class Convolver(Transformer):
         conv.patch_size = p
         return conv
 
-    def _convolve(self, images):
-        # The declared compute dtype is float32 (declares_dtype_change):
-        # narrow float64 loader output HERE, before any arithmetic, so the
-        # eager apply() path and the compiled _batch_fn path agree — the
-        # einsum's preferred_element_type alone would otherwise leave the
-        # patch normalization running in f64 on the eager path.
-        images = jnp.asarray(images, jnp.float32)
-        # The XLA path is the stated path: Mosaic refuses the fused Pallas
-        # form (ops/pallas_images.py — chip run, PR 21), so it is not
-        # dispatched from here.
-        patches = im2col(images, self.patch_size)
-        if self.normalize_patches:
-            patches = normalize_patch_rows(patches, self.var_constant)
-        if self.whitener is not None:
-            patches = patches - self.whitener.means
-        return jnp.einsum(
-            "nxyd,kd->nxyk", patches, self.filters,
-            preferred_element_type=jnp.float32,
-        )
-
-    def apply(self, img):
-        batch, single = _as_batch(img)
-        out = self._convolve(batch)
-        return out[0] if single else out
-
     # The convolution computes in float32 BY DESIGN (filters are cast at
     # construction, the einsum pins preferred_element_type): float64
     # image input narrowing to f32 here is the declared compute dtype,
     # not silent drift — tell the plan verifier so (workflow/verify.py).
     declares_dtype_change = True
 
-    def _batch_fn(self, X):
-        return self._convolve(jnp.asarray(X, jnp.float32))
+    def apply(self, img):
+        batch, single = _as_batch(img)
+        out = self.device_fn()(batch)
+        return out[0] if single else out
 
-    def device_fn(self):
-        return self._batch_fn
+    def device_operands(self):
+        means = None if self.whitener is None else self.whitener.means
+        key = (self.patch_size, bool(self.normalize_patches),
+               float(self.var_constant))
+        return key, (self.filters, means)
+
+    @staticmethod
+    def device_apply(static_key, params, X):
+        patch_size, normalize_patches, var_constant = static_key
+        filters, means = params
+        # Narrow float64 loader output HERE, before any arithmetic: the
+        # einsum's preferred_element_type alone would otherwise leave the
+        # patch normalization running in f64.
+        images = jnp.asarray(X, jnp.float32)
+        # The XLA path is the stated path: Mosaic refuses the fused Pallas
+        # form (ops/pallas_images.py — chip run, PR 21), so it is not
+        # dispatched from here.
+        patches = im2col(images, patch_size)
+        if normalize_patches:
+            patches = normalize_patch_rows(patches, var_constant)
+        if means is not None:
+            patches = patches - means
+        return jnp.einsum(
+            "nxyd,kd->nxyk", patches, filters,
+            preferred_element_type=jnp.float32,
+        )
 
 
 class Pooler(Transformer):
@@ -198,44 +199,51 @@ class Pooler(Transformer):
             raise ValueError(f"unknown pool_function {pool_function}")
         self.pool_function = pool_function
 
-    def _pool(self, images):
-        n, X, Y, C = images.shape
-        if self.pixel_function is not None:
-            images = self.pixel_function(images)
-        start = self.pool_size // 2
-        npx = -(-(X - start) // self.stride)  # ceil
-        npy = -(-(Y - start) // self.stride)
-        ext_x = (npx - 1) * self.stride + self.pool_size
-        ext_y = (npy - 1) * self.stride + self.pool_size
-        pad_val = -jnp.inf if self.pool_function == "max" else 0.0
+    def apply(self, img):
+        batch, single = _as_batch(img)
+        out = self.device_fn()(batch)
+        return out[0] if single else out
+
+    def device_operands(self):
+        # The pixel function rides in the key by identity: a new lambda
+        # per instance is a new key (and one bounded entry of the
+        # kept-program table), a shared module-level function is one key.
+        return (
+            (self.stride, self.pool_size, self.pixel_function,
+             self.pool_function),
+            (),
+        )
+
+    @staticmethod
+    def device_apply(static_key, params, X):
+        stride, pool_size, pixel_function, pool_function = static_key
+        images = jnp.asarray(X, jnp.float32)
+        _, nx, ny, _ = images.shape
+        if pixel_function is not None:
+            images = pixel_function(images)
+        start = pool_size // 2
+        npx = -(-(nx - start) // stride)  # ceil
+        npy = -(-(ny - start) // stride)
+        ext_x = (npx - 1) * stride + pool_size
+        ext_y = (npy - 1) * stride + pool_size
+        pad_val = -jnp.inf if pool_function == "max" else 0.0
         images = jnp.pad(
             images,
-            ((0, 0), (0, max(0, ext_x - X)), (0, max(0, ext_y - Y)), (0, 0)),
+            ((0, 0), (0, max(0, ext_x - nx)), (0, max(0, ext_y - ny)), (0, 0)),
             constant_values=pad_val,
         )
         images = images[:, :ext_x, :ext_y, :]
         init, op = (
-            (-jnp.inf, lax.max) if self.pool_function == "max" else (0.0, lax.add)
+            (-jnp.inf, lax.max) if pool_function == "max" else (0.0, lax.add)
         )
         return lax.reduce_window(
             images,
             jnp.asarray(init, images.dtype),
             op,
-            window_dimensions=(1, self.pool_size, self.pool_size, 1),
-            window_strides=(1, self.stride, self.stride, 1),
+            window_dimensions=(1, pool_size, pool_size, 1),
+            window_strides=(1, stride, stride, 1),
             padding="VALID",
         )
-
-    def apply(self, img):
-        batch, single = _as_batch(img)
-        out = self._pool(batch)
-        return out[0] if single else out
-
-    def _batch_fn(self, X):
-        return self._pool(jnp.asarray(X, jnp.float32))
-
-    def device_fn(self):
-        return self._batch_fn
 
 
 class Windower(Transformer):
@@ -278,13 +286,12 @@ class SymmetricRectifier(Transformer):
         self.max_val = max_val
         self.alpha = alpha
 
-    def _rectify(self, x):
-        pos = jnp.maximum(self.max_val, x - self.alpha)
-        neg = jnp.maximum(self.max_val, -x - self.alpha)
+    def device_operands(self):
+        return (float(self.max_val), float(self.alpha)), ()
+
+    @staticmethod
+    def device_apply(static_key, params, X):
+        max_val, alpha = static_key
+        pos = jnp.maximum(max_val, X - alpha)
+        neg = jnp.maximum(max_val, -X - alpha)
         return jnp.concatenate([pos, neg], axis=-1)
-
-    def apply(self, img):
-        return self._rectify(jnp.asarray(img))
-
-    def device_fn(self):
-        return self._rectify

@@ -44,24 +44,23 @@ class LabelExtractor(Transformer):
 class GrayScaler(Transformer):
     """RGB -> luminance (reference: nodes/images/GrayScaler.scala)."""
 
-    def apply(self, img):
-        return image_utils.to_grayscale(img)
+    def device_operands(self):
+        return (), ()
 
-    def device_fn(self):
-        return image_utils.to_grayscale
+    @staticmethod
+    def device_apply(static_key, params, X):
+        return image_utils.to_grayscale(X)
 
 
 class PixelScaler(Transformer):
     """Rescale byte pixels to [0, 1) (reference: nodes/images/PixelScaler.scala)."""
 
-    def apply(self, img):
-        return jnp.asarray(img, jnp.float32) / 255.0
+    def device_operands(self):
+        return (), ()
 
-    def _batch_fn(self, X):
+    @staticmethod
+    def device_apply(static_key, params, X):
         return jnp.asarray(X, jnp.float32) / 255.0
-
-    def device_fn(self):
-        return self._batch_fn
 
 
 class Cropper(Transformer):
@@ -86,14 +85,12 @@ class ImageVectorizer(Transformer):
     """Flatten an image to a vector, row-major over (x, y, c)
     (reference: nodes/images/ImageVectorizer.scala)."""
 
-    def apply(self, img):
-        return jnp.asarray(img).reshape(-1)
+    def device_operands(self):
+        return (), ()
 
-    def _batch_fn(self, X):
+    @staticmethod
+    def device_apply(static_key, params, X):
         return X.reshape(X.shape[0], -1)
-
-    def device_fn(self):
-        return self._batch_fn
 
 
 class RandomImageTransformer(Transformer):

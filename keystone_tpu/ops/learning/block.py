@@ -80,19 +80,13 @@ class BlockLinearMapper(Transformer):
                 )
         return W_flat, self.b_opt, mean, std
 
-    def device_fn(self):
+    def device_operands(self):
         """Stage-fusion contract: the whole blockwise model as one
         row-local array function — center by the concatenated means, one
-        flat GEMM, add the intercept. Lets the apply path fuse with an
-        upstream featurize program into a single dispatch."""
-        params = self._flat_params()
-        if params is None:
-            return None
-        return lambda X: affine_apply(params, X)
-
-    def device_operands(self):
-        """Operand form: the flat model rides as arguments, so every refit
-        of one geometry applies through one compiled chain."""
+        flat GEMM, add the intercept — so the apply path fuses with an
+        upstream featurize program into a single dispatch. The flat model
+        rides as arguments, so every refit of one geometry applies
+        through one compiled chain."""
         params = self._flat_params()
         return None if params is None else ((), params)
 
@@ -188,12 +182,12 @@ class BlockLeastSquaresEstimator(LabelEstimator):
         from keystone_tpu.workflow.fusion import DeviceFit, masked_center
         from keystone_tpu.ops.stats import StandardScalerModel
 
-        bs = self.block_size
+        bs, num_iter = self.block_size, self.num_iter
 
         def fit_fn(F, Y, n_true: int, lam):
             Fc, Yc, fmean, ymean = masked_center(F, Y, n_true)
             W_stack = linalg.bcd_least_squares_fused_flat(
-                Fc, Yc, bs, lam=lam, num_iter=self.num_iter
+                Fc, Yc, bs, lam=lam, num_iter=num_iter
             )
             return W_stack, fmean, ymean
 
@@ -218,7 +212,7 @@ class BlockLeastSquaresEstimator(LabelEstimator):
         return DeviceFit(
             fit_fn, build, supports,
             operands=(jnp.asarray(self.lam, jnp.float32),),
-            program_key=("BlockLS", bs, self.num_iter, self.num_features),
+            program_key=("BlockLS", bs, num_iter, self.num_features),
         )
 
     def fit(self, data: Dataset, labels: Dataset) -> BlockLinearMapper:

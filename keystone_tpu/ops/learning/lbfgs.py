@@ -296,6 +296,8 @@ class DenseLBFGSwithL2(LabelEstimator):
         from keystone_tpu.ops.stats import StandardScalerModel
         from keystone_tpu.workflow.fusion import DeviceFit, masked_center
 
+        num_iterations, tol = self.num_iterations, self.convergence_tol
+
         def fit_fn(F, Y, n_true: int, lam):
             Fc, Yc, fmean, ymean = masked_center(F, Y, n_true)
             dtype = jnp.result_type(Fc.dtype, Yc.dtype)
@@ -303,8 +305,8 @@ class DenseLBFGSwithL2(LabelEstimator):
             W, *_ = _lbfgs_body(
                 Fc.astype(dtype), Yc.astype(dtype), W0,
                 lam.astype(dtype),
-                jnp.asarray(self.num_iterations),
-                jnp.asarray(self.convergence_tol, dtype),
+                jnp.asarray(num_iterations),
+                jnp.asarray(tol, dtype),
                 jnp.asarray(n_true, dtype),
             )
             return W, fmean, ymean
@@ -318,9 +320,7 @@ class DenseLBFGSwithL2(LabelEstimator):
         return DeviceFit(
             fit_fn, build,
             operands=(jnp.asarray(self.lam, jnp.float32),),
-            program_key=(
-                "DenseLBFGS", self.num_iterations, self.convergence_tol,
-            ),
+            program_key=("DenseLBFGS", num_iterations, tol),
         )
 
     def fit(self, data: Dataset, labels: Dataset) -> LinearMapper:

@@ -52,15 +52,14 @@ class LinearMapper(Transformer):
     def batch_apply(self, data: Dataset) -> Dataset:
         return data.map_batch(self.apply)
 
-    def device_fn(self):
-        """Stage-fusion contract: center-scale + GEMM + intercept as one
-        row-local array function, so apply chains fuse through the model."""
-        return self.apply
-
     def device_operands(self):
-        """Operand form: weights, intercept and the scaler's mean/std ride
-        as arguments (absent ones as None), so every refit of one
-        geometry applies through one compiled chain."""
+        """Stage-fusion contract: center-scale + GEMM + intercept as one
+        row-local array function, so apply chains fuse through the model.
+        Weights, intercept and the scaler's mean/std ride as arguments
+        (absent ones as None), so every refit of one geometry applies
+        through one compiled chain. A feature scaler that is not a
+        mean/std scaler has no such form: the model then applies as a
+        node of its own (``batch_apply``)."""
         scaler = self.feature_scaler
         if scaler is not None and type(scaler) is not StandardScalerModel:
             return None

@@ -137,52 +137,45 @@ class StreamingFeaturizedLeastSquares(LabelEstimator):
         ONE dispatch. F here is the estimator's INPUT (the upstream
         program's output, typically narrow raw-ish rows) — the internal
         cosine features still materialize only one tile slab at a time.
-        A BankFeaturize featurizer rides as TRACED DeviceFit operands so
-        its arrays never embed as HLO constants."""
+        The featurizer rides as TRACED DeviceFit operands (the
+        BankFeaturize contract), so its arrays never embed as HLO
+        constants and λ-sweeps over same-shape banks share one fused
+        executable. A raw callable has no operand form — it could only
+        ride inside the kept program, arrays and all — so its fit is not
+        fused: it featurizes the materialized upstream rows."""
         from keystone_tpu.parallel.streaming import BankFeaturize, _fit_core
         from keystone_tpu.workflow.fusion import DeviceFit
 
-        bank = self.featurize if isinstance(self.featurize, BankFeaturize) else None
+        bank = self.featurize
+        if not isinstance(bank, BankFeaturize):
+            return None
+        bank_type, bank_key = type(bank), bank.static_key()
+        d_feat, block_size, num_iter = self.d_feat, self.block_size, self.num_iter
+        tile_rows, center = self.tile_rows, self.center
 
         def fit_fn(F, Y, n_true: int, lam, *bank_params):
-            if bank is not None:
-                bank_type, bank_key = type(bank), bank.static_key()
-                featurize = lambda X_t: bank_type.apply_bank(  # noqa: E731
-                    bank_key, bank_params, X_t
-                )
-            else:
-                featurize = self.featurize
-            tile = min(self.tile_rows, F.shape[0])
             W, _, _, fmean, ymean = _fit_core(
-                F, Y, featurize, self.d_feat, tile, self.block_size,
-                lam, self.num_iter, False,
-                n_true if n_true != F.shape[0] else None, None,
-                self.center,
+                F, Y,
+                lambda X_t: bank_type.apply_bank(bank_key, bank_params, X_t),
+                d_feat, min(tile_rows, F.shape[0]), block_size, lam, num_iter,
+                False, n_true if n_true != F.shape[0] else None, None, center,
             )
             return W, fmean, ymean
 
         def build(params):
             W, fmean, ymean = params
             return StreamingFeaturizedLinearModel(
-                self.featurize, W, self.tile_rows, fmean=fmean, ymean=ymean,
+                bank, W, tile_rows, fmean=fmean, ymean=ymean,
             )
 
-        lam_op = jnp.asarray(self.lam, jnp.float32)
-        if bank is not None:
-            # Logical program identity: λ-sweeps over same-shape banks
-            # share one fused executable (bank values ride as operands).
-            program_key = (
-                "StreamingFLS", self.d_feat, self.block_size,
-                self.num_iter, self.tile_rows, self.center,
-                type(bank).__name__, bank.static_key(),
-            )
-            return DeviceFit(
-                fit_fn, build, operands=(lam_op,) + tuple(bank.params),
-                program_key=program_key,
-            )
-        # Generic featurize closures have no shareable identity: keep the
-        # per-instance program cache (λ still traced).
-        return DeviceFit(fit_fn, build, operands=(lam_op,))
+        return DeviceFit(
+            fit_fn, build,
+            operands=(jnp.asarray(self.lam, jnp.float32),) + tuple(bank.params),
+            program_key=(
+                "StreamingFLS", d_feat, block_size, num_iter, tile_rows,
+                center, bank_type, bank_key,
+            ),
+        )
 
     def fit(self, data: Dataset, labels: Dataset) -> StreamingFeaturizedLinearModel:
         X = jnp.asarray(data.array)
@@ -409,47 +402,42 @@ def pick_block_size(d_feat: int, hint: int) -> int:
     return 1
 
 
-class ComposedDeviceFeaturize:
-    """Composition of device-fusable transformers as a featurize callable.
-
-    Holds the member transformers (picklable — the save contract) and
-    rebuilds the composed function on unpickle; one instance per fused
-    estimator, so the closure-path jit cache keys stay stable across
-    fits.
-    """
+class ComposedDeviceFeaturize(streaming.BankFeaturize):
+    """Composition of device-fusable transformers as a bank featurize:
+    the members' identities are its static key, their arrays its params
+    (``Transformer.device_operands`` behind the streamed tier's contract),
+    so streamed fits over new members of equal identities share one
+    compiled program. Holds the members only (picklable — the save
+    contract)."""
 
     def __init__(self, members):
+        from keystone_tpu.workflow.fusion import chain_operands
+
         self.members = list(members)
-        self._build()
+        self._key, self._params = chain_operands(self.members)
 
-    def _build(self):
-        fns = [m.device_fn() for m in self.members]
+    @property
+    def params(self):
+        return self._params
 
-        def composed(X_t):
-            for f in fns:
-                X_t = f(X_t)
-            return X_t
+    def static_key(self) -> tuple:
+        return self._key
 
-        self._fn = composed
+    @classmethod
+    def apply_bank(cls, static_key, params, X_t):
+        from keystone_tpu.workflow.fusion import chain_apply
 
-    def __getstate__(self):
-        state = self.__dict__.copy()
-        state.pop("_fn", None)
-        return state
-
-    def __setstate__(self, state):
-        self.__dict__.update(state)
-        self._build()
-
-    def __call__(self, X_t):
-        return self._fn(X_t)
+        return chain_apply(static_key, params, X_t)
 
 
 def _extract_bank(members) -> Optional[CosineBankFeaturize]:
     """Recognize the cosine-featurizer shapes the optimizer produces and
-    turn them into a :class:`CosineBankFeaturize` (bank-as-operand program
-    keys; the TIMIT composition — gather of CosineRandomFeatures branches
-    + VectorCombiner — is exactly this after GatherFusionRule)."""
+    turn them into a :class:`CosineBankFeaturize` (the TIMIT composition —
+    gather of CosineRandomFeatures branches + VectorCombiner — is exactly
+    this after GatherFusionRule). Not for program keys (any members
+    compose to a bank, :class:`ComposedDeviceFeaturize`): the Pallas tile
+    kernel and BlockStreamedLeastSquares' per-block bank slices need the
+    bank ITSELF (ROADMAP D12)."""
     from keystone_tpu.ops.stats import CosineRandomFeaturesModel
     from keystone_tpu.ops.util import VectorCombiner
     from keystone_tpu.workflow.fusion import FusedGatherTransformer
@@ -843,7 +831,7 @@ class StreamedFitEstimator(LabelEstimator):
     """A capacity-selected streaming fit bound to its upstream featurize
     program (the rewrite StreamedFitFusionRule performs).
 
-    The members' composed ``device_fn`` becomes the tile featurizer of a
+    The members' composed ``device_apply`` becomes the tile featurizer of a
     :class:`StreamingFeaturizedLeastSquares` — featurize + Gramian fold +
     centered BCD compile as one scanned program and the feature matrix
     never materializes (the cost-model-driven form of the ``--streaming``

@@ -94,25 +94,16 @@ class CosineRandomFeaturesModel(Transformer):
         # defeat jit's trace cache and recompile every batch.
         self._sharded_fused = None
 
-    def apply(self, x):
-        return jnp.cos(jnp.asarray(x) @ self.W.T + self.b)
-
-    def _batch_fn(self, X):
-        return self.device_apply((), (self.W, self.b), X)
-
-    def device_fn(self):
-        """Stage-fusion contract (workflow/fusion.py): row-local cos-GEMM.
+    def device_operands(self):
+        """Stage-fusion contract (workflow/fusion.py): row-local cos-GEMM
+        whose bank rides into a fused program as arguments, so every bank
+        of one shape runs one compiled program (a λ-sweep draws or
+        rebuilds a ~7 MB bank per branch per fit).
 
         The XLA form — inside a fused program XLA fuses the cosine into
         the matmul epilogue; the standalone batch path below still
         prefers the Pallas kernel, and fused STREAMED fits recover it via
         the bank extraction (streaming_ls._extract_bank)."""
-        return self._batch_fn
-
-    def device_operands(self):
-        """Operand form: the bank rides into a fused program as arguments,
-        so every bank of one shape runs one compiled program (a λ-sweep
-        draws or rebuilds a ~7 MB bank per branch per fit)."""
         return (), (self.W, self.b)
 
     @staticmethod
@@ -152,7 +143,7 @@ class CosineRandomFeaturesModel(Transformer):
             return data.map_batch(
                 lambda X: pallas_ops.cosine_features(X, self.W, self.b)
             )._rezero_padding()
-        return data.map_batch(lambda X: jnp.cos(X @ self.W.T + self.b))._rezero_padding()
+        return data.map_batch(self.device_fn())._rezero_padding()
 
 
 def CosineRandomFeatures(
@@ -226,48 +217,24 @@ class PaddedFFT(Transformer):
     halves both the FFT flops and the c64 round-trip bytes of the
     featurize phase (the HBM-bound piece of the row's roofline)."""
 
-    def _padded_size(self, n: int) -> int:
-        return padded_pow2(n)
+    def device_operands(self):
+        return (), ()
 
-    def apply(self, x):
-        x = jnp.asarray(x)
-        p = self._padded_size(x.shape[-1])
-        padded = jnp.pad(x, [(0, p - x.shape[-1])])
+    @staticmethod
+    def device_apply(static_key, params, X):
+        p = padded_pow2(X.shape[-1])
+        padded = jnp.pad(X, [(0, 0)] * (X.ndim - 1) + [(0, p - X.shape[-1])])
         return rfft_real_half(padded, p)
-
-    def _batch_fn(self, X):
-        p = self._padded_size(X.shape[-1])
-        padded = jnp.pad(X, [(0, 0), (0, p - X.shape[-1])])
-        return rfft_real_half(padded, p)
-
-    def device_fn(self):
-        return self._batch_fn
 
 
 def packed_fft_gather_fn(branches, combiner):
     """Recognize the MnistRandomFFT gather shape — every branch
     [RandomSignNode → PaddedFFT → LinearRectifier] over one input, merged
-    by a VectorCombiner — and build the packed-pair batch program, or
-    return None when the shape doesn't match (the caller falls back to
-    per-branch composition).
-
-    The per-branch composition reads X once PER BRANCH and runs nb real
-    FFTs of width p. The packed program:
-
-      - reads X once, applies the stacked sign flips as one broadcast
-        multiply (the gather's input reads become one contiguous read);
-      - packs branch pairs as real/imag of ONE width-p complex FFT —
-        nb real transforms become ⌈nb/2⌉ complex ones — and unpacks
-        Re(bins 0..p/2) by conjugate symmetry:
-
-            Re A(k) = (Re Z(k) + Re Z((p−k) mod p)) / 2
-            Re B(k) = (Im Z(k) + Im Z((p−k) mod p)) / 2
-
-        (the scale-and-reversed-phase multiply of the classic two-real-
-        FFTs-in-one-complex-FFT identity, folded into the FFT epilogue
-        as two adds + one scale per bin);
-      - applies the per-branch rectifiers and writes the concatenated
-        output once, in the exact branch order the combiner produced.
+    by a VectorCombiner — and return the packed-pair batch program in
+    operand form, ``(static_key, params)`` for :func:`packed_fft_gather_apply`
+    (the packing geometry and the rectifiers' settings in the key, the
+    branches' sign vectors as params), or None when the shape doesn't
+    match (the caller falls back to per-branch composition).
 
     Branch members may arrive wrapped in a FusedBatchTransformer (stage
     fusion runs before gather fusion) — those are unwrapped by their
@@ -296,42 +263,61 @@ def packed_fft_gather_fn(branches, combiner):
     widths = {int(m[0].signs.shape[0]) for m in flat}
     if len(widths) != 1:
         return None
-    d_in = widths.pop()
-    nb = len(flat)
-    p = flat[0][1]._padded_size(d_in)
-    signs = jnp.stack([m[0].signs for m in flat])  # (nb, d_in)
-    alphas = jnp.asarray([float(m[2].alpha) for m in flat], jnp.float32)
-    maxvals = jnp.asarray([float(m[2].max_val) for m in flat], jnp.float32)
-    npairs = nb // 2
+    rectifiers = tuple((float(m[2].max_val), float(m[2].alpha)) for m in flat)
+    return (widths.pop(), rectifiers), tuple(m[0].signs for m in flat)
 
-    def fused(X):
-        n = X.shape[0]
-        Z = X[:, None, :] * signs  # ONE read of X for all branches
-        Zp = jnp.pad(Z, ((0, 0), (0, 0), (0, p - d_in)))
-        outs = []
-        if npairs:
-            pairs = Zp[:, : 2 * npairs].reshape(n, npairs, 2, p)
-            F = jnp.fft.fft(
-                jax.lax.complex(pairs[:, :, 0], pairs[:, :, 1]), axis=-1
-            )
-            re, im = jnp.real(F), jnp.imag(F)
 
-            def rev(a):  # a[..., (p − k) mod p]
-                return jnp.roll(a[..., ::-1], 1, axis=-1)
+def packed_fft_gather_apply(static_key, params, X):
+    """The packed program of :func:`packed_fft_gather_fn`.
 
-            reA = (0.5 * (re + rev(re)))[..., : p // 2]
-            reB = (0.5 * (im + rev(im)))[..., : p // 2]
-            outs.append(
-                jnp.stack([reA, reB], axis=2).reshape(n, 2 * npairs, p // 2)
-            )
-        if nb % 2:
-            tail = rfft_real_half(Zp[:, -1], p)
-            outs.append(tail[:, None, :])
-        halves = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
-        out = jnp.maximum(halves - alphas[None, :, None], maxvals[None, :, None])
-        return out.reshape(n, nb * (p // 2))
+    The per-branch composition reads X once PER BRANCH and runs nb real
+    FFTs of width p. The packed program:
 
-    return fused
+      - reads X once, applies the stacked sign flips as one broadcast
+        multiply (the gather's input reads become one contiguous read);
+      - packs branch pairs as real/imag of ONE width-p complex FFT —
+        nb real transforms become ⌈nb/2⌉ complex ones — and unpacks
+        Re(bins 0..p/2) by conjugate symmetry:
+
+            Re A(k) = (Re Z(k) + Re Z((p−k) mod p)) / 2
+            Re B(k) = (Im Z(k) + Im Z((p−k) mod p)) / 2
+
+        (the scale-and-reversed-phase multiply of the classic two-real-
+        FFTs-in-one-complex-FFT identity, folded into the FFT epilogue
+        as two adds + one scale per bin);
+      - applies the per-branch rectifiers and writes the concatenated
+        output once, in the exact branch order the combiner produced.
+    """
+    d_in, rectifiers = static_key
+    nb, p, npairs = len(rectifiers), padded_pow2(d_in), len(rectifiers) // 2
+    signs = jnp.stack(params)  # (nb, d_in)
+    maxvals = jnp.asarray([r[0] for r in rectifiers], jnp.float32)
+    alphas = jnp.asarray([r[1] for r in rectifiers], jnp.float32)
+    n = X.shape[0]
+    Z = X[:, None, :] * signs  # ONE read of X for all branches
+    Zp = jnp.pad(Z, ((0, 0), (0, 0), (0, p - d_in)))
+    outs = []
+    if npairs:
+        pairs = Zp[:, : 2 * npairs].reshape(n, npairs, 2, p)
+        F = jnp.fft.fft(
+            jax.lax.complex(pairs[:, :, 0], pairs[:, :, 1]), axis=-1
+        )
+        re, im = jnp.real(F), jnp.imag(F)
+
+        def rev(a):  # a[..., (p − k) mod p]
+            return jnp.roll(a[..., ::-1], 1, axis=-1)
+
+        reA = (0.5 * (re + rev(re)))[..., : p // 2]
+        reB = (0.5 * (im + rev(im)))[..., : p // 2]
+        outs.append(
+            jnp.stack([reA, reB], axis=2).reshape(n, 2 * npairs, p // 2)
+        )
+    if nb % 2:
+        tail = rfft_real_half(Zp[:, -1], p)
+        outs.append(tail[:, None, :])
+    halves = outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
+    out = jnp.maximum(halves - alphas[None, :, None], maxvals[None, :, None])
+    return out.reshape(n, nb * (p // 2))
 
 
 class RandomSignNode(Transformer):
@@ -348,14 +334,12 @@ class RandomSignNode(Transformer):
         )
         return RandomSignNode(signs)
 
-    def apply(self, x):
-        return jnp.asarray(x) * self.signs
+    def device_operands(self):
+        return (), (self.signs,)
 
-    def _batch_fn(self, X):
-        return X * self.signs
-
-    def device_fn(self):
-        return self._batch_fn
+    @staticmethod
+    def device_apply(static_key, params, X):
+        return X * params[0]
 
 
 # ---------------------------------------------------------------------------
@@ -370,29 +354,25 @@ class LinearRectifier(Transformer):
     max_val: float = 0.0
     alpha: float = 0.0
 
-    def apply(self, x):
-        return jnp.maximum(jnp.asarray(x) - self.alpha, self.max_val)
+    def device_operands(self):
+        return (float(self.max_val), float(self.alpha)), ()
 
-    def _batch_fn(self, X):
-        return jnp.maximum(X - self.alpha, self.max_val)
-
-    def device_fn(self):
-        return self._batch_fn
+    @staticmethod
+    def device_apply(static_key, params, X):
+        max_val, alpha = static_key
+        return jnp.maximum(X - alpha, max_val)
 
 
 @dataclass(frozen=True)
 class SignedHellingerMapper(Transformer):
     """sign(x)·√|x| (reference: nodes/stats/SignedHellingerMapper.scala:11-22)."""
 
-    def apply(self, x):
-        x = jnp.asarray(x)
-        return jnp.sign(x) * jnp.sqrt(jnp.abs(x))
+    def device_operands(self):
+        return (), ()
 
-    def _batch_fn(self, X):
+    @staticmethod
+    def device_apply(static_key, params, X):
         return jnp.sign(X) * jnp.sqrt(jnp.abs(X))
-
-    def device_fn(self):
-        return self._batch_fn
 
 
 @dataclass(frozen=True)
@@ -401,13 +381,15 @@ class NormalizeRows(Transformer):
 
     eps: float = 2.2e-16
 
-    def apply(self, x):
-        x = jnp.asarray(x)
-        norm = jnp.maximum(jnp.linalg.norm(x, axis=-1, keepdims=True), self.eps)
-        return x / norm
+    def device_operands(self):
+        return (float(self.eps),), ()
 
-    def device_fn(self):
-        return self.apply
+    @staticmethod
+    def device_apply(static_key, params, X):
+        norm = jnp.maximum(
+            jnp.linalg.norm(X, axis=-1, keepdims=True), static_key[0]
+        )
+        return X / norm
 
 
 @dataclass(frozen=True)

@@ -134,18 +134,7 @@ class ClassLabelIndicatorsFromIntArrayLabels(Transformer):
 class MaxClassifier(Transformer):
     """argmax over scores -> int label (reference: nodes/util/MaxClassifier.scala:9-11)."""
 
-    def apply(self, x):
-        return jnp.argmax(x, axis=-1)
-
-    def _batch_fn(self, X):
-        return jnp.argmax(X, axis=-1)
-
-    def device_fn(self):
-        return self._batch_fn
-
     def device_operands(self):
-        # Operand form with nothing to hand over: lets a [model >
-        # MaxClassifier] apply chain keep its program across refits.
         return (), ()
 
     @staticmethod
@@ -194,22 +183,17 @@ class VectorCombiner(Transformer):
     """
 
     def apply(self, x):
-        return jnp.concatenate([jnp.asarray(v) for v in x], axis=-1)
+        return self.device_combine_apply((), (), x)
 
     def batch_apply(self, data: Dataset) -> Dataset:
         if isinstance(data.data, tuple):
-            out = jnp.concatenate([jnp.asarray(a) for a in data.data], axis=-1)
+            out = self.device_combine_apply((), (), data.data)
             return Dataset(out, n=data.n, mesh=data.mesh)
         return Dataset.of([self.apply(x) for x in data.to_list()])
 
-    def device_combine_fn(self):
-        """Gather-fusion contract: merge branch ARRAYS inside one program
-        (workflow/fusion.py::GatherFusionRule)."""
-        return lambda arrays: self.device_combine_apply((), (), arrays)
-
     def device_combine_operands(self):
-        """Operand form of ``device_combine_fn`` (the combiner's side of
-        ``Transformer.device_operands``): no setting, no array."""
+        """Gather-fusion contract: merge branch ARRAYS inside one program
+        (workflow/fusion.py::GatherFusionRule). No setting, no array."""
         return (), ()
 
     @staticmethod
@@ -222,14 +206,12 @@ class MatrixVectorizer(Transformer):
     """Flatten a matrix to a vector, column-major to match Breeze's
     ``DenseMatrix.toDenseVector`` (reference: nodes/util/MatrixVectorizer.scala:9-11)."""
 
-    def apply(self, x):
-        return jnp.asarray(x).T.reshape(-1)
+    def device_operands(self):
+        return (), ()
 
-    def _batch_fn(self, X):
+    @staticmethod
+    def device_apply(static_key, params, X):
         return jnp.transpose(X, (0, 2, 1)).reshape(X.shape[0], -1)
-
-    def device_fn(self):
-        return self._batch_fn
 
 
 @dataclass(frozen=True)
@@ -247,17 +229,12 @@ class FloatToDouble(Transformer):
     # verifier's drift check it is declared, not silent.
     declares_dtype_change = True
 
-    def _dtype(self):
-        return jnp.float64 if self.strict else jnp.float32
+    def device_operands(self):
+        return (bool(self.strict),), ()
 
-    def apply(self, x):
-        return jnp.asarray(x, dtype=self._dtype())
-
-    def _batch_fn(self, X):
-        return jnp.asarray(X, dtype=self._dtype())
-
-    def device_fn(self):
-        return self._batch_fn
+    @staticmethod
+    def device_apply(static_key, params, X):
+        return jnp.asarray(X, dtype=jnp.float64 if static_key[0] else jnp.float32)
 
 
 @dataclass(frozen=True)

@@ -101,8 +101,7 @@ class PipelineEnv:
         Also clears the autocache observed-profile table: its keys hash
         DatasetOperators by dataset id(), and letting entries outlive the
         env generation would widen the window for a recycled id to alias a
-        stale profile onto different data (the hazard _SHARED_FIT_PROGRAMS
-        guards with weakref re-verification)."""
+        stale profile onto different data."""
         self.state.clear()
         self._optimizer = None
         from . import autocache

@@ -114,7 +114,7 @@ class GraphExecutor:
         from keystone_tpu import obs
 
         # A fused node says how it got its batch program (fusion.py):
-        # "hit" / "miss" of the kept table, or "closure".
+        # "hit" / "miss" of the kept-program table.
         how = getattr(operator, "fused_program", None)
         fused = {} if how is None else {"fused_program": how}
 

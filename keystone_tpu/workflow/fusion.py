@@ -9,8 +9,13 @@ neighboring matmul/FFT instead round-trips HBM between programs. This module
 is the whole-pipeline optimizer's TPU-specific answer (SURVEY §3's optimizer
 layer doing a transform Spark has no analog of):
 
-  - Transformers that are *row-local pure array functions* declare it by
-    implementing ``device_fn()`` (returns the array->array function).
+  - Transformers that are *row-local pure array functions* declare it in
+    the operand form: ``Transformer.device_operands()`` -> ``(static_key,
+    params)`` — every non-array setting, every array — computed by the
+    class-level ``device_apply(static_key, params, X)``, which captures
+    no array (a gather's combiner: ``device_combine_operands`` /
+    ``device_combine_apply``). ``device_fn()`` is derived from it on the
+    base class and is not an extension point.
   - :class:`StageFusionRule` rewrites maximal linear chains of such nodes
     into one :class:`FusedBatchTransformer` whose batch path is a single
     ``jax.jit`` of the composed functions: one dispatch, full XLA fusion
@@ -21,29 +26,27 @@ combiner), sinks, prefix-published nodes (their intermediate result must
 stay materializable for the state table — e.g. everything a Cacher marks),
 or nodes whose results another branch consumes.
 
-Row-local contract for ``device_fn``: output row i depends only on input row
-i (elementwise over the leading axis), so mesh zero-padding rows cannot leak
-into valid rows and a single trailing ``_rezero_padding`` is equivalent to
-per-stage rezeroing.
+Row-local contract for ``device_apply``: output row i depends only on
+input row i (elementwise over the leading axis), so mesh zero-padding rows
+cannot leak into valid rows and a single trailing ``_rezero_padding`` is
+equivalent to per-stage rezeroing.
 
-Where a fused program lives. A member that holds arrays can hand them over
-(``Transformer.device_operands()`` -> ``(static_key, params)``, computed by
-the class-level ``device_apply(static_key, params, X)``; a combiner offers
-``device_combine_operands`` / ``device_combine_apply``). When EVERY member
-of a fused chain or gather does, the batch program takes the members'
-arrays as traced operands and is kept in one table of this module by its
-LOGICAL identity — the kind of fusion and each member's ``(type,
-static_key)`` in order — so a pipeline built afresh (a λ sweep builds one
-per fit, each with new bank arrays) calls the program the first one
-compiled: no trace, no lowering, no constants baked into a new executable.
-Shapes and dtypes are ``jax.jit``'s own cache key under that. The table
-holds callables only, never an array. If any member has only the closure
-form (``device_fn()``), the composition is built as one ``jax.jit`` per
-fused instance over the members' closures, whose arrays become HLO
-constants of that instance's executable. A fused node says which it got in
-``fused_program`` (``"hit"``, ``"miss"`` or ``"closure"``; the node's
-``executor.node`` span carries it) and :func:`fused_program_totals` counts
-them for the process.
+Where a fused program lives. Every fused program — a chain's or a
+gather's batch program, a fused featurize+fit — takes its members' arrays
+as traced operands and is kept in ONE table of this module
+(:func:`_kept_program`) by its LOGICAL identity: each member's ``(type,
+static_key)`` in order, and for a fit the estimator's
+``DeviceFit.program_key`` and the geometry. A pipeline built afresh (a λ
+sweep builds one per fit, each with new bank arrays) calls the program the
+first one compiled: no trace, no lowering, no constants baked into a new
+executable. Shapes and dtypes are ``jax.jit``'s own cache key under that.
+The table holds callables only, never an array. A fused transformer says
+how it got its program in ``fused_program`` (``"hit"`` or ``"miss"``; the
+node's ``executor.node`` span carries it) and
+:func:`fused_program_totals` counts them for the process. The fused
+wrappers offer the operand form themselves (key: their members'
+identities; params: their members' params), so a fused node nested in
+another program keeps its program too.
 """
 
 from __future__ import annotations
@@ -78,8 +81,15 @@ __all__ = [
 
 def fusable(op) -> bool:
     """True when the operator participates in stage fusion."""
-    fn = getattr(op, "device_fn", None)
-    return callable(fn) and fn() is not None
+    offer = getattr(op, "device_operands", None)
+    return callable(offer) and offer() is not None
+
+
+def _combines(op) -> bool:
+    """True when the operator can merge a gather's branches inside a fused
+    program."""
+    offer = getattr(op, "device_combine_operands", None)
+    return callable(offer) and offer() is not None
 
 
 def fused_members(op) -> list:
@@ -154,10 +164,7 @@ def cache_would_split_fusion(plan, node, prefixes, consumers=None) -> bool:
         gouts = consumers.get(consumer, [])
         if len(gouts) == 1 and isinstance(gouts[0], NodeId):
             comb = plan.get_operator(gouts[0])
-            if (
-                getattr(comb, "device_combine_fn", None) is not None
-                and comb.device_combine_fn() is not None
-            ):
+            if _combines(comb):
                 return True
     return False
 
@@ -178,132 +185,169 @@ def fusion_splitting_nodes(plan, prefixes) -> set:
 # program (and its executables) per shape for ever.
 _KEPT_PROGRAMS_MAX = 16
 
-# logical key -> jitted ``composed(params, X)``. Callables only: nothing
-# here may pin a dead pipeline's arrays in device memory.
+# logical key -> jitted program taking the members' arrays as operands.
+# Callables only: nothing here may pin a dead pipeline's arrays in device
+# memory.
 _KEPT_PROGRAMS: Dict[tuple, Callable] = {}
-_PROGRAM_TOTALS = {"hit": 0, "miss": 0, "closure": 0}
+_PROGRAM_TOTALS = {"hit": 0, "miss": 0}
 _KEPT_LOCK = threading.Lock()
 
 
 def fused_program_totals() -> Dict[str, int]:
-    """How the process's fused transformers got their batch program so
-    far: ``hit`` (the kept table had it), ``miss`` (built and kept now),
-    ``closure`` (a member has no operand form: one program per instance)."""
+    """How the process's fused nodes got their program so far: ``hit``
+    (the kept table had it) or ``miss`` (built and kept now)."""
     with _KEPT_LOCK:
         return dict(_PROGRAM_TOTALS)
 
 
-def _step(member, combine: bool = False) -> tuple:
-    """One member of a fused program as ``(identity, run, params)``:
-    ``run(params, X)`` computes it and captures no array when the member
-    offers the operand form — ``identity`` is then its ``(type,
-    static_key)``. A member with only the closure form has identity None,
-    no operands, and a ``run`` closed over ``device_fn()``. ``combine``:
-    the member is a gather's combiner (``X`` is the list of branch
-    outputs)."""
+def _kept_program(key: tuple, build: Callable[[], Callable]) -> Tuple[Callable, str]:
+    """THE table of fused programs: the program kept under ``key`` —
+    ``build()`` makes it on a miss — and which of the two it was."""
+    with _KEPT_LOCK:
+        program = _KEPT_PROGRAMS.get(key)
+        how = "miss" if program is None else "hit"
+        if program is None:
+            program = build()
+            if len(_KEPT_PROGRAMS) >= _KEPT_PROGRAMS_MAX:
+                _KEPT_PROGRAMS.pop(next(iter(_KEPT_PROGRAMS)))
+            _KEPT_PROGRAMS[key] = program
+        _PROGRAM_TOTALS[how] += 1
+    return program, how
+
+
+def _member_form(member, combine: bool = False) -> Tuple[tuple, tuple]:
+    """One member of a fused program as ``(identity, params)``: its
+    ``(type, static_key)`` and its arrays. ``combine``: the member is a
+    gather's combiner."""
     offer = getattr(
         member, "device_combine_operands" if combine else "device_operands",
         None,
     )
-    form = offer() if offer is not None else None
+    form = offer() if callable(offer) else None
     if form is None:
-        fn = member.device_combine_fn() if combine else member.device_fn()
-        return None, (lambda _params, X: fn(X)), ()
+        raise ValueError(f"{member!r} is not device-fusable")
     static_key, params = form
-    cls = type(member)
-    apply = cls.device_combine_apply if combine else cls.device_apply
-    return (cls, static_key), functools.partial(apply, static_key), tuple(params)
+    return (type(member), static_key), tuple(params)
 
 
-def _batch_program(branches, combiner=None) -> Tuple[Callable, str]:
-    """THE composition routine of the fused transformers. ``branches``:
-    member chains over one input (a plain chain is one branch and no
-    combiner); ``combiner`` merges their outputs. Returns the X-only batch
-    function and how its program was obtained (``fused_program``).
+def chain_operands(members) -> Tuple[tuple, tuple]:
+    """A chain of members as ``(identities, params)``, the arguments of
+    :func:`chain_apply`."""
+    forms = [_member_form(m) for m in members]
+    return tuple(i for i, _ in forms), tuple(p for _, p in forms)
 
-    When every member offers the operand form, the jitted ``composed(params,
-    X)`` is kept by the members' identities and the returned function only
-    binds this instance's arrays to it; otherwise it is jitted for this
-    instance, with the closures' arrays inside."""
-    steps = [[_step(m) for m in br] for br in branches]
-    combine = None if combiner is None else _step(combiner, combine=True)
-    runs = [[run for _, run, _ in br] for br in steps]
-    combine_run = None if combine is None else combine[1]
 
-    def composed(params, X):
-        branch_params, combine_params = params
-        outs = []
-        for branch_runs, ps in zip(runs, branch_params):
-            b = X
-            for run, p in zip(branch_runs, ps):
-                b = run(p, b)
-            outs.append(b)
-        if combine_run is None:
-            return outs[0]
-        return combine_run(combine_params, outs)
+def chain_apply(identities, params, X):
+    """Run a chain: a pure function of its arguments (the identities are
+    static, the params traced), so one trace serves every chain of equal
+    identities."""
+    for (cls, static_key), p in zip(identities, params):
+        X = cls.device_apply(static_key, p, X)
+    return X
 
-    operands = (
-        tuple(tuple(p for _, _, p in br) for br in steps),
-        () if combine is None else combine[2],
+
+def _compose(static_key, params, X):
+    """THE composition routine of the fused transformers: ``static_key``
+    is (the identities of each branch over one input, the combiner's
+    identity or None); a plain chain is one branch and no combiner."""
+    branch_identities, combiner = static_key
+    branch_params, combine_params = params
+    outs = [
+        chain_apply(identities, ps, X)
+        for identities, ps in zip(branch_identities, branch_params)
+    ]
+    if combiner is None:
+        return outs[0]
+    cls, combine_key = combiner
+    return cls.device_combine_apply(combine_key, combine_params, outs)
+
+
+def _compose_form(branches, combiner=None) -> Tuple[Callable, tuple, tuple]:
+    """``(apply, static_key, params)`` of :func:`_compose` for these
+    members."""
+    chains = [chain_operands(br) for br in branches]
+    combine = (None, ()) if combiner is None else _member_form(combiner, True)
+    return (
+        _compose,
+        (tuple(i for i, _ in chains), combine[0]),
+        (tuple(p for _, p in chains), combine[1]),
     )
-    idents = [ident for br in steps for ident, _, _ in br]
-    if combine is not None:
-        idents.append(combine[0])
-    closures = None in idents
-    key = (tuple(len(br) for br in steps), combine is not None, tuple(idents))
-    with _KEPT_LOCK:
-        program = None if closures else _KEPT_PROGRAMS.get(key)
-        how = "closure" if closures else "miss" if program is None else "hit"
-        if program is None:
-            program = jax.jit(composed)
-            if not closures:
-                if len(_KEPT_PROGRAMS) >= _KEPT_PROGRAMS_MAX:
-                    _KEPT_PROGRAMS.pop(next(iter(_KEPT_PROGRAMS)))
-                _KEPT_PROGRAMS[key] = program
-        _PROGRAM_TOTALS[how] += 1
-    return (lambda X: program(operands, X)), how
 
 
-class FusedBatchTransformer(Transformer):
-    """A chain of row-local transformers compiled as one program.
+class _FusedTransformer(Transformer):
+    """What the two fused transformers share: the fused program in operand
+    form — ``_program_form()`` -> ``(apply, static_key, params)`` with
+    ``apply(static_key, params, X)`` a module-level function — kept in the
+    table by ``(apply, static_key)`` and offered as this node's own
+    operand form."""
 
-    Single-datum ``apply`` keeps exact per-node semantics (composition of
-    the members' ``apply``); the batch path jits the composition of the
-    members' ``device_fn`` functions. Host-form datasets fall back to the
-    sequential member chain.
-    """
-
-    def __init__(self, members: Sequence[Transformer]):
-        if len(members) < 2:
-            raise ValueError("fusion needs at least two members")
-        for m in members:
-            if not isinstance(m, Transformer) or m.device_fn() is None:
-                raise ValueError(f"member {m!r} is not device-fusable")
-        self.members = list(members)
-        self._build_composed()
+    def _program_form(self) -> Tuple[Callable, tuple, tuple]:
+        raise NotImplementedError
 
     def _build_composed(self) -> None:
-        self._composed, self.fused_program = _batch_program([self.members])
+        apply, static_key, params = self._program_form()
+        self._operands = ((apply, static_key), params)
 
-    # The jitted closure is not picklable; FittedPipeline.save() pickles the
+        def build():
+            def composed(params, X):
+                return apply(static_key, params, X)
+
+            return jax.jit(composed)
+
+        program, self._how = _kept_program((apply, static_key), build)
+        self._composed = lambda X: program(params, X)
+
+    @property
+    def fused_program(self) -> str:
+        """How this node got its program: ``"hit"`` or ``"miss"`` of the
+        kept table. Not state: a plan's fingerprint (serving/export.py)
+        must not depend on which build came first in the process."""
+        return self._how
+
+    # The jitted program is not picklable; FittedPipeline.save() pickles the
     # whole transformer graph (the serializable-pipeline contract,
     # Pipeline.scala:38-65 / FittedPipeline.scala:12-22), so persist only the
     # members and rebuild the composition on load.
     def __getstate__(self):
         state = self.__dict__.copy()
-        state.pop("_composed", None)
+        for derived in ("_composed", "_operands", "_how"):
+            state.pop(derived, None)
         return state
 
     def __setstate__(self, state):
         self.__dict__.update(state)
         self._build_composed()
 
+    def device_operands(self):
+        return self._operands
+
+    @staticmethod
+    def device_apply(static_key, params, X):
+        apply, inner_key = static_key
+        return apply(inner_key, params, X)
+
+
+class FusedBatchTransformer(_FusedTransformer):
+    """A chain of row-local transformers compiled as one program.
+
+    Single-datum ``apply`` keeps exact per-node semantics (composition of
+    the members' ``apply``); the batch path runs the kept composition of
+    the members' ``device_apply`` functions. Host-form datasets fall back
+    to the sequential member chain.
+    """
+
+    def __init__(self, members: Sequence[Transformer]):
+        if len(members) < 2:
+            raise ValueError("fusion needs at least two members")
+        self.members = list(members)
+        self._build_composed()
+
+    def _program_form(self):
+        return _compose_form([self.members])
+
     @property
     def label(self) -> str:
         return "Fused[" + " > ".join(m.label for m in self.members) + "]"
-
-    def device_fn(self):
-        return self._composed
 
     def apply(self, x):
         for m in self.members:
@@ -330,18 +374,19 @@ class DeviceFit:
     embeds them as HLO constants, which recompiles per instance and
     bakes a TIMIT-size bank (~360 MB) into every executable.
 
-    ``program_key``: hashable logical identity of the TRACE (estimator
-    family + every static config the fit function closes over). When
-    set, fused programs are shared ACROSS FusedFitEstimator instances
-    with identical members and key — a λ-sweep building a fresh
-    estimator per λ then compiles ONE program (λ rides in ``operands``).
-    The contract: two DeviceFits with equal program_key and identical
-    member objects must trace identically; anything value-affecting that
-    is not in the key MUST be an operand.
+    ``program_key`` (required): hashable logical identity of the TRACE
+    (estimator family + every static config the fit function closes
+    over). Fused programs are kept ACROSS FusedFitEstimator instances by
+    the members' identities and this key — a λ-sweep building a fresh
+    pipeline per λ then compiles ONE program (λ rides in ``operands``).
+    The contract: two DeviceFits with equal program_key must trace
+    identically; anything value-affecting that is not in the key MUST be
+    an operand. ``fit`` outlives its estimator inside the kept program,
+    so it captures settings, never an array.
     """
 
     def __init__(self, fit, build, supports=lambda d: True, operands=(),
-                 program_key=None):
+                 *, program_key):
         self.fit = fit
         self.build = build
         self.supports = supports
@@ -369,40 +414,36 @@ def masked_center(F, Y, n_true: int):
     return Fc, Yc, fmean, ymean
 
 
-class FusedGatherTransformer(Transformer):
+class FusedGatherTransformer(_FusedTransformer):
     """A gather-of-branches + combiner compiled as one program.
 
     Each branch is a (possibly empty — identity) list of row-local
     device-fusable transformers applied to the SAME input; the combiner's
-    ``device_combine_fn`` merges the branch outputs (e.g. VectorCombiner's
-    concat). The batch path is one jit: branch intermediates never
-    round-trip HBM between programs, and XLA schedules the branches inside
-    one computation (the gather's per-branch dispatch waves disappear —
-    the tree analog of :class:`FusedBatchTransformer`'s chains).
+    ``device_combine_apply`` merges the branch outputs (e.g.
+    VectorCombiner's concat). The batch path is one jit: branch
+    intermediates never round-trip HBM between programs, and XLA schedules
+    the branches inside one computation (the gather's per-branch dispatch
+    waves disappear — the tree analog of :class:`FusedBatchTransformer`'s
+    chains).
     """
 
     def __init__(self, branches: Sequence[Sequence[Transformer]], combiner):
         if not branches:
             raise ValueError("gather fusion needs at least one branch")
-        for br in branches:
-            for m in br:
-                if not isinstance(m, Transformer) or m.device_fn() is None:
-                    raise ValueError(f"branch member {m!r} is not fusable")
-        if getattr(combiner, "device_combine_fn", None) is None or (
-            combiner.device_combine_fn() is None
-        ):
-            raise ValueError(f"combiner {combiner!r} has no device_combine_fn")
         self.branches = [list(b) for b in branches]
         self.combiner = combiner
         self._build_composed()
 
-    def _build_composed(self) -> None:
+    def _program_form(self):
         # Shape-specialized lowering first: a gather of
         # [RandomSign → PaddedFFT → LinearRectifier] branches packs branch
         # pairs into complex FFTs and reads X once for all branches
-        # (stats.packed_fft_gather_fn) — the generic composition below
+        # (stats.packed_fft_gather_apply) — the generic composition
         # reads X per branch and runs one real FFT each.
-        from keystone_tpu.ops.stats import packed_fft_gather_fn
+        from keystone_tpu.ops.stats import (
+            packed_fft_gather_apply,
+            packed_fft_gather_fn,
+        )
 
         packed = packed_fft_gather_fn(self.branches, self.combiner)
         # Observable engagement: tests pin that the MNIST-shaped gather
@@ -410,25 +451,8 @@ class FusedGatherTransformer(Transformer):
         # the bench row states), not the generic composition.
         self.uses_packed_fft = packed is not None
         if packed is not None:
-            self._composed = jax.jit(packed)
-            self.fused_program = "closure"
-            with _KEPT_LOCK:
-                _PROGRAM_TOTALS["closure"] += 1
-            return
-        self._composed, self.fused_program = _batch_program(
-            self.branches, self.combiner
-        )
-
-    # Same pickling contract as FusedBatchTransformer: jitted closures are
-    # rebuilt on load.
-    def __getstate__(self):
-        state = self.__dict__.copy()
-        state.pop("_composed", None)
-        return state
-
-    def __setstate__(self, state):
-        self.__dict__.update(state)
-        self._build_composed()
+            return (packed_fft_gather_apply, *packed)
+        return _compose_form(self.branches, self.combiner)
 
     @property
     def label(self) -> str:
@@ -436,9 +460,6 @@ class FusedGatherTransformer(Transformer):
             " > ".join(m.label for m in br) or "id" for br in self.branches
         )
         return f"FusedGather[{inner} -> {self.combiner.label}]"
-
-    def device_fn(self):
-        return self._composed
 
     def apply(self, x):
         outs = []
@@ -462,54 +483,6 @@ class FusedGatherTransformer(Transformer):
         return data.map_batch(self._composed)
 
 
-# A handful of entries covers the λ-sweep reuse case; FIFO keeps a refit
-# loop over many geometries from retaining one executable per geometry.
-_FIT_PROGRAM_CACHE_MAX = 8
-
-# Programs shared ACROSS FusedFitEstimator instances by (member identity,
-# DeviceFit.program_key, geometry): a λ-sweep whose driver builds a fresh
-# estimator object per λ (so the rule's identity memo misses) still
-# compiles the featurize+fit program ONCE — λ rides as a traced operand.
-# Values hold WEAK member refs (see _shared_fit_program) and hits
-# re-verify identity against the dereferenced members, so recycled id()s
-# cannot alias and dead pipelines don't pin their device operands; FIFO.
-_SHARED_FIT_PROGRAMS: Dict[tuple, tuple] = {}
-_SHARED_FIT_MAX = 16
-
-
-def _shared_fit_program(members, program_key, geom_key, build):
-    # Members are held through WEAK refs: the cached program's closure
-    # pins the estimator's device operands (a TIMIT-scale bank is 100s of
-    # MB of HBM), so once the owning pipeline is garbage-collected the
-    # entry must die with it — dead entries are purged on every insert,
-    # and a hit re-verifies identity against the dereferenced members (a
-    # recycled id() cannot alias a live weakref).
-    import weakref
-
-    key = (tuple(id(m) for m in members), program_key, geom_key)
-    hit = _SHARED_FIT_PROGRAMS.get(key)
-    if hit is not None:
-        live = [r() for r in hit[0]]
-        if len(live) == len(members) and all(
-            a is not None and a is b for a, b in zip(live, members)
-        ):
-            return hit[1]
-    for k in [
-        k for k, (refs, _) in _SHARED_FIT_PROGRAMS.items()
-        if any(r() is None for r in refs)
-    ]:
-        del _SHARED_FIT_PROGRAMS[k]
-    program = build()
-    if key not in _SHARED_FIT_PROGRAMS and (
-        len(_SHARED_FIT_PROGRAMS) >= _SHARED_FIT_MAX
-    ):
-        _SHARED_FIT_PROGRAMS.pop(next(iter(_SHARED_FIT_PROGRAMS)))
-    _SHARED_FIT_PROGRAMS[key] = (
-        tuple(weakref.ref(m) for m in members), program,
-    )
-    return program
-
-
 class FusedFitEstimator(LabelEstimator):
     """An estimator fit fused with its upstream featurize program.
 
@@ -519,26 +492,15 @@ class FusedFitEstimator(LabelEstimator):
     device-fusable transformer(s) feeding it. ``fit`` then compiles
     featurize + solve into ONE program — the feature matrix never
     materializes between them (the pipeline form of the bench's hand-fused
-    featurize+BCD region). Falls back to the sequential path for host
-    datasets, multi-device meshes, or unsupported geometry.
+    featurize+BCD region) — kept in the table like every fused program, so
+    a λ-sweep that builds a new pipeline per fit pays the multi-second
+    featurize+solve compile once. Falls back to the sequential path for
+    host datasets, multi-device meshes, or unsupported geometry.
     """
 
     def __init__(self, members: Sequence[Transformer], est):
         self.members = list(members)
         self.est = est
-        # (n_true, input shape/dtype) -> jitted featurize+fit program. The
-        # rule memoizes FusedFitEstimator instances, so a λ-sweep refitting
-        # the same geometry reuses ONE compiled program instead of paying
-        # the multi-second featurize+solve compile per fit (the same trap
-        # _gram_streamed_program documents in ops/learning/lbfgs.py).
-        # FIFO-bounded like _IdentityMemo: a long-lived estimator refit
-        # across many geometries must not retain one executable per key.
-        self._programs: Dict[tuple, object] = {}
-
-    def __getstate__(self):
-        state = self.__dict__.copy()
-        state["_programs"] = {}  # jitted closures are not picklable
-        return state
 
     @property
     def label(self) -> str:
@@ -561,45 +523,33 @@ class FusedFitEstimator(LabelEstimator):
         )
         if dev is None or data.is_host or labels.is_host or multi:
             return self._fallback(data, labels)
-        fns = [m.device_fn() for m in self.members]
+        identities, member_params = chain_operands(self.members)
         X = data.array
         d_feat = int(
-            jax.eval_shape(lambda a: _compose(fns, a), X).shape[-1]
+            jax.eval_shape(
+                functools.partial(chain_apply, identities), member_params, X
+            ).shape[-1]
         )
         if not dev.supports(d_feat):
             return self._fallback(data, labels)
         n_true = int(data.n)
+        # The kept program holds the fit FUNCTION alone: ``dev`` itself
+        # holds this estimator's operand arrays.
+        fit = dev.fit
 
-        key = (n_true, X.shape, str(X.dtype))
+        def build():
+            def fused(X, Y, member_params, operands):
+                F = chain_apply(identities, member_params, X)
+                return fit(F, Y, n_true, *operands)
 
-        def build_program():
-            @jax.jit
-            def fused(X, Y, operands):
-                return dev.fit(_compose(fns, X), Y, n_true, *operands)
+            return jax.jit(fused)
 
-            return fused
-
-        if dev.program_key is not None:
-            fused = _shared_fit_program(
-                self.members, dev.program_key, key, build_program
-            )
-        else:
-            fused = self._programs.get(key)
-            if fused is None:
-                fused = build_program()
-                if len(self._programs) >= _FIT_PROGRAM_CACHE_MAX:
-                    self._programs.pop(next(iter(self._programs)))
-                self._programs[key] = fused
-
-        params = fused(X, labels.array, dev.operands)
+        fused, _ = _kept_program(
+            ("fit", identities, dev.program_key, n_true, X.shape, str(X.dtype)),
+            build,
+        )
+        params = fused(X, labels.array, member_params, dev.operands)
         return dev.build(params)
-
-
-def _compose(fns, X):
-    for f in fns:
-        X = f(X)
-    return X
-
 
 
 class _IdentityMemo:
@@ -607,11 +557,16 @@ class _IdentityMemo:
 
     Shared by every fusion rule: re-optimizing a graph built from the same
     node objects (the normal case — pipelines are re-applied with the same
-    operators) must return the SAME fused wrapper, so its jitted program
-    compiles once instead of once per apply (~4.5 s per miss at the
-    MnistRandomFFT geometry). id() keys alone are unsafe — an evicted
-    entry's ids can be recycled by the allocator — so hits re-verify every
-    constituent with `is` against the live objects the cached value holds.
+    operators) must return the SAME fused wrapper. Not for the compiled
+    program (the kept table has that whatever object asks): for PREFIX
+    identity. ``Prefix.__eq__`` (env.py) compares operators with ``==``,
+    which for a fused wrapper is object identity, so saved state and
+    autocache profiles are found again across re-optimizations only if
+    the rules hand back the wrapper they made before (ROADMAP D10: value
+    equality on the wrappers would retire this). id() keys alone are
+    unsafe — an evicted entry's ids can be recycled by the allocator — so
+    hits re-verify every constituent with `is` against the live objects
+    the cached value holds.
     """
 
     def __init__(self, max_entries: int = 64):
@@ -649,11 +604,11 @@ class StageFusionRule(Rule):
     dependency has exactly one consumer (this node), and neither is
     prefix-published (prefix results must materialize for the state table).
 
-    Fused transformers are memoized by member identity: re-optimizing a
-    graph that contains the same transformer instances (the normal case —
-    pipelines are re-applied with the same node objects) reuses the same
-    ``jax.jit`` callable, so XLA's compile cache hits instead of retracing
-    a fresh closure every optimization pass.
+    Fused transformers are memoized by member identity (:class:`_IdentityMemo`):
+    re-optimizing a graph that contains the same transformer instances
+    (the normal case — pipelines are re-applied with the same node
+    objects) hands back the same wrapper, so prefix state published under
+    it is found again.
     """
 
     def __init__(self) -> None:
@@ -730,18 +685,15 @@ class GatherFusionRule(Rule):
     """Fuse gather(branch...) -> combiner trees into one program.
 
     Applies when: a :class:`GatherTransformerOperator` node's single
-    consumer is a combiner exposing ``device_combine_fn``; every branch
+    consumer is a combiner offering ``device_combine_operands``; every branch
     feeding the gather is the common input itself (identity branch) or a
     device-fusable node consumed only by the gather; and all branches hang
     off ONE common dependency. Runs after :class:`StageFusionRule`, so
     multi-node branches have already collapsed to single fused nodes.
 
     Fused gathers are memoized by (branch members, combiner) identity —
-    same policy as the other fusion rules. Without it every pipeline
-    apply() re-optimizes into a FRESH FusedGatherTransformer whose new
-    jit closure recompiles the whole tree (~4.5 s per apply at the
-    MnistRandomFFT bench geometry — observed as a 27x end-to-end
-    regression before this cache existed).
+    same policy, same reason as the other fusion rules
+    (:class:`_IdentityMemo`).
     """
 
     def __init__(self) -> None:
@@ -778,12 +730,7 @@ class GatherFusionRule(Rule):
                 continue
             comb_node = outs[0]
             comb = plan.get_operator(comb_node)
-            if (
-                getattr(comb, "device_combine_fn", None) is None
-                or comb.device_combine_fn() is None
-                or comb_node in prefixes
-                or node in prefixes
-            ):
+            if not _combines(comb) or comb_node in prefixes or node in prefixes:
                 continue
             tails = plan.get_dependencies(node)
             if not tails:
@@ -983,9 +930,9 @@ class EstimatorFusionRule(Rule):
     Runs after Stage/Gather fusion so the upstream is a single node.
 
     Fused estimators are memoized by (member, estimator) identity — the
-    same policy as StageFusionRule — so a λ-sweep re-optimizing graphs
-    built from the same node objects reuses ONE FusedFitEstimator, whose
-    per-geometry compiled program cache then hits across fits.
+    same policy as StageFusionRule (:class:`_IdentityMemo`). The compiled
+    featurize+fit program is kept by logical identity whatever object
+    asks (:func:`_kept_program`).
     """
 
     def __init__(self) -> None:

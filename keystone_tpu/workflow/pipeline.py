@@ -12,6 +12,8 @@ yields a serializable transformer-only pipeline.
 
 from __future__ import annotations
 
+import functools
+
 import cloudpickle as pickle
 from typing import Any, Callable, Dict, Generic, List, Optional, Sequence, Tuple, TypeVar, Union
 
@@ -463,16 +465,23 @@ class FittedPipeline(Generic[A, B]):
 class Transformer(TransformerOperator, Chainable[A, B]):
     """A function on single items, batchable over datasets.
 
-    Subclasses implement ``apply`` (single item). ``batch_apply`` defaults to
-    the node's ``device_fn`` via ``map_batch`` when one is declared (so a
-    device-pure node implements ONE batched function, not three methods kept
-    in sync), else to mapping ``apply`` over the dataset (vmap for device
+    Subclasses implement ``apply`` (single item) or the operand form, from
+    which it is derived. ``batch_apply`` defaults to
+    the node's operand form (``device_operands`` + ``device_apply``) via
+    ``map_batch`` when one is declared (so a device-pure node writes ONE
+    batched function, not three methods kept in sync), else to mapping
+    ``apply`` over the dataset (vmap for device
     arrays, Python map for host collections); override it only for batch
     semantics neither default expresses (Transformer.scala:18-70).
     """
 
     def apply(self, x: A) -> B:
-        raise NotImplementedError
+        """Default for a node with the operand form: its one row-local
+        program on a batch of one (the arithmetic is written once)."""
+        fn = self.device_fn()
+        if fn is None:
+            raise NotImplementedError
+        return fn(jnp.asarray(x)[None])[0]
 
     def batch_apply(self, data: Dataset) -> Dataset:
         fn = self.device_fn()
@@ -514,37 +523,71 @@ class Transformer(TransformerOperator, Chainable[A, B]):
                 return data.map(self.apply)
         return data.map(self.apply)
 
-    def device_fn(self) -> Optional[Callable]:
-        """Pure batched array function equivalent to ``batch_apply`` on
-        array-form datasets, or None when the node is not expressible as
-        one. Implementing it opts the node into whole-pipeline stage fusion
-        (workflow/fusion.py): chains of such nodes compile into ONE XLA
-        program. Contract: row-local (output row i depends only on input
-        row i) and side-effect free.
-
-        A node that holds arrays should also offer the OPERAND form
-        (:meth:`device_operands` + :meth:`device_apply`): ``device_fn()``
-        is then that form closed over the node's own arrays, and a fused
-        program made of such nodes is kept across pipelines — a new node
-        with new arrays of the same shapes runs the program already
-        compiled, where a closure would bake its arrays into a new one."""
-        return None
+    # THE device contract: a node that is one row-local array program says
+    # so in the operand form below. ``device_fn`` / ``device_combine_fn``
+    # are derived from it here and are not extension points — outside code
+    # that still overrides one is told so where its class is created.
+    def __init_subclass__(cls, **kwargs):
+        super().__init_subclass__(**kwargs)
+        for derived, form in (
+            ("device_fn", "device_operands() + device_apply"),
+            ("device_combine_fn",
+             "device_combine_operands() + device_combine_apply"),
+        ):
+            if derived in cls.__dict__:
+                raise TypeError(
+                    f"{cls.__qualname__} overrides {derived}(), which is "
+                    f"derived from the operand form: implement {form}"
+                    "(static_key, params, X) instead (docs/MIGRATING.md)"
+                )
 
     def device_operands(self) -> Optional[Tuple[Any, tuple]]:
-        """Operand form of ``device_fn``: ``(static_key, params)`` — a
-        hashable key holding every non-array setting the computation
-        depends on, and a tuple of the node's arrays — or None (default)
-        when the node only has the closure form. Contract:
-        ``type(self).device_apply(static_key, params, X)`` equals
-        ``self.device_fn()(X)``."""
+        """The node as one row-local array program: ``(static_key,
+        params)`` — a hashable key holding every non-array setting the
+        computation depends on, and a tuple of EVERY array the node owns
+        (absent ones as None) — or None (default) when the node is not
+        expressible as one. Offering it opts the node into whole-pipeline
+        stage fusion (workflow/fusion.py): chains of such nodes compile
+        into ONE XLA program that takes the arrays as arguments and is
+        kept across pipelines, so a new node with new arrays of the same
+        shapes runs the program already compiled. Contract of
+        ``type(self).device_apply(static_key, params, X)``: row-local
+        (output row i depends only on input row i), side-effect free, and
+        equal to ``batch_apply`` on array-form datasets."""
         return None
 
     @staticmethod
     def device_apply(static_key, params, X):
-        """The computation of the operand form: a pure function of its
-        arguments, resolved through the CLASS, that captures no array — so
-        one traced program serves every instance with an equal key."""
+        """The computation of :meth:`device_operands`: a pure function of
+        its arguments, resolved through the CLASS, that captures no array
+        — so one traced program serves every instance with an equal
+        key."""
         raise NotImplementedError
+
+    def device_fn(self) -> Optional[Callable]:
+        """``device_apply`` bound to this instance's key and arrays — the
+        X-only batched function (plan verifier, datum programs, serving
+        export, the default ``batch_apply``) — or None when the node has
+        no operand form. Derived; subclasses implement
+        :meth:`device_operands` and :meth:`device_apply`."""
+        form = self.device_operands()
+        return form and functools.partial(type(self).device_apply, *form)
+
+    def device_combine_operands(self) -> Optional[Tuple[Any, tuple]]:
+        """A gather's combiner as one array program over the LIST of its
+        branch outputs: the combiner's side of :meth:`device_operands`
+        (workflow/fusion.py::GatherFusionRule). None (default): not one."""
+        return None
+
+    @staticmethod
+    def device_combine_apply(static_key, params, arrays):
+        """The computation of :meth:`device_combine_operands`."""
+        raise NotImplementedError
+
+    def device_combine_fn(self) -> Optional[Callable]:
+        """``device_combine_apply`` bound to this instance (derived)."""
+        form = self.device_combine_operands()
+        return form and functools.partial(type(self).device_combine_apply, *form)
 
     def __call__(self, x: Any) -> Any:
         """Eager application to a datum or Dataset; lazy on pipeline handles."""

@@ -42,7 +42,8 @@ def fit_tiny_mnist(n=96, d_in=TINY_D_IN, num_ffts=2, block_size=16, seed=0):
 class TraceCountingScale(Transformer):
     """Device-pure x -> 2x whose traced-function body counts traces: the
     python body of a jitted function runs once per TRACE, never on a
-    compiled-cache hit, so ``traces`` is exactly the compile count."""
+    compiled-cache hit, so ``traces`` is exactly the compile count. The
+    count lives on the node, which rides in ``static_key`` (by identity)."""
 
     def __init__(self):
         self.traces = 0
@@ -50,11 +51,13 @@ class TraceCountingScale(Transformer):
     def apply(self, x):
         return jnp.asarray(x) * 2.0
 
-    def device_fn(self):
-        def fn(X):
-            self.traces += 1
-            return X * 2.0
-        return fn
+    def device_operands(self):
+        return (self,), ()
+
+    @staticmethod
+    def device_apply(static_key, params, X):
+        static_key[0].traces += 1
+        return X * 2.0
 
 
 def fitted_from_transformer(t) -> FittedPipeline:

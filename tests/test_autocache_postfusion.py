@@ -50,9 +50,12 @@ class DeviceScale(Transformer):
         self.c = float(c)
         self.weight = weight
 
-    def device_fn(self):
-        c = self.c
-        return lambda X: X * c
+    def device_operands(self):
+        return (self.c,), ()
+
+    @staticmethod
+    def device_apply(static_key, params, X):
+        return X * static_key[0]
 
     def apply(self, x):
         return x * self.c

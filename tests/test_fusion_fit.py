@@ -330,43 +330,82 @@ class TestMoreFamilyFitFusion:
         np.testing.assert_allclose(got, ref, atol=2e-3, rtol=2e-3)
 
 
+@pytest.fixture
+def kept(monkeypatch):
+    """The kept-program table as a new process has it (other tests of this
+    worker fused fits of the same logical identity before)."""
+    from keystone_tpu.workflow import fusion
+
+    monkeypatch.setattr(fusion, "_KEPT_PROGRAMS", {})
+    return fusion
+
+
+def _fit_keys(fusion):
+    """The fused featurize+fit programs in the kept-program table."""
+    return {k for k in fusion._KEPT_PROGRAMS if k[0] == "fit"}
+
+
 class TestSharedFitPrograms:
-    def test_lambda_sweep_with_fresh_estimators_compiles_once(self):
+    def _problem(self, n=64):
+        X = rng.normal(size=(n, D_IN)).astype(np.float32)
+        Y = rng.normal(size=(n, 3)).astype(np.float32)
+        return X, Dataset.of(jnp.asarray(X)), Dataset.of(jnp.asarray(Y))
+
+    def test_lambda_sweep_with_fresh_estimators_compiles_once(self, kept):
         """A λ-sweep whose driver builds a NEW estimator object per λ (the
         autocache bench pattern) must share ONE fused featurize+fit
-        program: λ is a DeviceFit operand and the program cache keys on
-        (members, program_key), not estimator identity. Regression test
-        for the round-5 recompile-per-λ slowdown the CRF device_fn
-        introduced."""
-        from keystone_tpu.workflow import fusion
+        program: λ is a DeviceFit operand and the program is kept by
+        (members' identities, program_key), not estimator identity.
+        Regression test for the round-5 recompile-per-λ slowdown the CRF
+        device_fn introduced."""
         from keystone_tpu.workflow.env import PipelineEnv
 
         PipelineEnv.get_or_create().reset()
         pipe, cfg = _featurizer(num_ffts=2, block=32)
-        n = 64
-        X = rng.normal(size=(n, D_IN)).astype(np.float32)
-        Y = rng.normal(size=(n, 3)).astype(np.float32)
-        data = Dataset.of(jnp.asarray(X))
-        labels = Dataset.of(jnp.asarray(Y))
-
-        before_keys = set(fusion._SHARED_FIT_PROGRAMS)
+        X, data, labels = self._problem()
         preds = []
         for lam in (1e-4, 1e-3, 1e-2):
             # One optimizer across the sweep (the bench pattern): the
-            # fusion memos then hand every λ the SAME fused members, and
-            # the shared-program cache must collapse the sweep to one
-            # compile. (Estimator prefix state would make later fits
-            # no-ops, so clear just the state table, not the optimizer.)
+            # fusion memos then hand every λ the SAME fused members.
+            # (Estimator prefix state would make later fits no-ops, so
+            # clear just the state table, not the optimizer.)
             PipelineEnv.get_or_create().state.clear()
             est = BlockLeastSquaresEstimator(cfg.block_size, 2, lam)
             p = pipe.and_then(est, data, labels)
             X2 = Dataset.of(jnp.asarray(X[:16]))
             preds.append(np.asarray(p.apply(X2).get().array))
-        # One shared program for the whole sweep (same members + same
-        # BlockLS program_key; λ rides as an operand). Key-set delta, not
-        # length delta: the insert-time purge may drop entries whose
-        # owners died in earlier tests.
-        new_keys = set(fusion._SHARED_FIT_PROGRAMS) - before_keys
-        assert len(new_keys) == 1, new_keys
+        # One kept program for the whole sweep (same member identities +
+        # same BlockLS program_key; λ rides as an operand).
+        assert len(_fit_keys(kept)) == 1, _fit_keys(kept)
         # And λ genuinely differed: heavier ridge shrinks predictions.
         assert not np.allclose(preds[0], preds[2])
+
+    def test_sweep_with_a_new_pipeline_per_lambda_compiles_once(self, kept):
+        """The benchmark's traffic: every λ resets the environment and
+        builds a NEW featurizer with NEW sign vectors and a NEW estimator.
+        The fused featurize+fit program is kept by logical identity, so
+        the sweep traces and compiles it once — and each fit still
+        computes with its own arrays."""
+        from keystone_tpu import obs
+        from keystone_tpu.workflow.env import PipelineEnv
+
+        X, data, labels = self._problem()
+        preds, compiles = [], []
+        for i, lam in enumerate((1e-4, 1e-3, 1e-2)):
+            PipelineEnv.get_or_create().reset()
+            cfg = MnistRandomFFTConfig(
+                num_ffts=2, block_size=32, image_size=D_IN, seed=10 * i)
+            est = BlockLeastSquaresEstimator(cfg.block_size, 2, lam)
+            with obs.tracing() as t:
+                fitted = build_featurizer(cfg).and_then(est, data, labels).fit()
+            compiles.append(sorted(
+                c["args"]["stage"] for c in t.spans("jax.compile")
+                if c["args"]["fun"] in ("fused", "jit(fused)")))
+            preds.append(np.asarray(fitted.apply(data).to_numpy()))
+            # What an unfused fit over this pipeline's own featurizer gives.
+            feats = build_featurizer(cfg).apply(data).get()
+            want = est.fit(feats, labels).batch_apply(feats).array
+            np.testing.assert_allclose(preds[-1], np.asarray(want), atol=2e-3, rtol=2e-3)
+        assert compiles == [["backend", "lower", "trace"], [], []]
+        assert len(_fit_keys(kept)) == 1
+        assert not np.allclose(preds[0], preds[1])

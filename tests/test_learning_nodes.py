@@ -303,7 +303,7 @@ class TestSharedRfftEpilogue:
         rng = np.random.default_rng(1)
         X = rng.normal(size=(7, 45)).astype(np.float32)
         node = PaddedFFT()
-        batched = np.asarray(node._batch_fn(X))
+        batched = np.asarray(node.device_fn()(X))
         singles = np.stack([np.asarray(node.apply(row)) for row in X])
         assert batched.shape == singles.shape == (7, 32)
         np.testing.assert_allclose(batched, singles, atol=1e-5)

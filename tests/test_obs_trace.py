@@ -251,8 +251,12 @@ class TestServingBridge:
             def apply(self, x):
                 return jnp.asarray(x) * 2.0
 
-            def device_fn(self):
-                return lambda X: X * 2.0
+            def device_operands(self):
+                return (), ()
+
+            @staticmethod
+            def device_apply(static_key, params, X):
+                return X * 2.0
 
         plan = export_plan(
             fitted_from_transformer(Scale2()), np.zeros(4, np.float32),

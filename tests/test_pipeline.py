@@ -231,7 +231,7 @@ class TestBatchApplyDefault:
     datasets AND on rectangular host collections (one dispatch, not one per
     item); ragged host items fall back to per-item apply."""
 
-    def test_rectangular_host_list_takes_batched_path(self):
+    def test_rectangular_host_list_takes_batched_path(self, monkeypatch):
         from keystone_tpu.ops.util import FloatToDouble
 
         items = [np.full(3, i, dtype=np.float32) for i in range(4)]
@@ -241,8 +241,11 @@ class TestBatchApplyDefault:
         assert ds.is_host
         calls = []
         t = FloatToDouble()
-        orig = t._batch_fn
-        object.__setattr__(t, "_batch_fn", lambda X: calls.append(X.shape) or orig(X))
+        orig = FloatToDouble.device_apply
+        monkeypatch.setattr(
+            FloatToDouble, "device_apply",
+            staticmethod(lambda k, p, X: calls.append(X.shape) or orig(k, p, X)),
+        )
         out = t.batch_apply(ds)
         assert calls == [(4, 3)]  # one batched call over the stacked array
         assert not out.is_host

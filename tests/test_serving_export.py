@@ -215,9 +215,12 @@ class TestPlanFingerprint:
             def apply(self, x):
                 return jnp.asarray(x) * float(len(self.vocab))
 
-            def device_fn(self):
-                scale = float(len(self.vocab))
-                return lambda X: X * scale
+            def device_operands(self):
+                return (float(len(self.vocab)),), ()
+
+            @staticmethod
+            def device_apply(static_key, params, X):
+                return X * static_key[0]
 
         example = np.zeros(4, np.float32)
 

@@ -41,11 +41,12 @@ class _IdentityFit(Transformer):
     def apply(self, x):
         return x
 
-    def _batch_fn(self, X):
-        return X
+    def device_operands(self):
+        return (), ()
 
-    def device_fn(self):
-        return self._batch_fn
+    @staticmethod
+    def device_apply(static_key, params, X):
+        return X
 
 
 class _MeanEstimator(LabelEstimator):
@@ -66,11 +67,12 @@ class _CastsToBf16(Transformer):
     def apply(self, x):
         return jnp.asarray(x, jnp.bfloat16)
 
-    def _batch_fn(self, X):
-        return X.astype(jnp.bfloat16)
+    def device_operands(self):
+        return (), ()
 
-    def device_fn(self):
-        return self._batch_fn
+    @staticmethod
+    def device_apply(static_key, params, X):
+        return X.astype(jnp.bfloat16)
 
 
 def _data(n=4, d=5, dtype=np.float32):
