@@ -472,9 +472,11 @@ def run_lbfgs_gram_streamed(
     transfer overlap segment i's fold.
 
     ``pipeline``: double-buffer the densified chunk slab inside the fold
-    (``sparse.sparse_gram_fold``) so chunk k+1's regen+densify is
-    schedulable against chunk k's accumulating syrk; costs one extra
-    resident slab — pass False beside large resident operands.
+    (``sparse.sparse_gram_fold``) so chunk k+1's regen+densify (the
+    densify a one-hot contraction that writes the slab,
+    ``sparse_densify.densify_rows`` — no scatter) is schedulable against chunk
+    k's accumulating syrk; costs one extra resident slab — pass False
+    beside large resident operands.
 
     ``prefetch_stats``: a :class:`keystone_tpu.data.prefetch.
     PrefetchStats` filled by the prefetched source path (overlap +
@@ -1141,9 +1143,9 @@ def _gram_streamed_program(chunk_fn, num_chunks, d, k, use_pallas, val_dtype,
 
 
 # Largest per-row sum of |value| the range probe lets through: with the
-# intercept lane's 1 every scatter-added slab entry, and every partial sum
-# on the way to it, is an integer of magnitude <= 256 — the last integer
-# below which bfloat16 (8 significant bits) has no gaps.
+# intercept lane's 1 every slab entry (a row's lanes that share an id add)
+# is an integer of magnitude <= 256 — the last integer below which
+# bfloat16 (8 significant bits) has no gaps.
 _BF16_EXACT_ROW_SUM = 255.0
 
 
@@ -1155,12 +1157,13 @@ def _slabs_exact_in_bf16(values, Y):
 
     - every value is an integer and no row's |value|s sum past
       ``_BF16_EXACT_ROW_SUM`` — what binary and count term frequencies
-      are. The densify scatter-ADDS a row's lanes in the slab's type, so a
-      row that repeats an id sums its values there: under this bound every
-      entry and every partial sum is an integer of magnitude <= 256, which
-      bfloat16 holds exactly, even when a stray id lands on the intercept
-      column's 1. Masked lanes are counted as if live (refuses more, never
-      wrongly admits); a NaN fails the integer test, an infinity the sum.
+      are. The densify ADDS a row's lanes that share an id (a contraction
+      over the lanes, summed in float32 and rounded to the slab's type),
+      so a row that repeats an id sums its values there: under this bound
+      every entry is an integer of magnitude <= 256, which bfloat16 holds
+      exactly, even when a stray id lands on the intercept column's 1.
+      Masked lanes are counted as if live (refuses more, never wrongly
+      admits); a NaN fails the integer test, an infinity the sum.
     - the targets survive the round trip through bfloat16, because the
       accumulate kernels round them to the slab's type.
 
@@ -1388,6 +1391,7 @@ class SparseLBFGSwithL2(LabelEstimator):
         from keystone_tpu import obs
         from keystone_tpu.ops import pallas_ops
         from keystone_tpu.ops.sparse import gram_pad_dim
+        from keystone_tpu.ops.sparse_densify import densify_form
 
         d1 = d + 1
         npad, lanes = int(indices.shape[0]), int(indices.shape[1]) + 1
@@ -1434,10 +1438,12 @@ class SparseLBFGSwithL2(LabelEstimator):
             val_dtype = jnp.bfloat16 if exact else jnp.float32
             obs.counter_track("sparse.exact_bf16_fits", int(exact))
         use_pallas = pallas_ops.pallas_direct_ok(*operands)
+        d_pad = gram_pad_dim(d1, val_dtype)
         obs.set_on_open(
             "estimator.fit", engine="gram", compress=self.compress,
             slab_dtype=jnp.dtype(val_dtype).name, chunks=nchunks,
-            d_pad=gram_pad_dim(d1, val_dtype), pallas=bool(use_pallas),
+            d_pad=d_pad, pallas=bool(use_pallas),
+            densify=densify_form(use_pallas, c, d_pad, val_dtype),
             **probed,
         )
         solved: dict = {}

@@ -14,7 +14,9 @@ active-index gradient loops (Gradient.scala:58-123). At Amazon-review scale
 (n=65e6, d=16384, sparsity≈0.005 — scripts/constantEstimator.R:34) the
 padded-COO operands are ~100× smaller than the dense design matrix the old
 densify path would have materialized. ``densify_dataset`` remains for small
-inputs where one dense GEMM beats gather+scatter dispatch.
+inputs where one dense GEMM beats gather+scatter dispatch. The Gramian
+tier (``sparse_gram_fold``) densifies on purpose, chunk by chunk, and
+WRITES each slab by a one-hot contraction (``ops/sparse_densify.py``).
 
 Measured characteristics (v5e): both kernels run at the chip's
 random-access rate — 129M indices/s on the raw column-take microbenchmark,
@@ -242,11 +244,14 @@ def sparse_gram_stream(
 
     Each chunk is DENSIFIED into a (c, d_pad) slab and folded through the
     accumulating symmetric Pallas kernel. Deliberately so: at TPU rates —
-    dense bf16 GEMM ~150 TF/s vs ~2e8 random accesses/s — the ~200
+    dense bf16 GEMM ~175 TF/s vs ~1e8 random accesses/s — the ~200
     "wasted" multiplies per zero at Amazon sparsity (0.005) still beat
     per-element gather/scatter by an order of magnitude for AᵀA, and the
     L-BFGS iterations on the folded G then cost no data pass at all
-    (ops/learning/lbfgs.py::_lbfgs_gram_core). This is the same
+    (ops/learning/lbfgs.py::_lbfgs_gram_core). The densify itself makes no
+    random access either: the slab is WRITTEN by a one-hot contraction on
+    the MXU (``ops/sparse_densify.py``), at the rate the chip writes its bytes,
+    where a scatter-add into it ran at that 1e8 a second. This is the same
     per-partition Gramian + treeReduce pattern as the dense tier
     (BlockWeightedLeastSquares.scala:177-313), with densify-then-syrk as
     the per-partition kernel.
@@ -304,11 +309,12 @@ def sparse_gram_fold(
 
     - ``pipeline=True`` (default): the scan carry holds the NEXT chunk's
       densified slab, so each step folds slab k while regenerating +
-      scattering slab k+1 — the two are data-independent inside one step,
-      which hands the scheduler regen/densify work (VPU + scatter) to
-      overlap with the accumulating syrk (MXU), the device-compute analog
-      of ``data/prefetch.py``'s host-side double buffer. Costs one extra
-      resident chunk slab (c × d_pad of ``val_dtype``).
+      densifying slab k+1 — the two are data-independent inside one step,
+      which hands the scheduler the regen work (VPU) and the densify's
+      small one-hot products to place around the accumulating syrk, the
+      device-compute analog of ``data/prefetch.py``'s host-side double
+      buffer. Costs one extra resident chunk slab (c × d_pad of
+      ``val_dtype``).
     - ``pipeline=False``: the round-5 serial body (regen → densify →
       fold per step); one slab resident. Use when the extra slab busts
       HBM (resident-capacity probes).
@@ -320,6 +326,7 @@ def sparse_gram_fold(
     separate AᵀY GEMM's full re-read of the slab from HBM disappears.
     """
     from keystone_tpu.ops import pallas_ops
+    from keystone_tpu.ops.sparse_densify import densify_rows
 
     if carry is None:
         carry = sparse_gram_init(d, k, val_dtype)
@@ -328,13 +335,7 @@ def sparse_gram_fold(
     @jax.named_scope("ks.sparse_densify")  # names the phase in a device profile
     def densify_chunk(cid):
         indices, values, Yc = chunk_fn(cid)
-        c, w = indices.shape
-        mask = (indices >= 0) & (indices < d)
-        safe = jnp.where(mask, indices, 0).astype(jnp.int32)
-        vals = jnp.where(mask, values, 0).astype(val_dtype)
-        rows = jnp.broadcast_to(jnp.arange(c)[:, None], (c, w))
-        dense = jnp.zeros((c, d_pad), val_dtype).at[rows, safe].add(vals)
-        return dense, Yc
+        return densify_rows(indices, values, d, d_pad, val_dtype, use_pallas), Yc
 
     # Fused-kernel eligibility is static (shapes only): probe the slab
     # shape abstractly so the decision never depends on a chunk id.

@@ -106,7 +106,7 @@ def test_spans_attributes_and_counters_under_a_tracer_and_nothing_without():
     gram, gather = tracer.spans("estimator.fit")
     assert gram["args"] == {"estimator": "SparseLBFGSwithL2", "engine": "gram", "compress": None,
                             "slab_dtype": "float32", "slab_exact": False, "chunks": 4, "d_pad": 512,
-                            "pallas": False}  # normal values: the probe ran and refused
+                            "pallas": False, "densify": "contract"}  # normal values: the probe ran and refused
     assert gather["args"]["engine"] == "gather"
     sites = [s["args"].get("site") for s in tracer.spans("executor.drain")]
     assert sites.count("solver_loss") == 2  # the one wait of each fit, filed as a wait
