@@ -770,7 +770,7 @@ class LeastSquaresEstimator(OptimizableLabelEstimator):
         exact = LinearMapEstimator(lam)
         streaming = StreamingLeastSquaresChoice(
             num_iter=block_iters, lam=lam,
-            block_size_hint=max(block_size, 1024),
+            block_size_hint=block_size,
         )
         self._streaming_choice = streaming
 

@@ -20,6 +20,7 @@ intercept. ``center=False`` gives the raw-BCD semantics of
 
 from __future__ import annotations
 
+import logging
 from typing import Callable, Optional
 
 import jax
@@ -31,6 +32,8 @@ from keystone_tpu.data import Dataset
 from keystone_tpu.parallel import mesh as mesh_lib
 from keystone_tpu.parallel import streaming
 from keystone_tpu.workflow import LabelEstimator, Transformer
+
+logger = logging.getLogger("keystone_tpu.streaming")
 
 
 class StreamingFeaturizedLinearModel(Transformer):
@@ -464,12 +467,22 @@ def _extract_bank(members) -> Optional[CosineBankFeaturize]:
 
 class BlockStreamedLeastSquares(LabelEstimator):
     """The north-star tier as a pipeline estimator: per-block featurize →
-    psum → solve → residual update (``streaming_block_bcd_mesh``), for
-    geometries where even the (d, d) Gramian of the gram-streamed tier
+    psum → solve → residual update (``parallel.streaming._block_sweep``),
+    for geometries where even the (d, d) Gramian of the gram-streamed tier
     exceeds device memory (d ≳ 60k on a 16 GB chip). Requires a
     :class:`CosineBankFeaturize` (the residual sweep needs per-block bank
     slices). Centered by default — same BlockLeastSquares semantics as
     the other tiers (means fold into the block steps; NORTHSTAR.md).
+
+    The fit is TWO dispatches: epoch 1, which builds the per-block
+    Gramian/factor stash, and epochs 2+ in one more; the sweep's carry
+    (residual, block weights, stash, block means) crosses between them as
+    device arrays, the residual and the weights donated — so the host can
+    put a span and a residual norm on each phase and the stash counts as
+    the device memory it is. ``tile_rows`` bounds the feature slab a block
+    step holds (None: a ~2 GB slab of ``block_size`` columns); ``stash`` is
+    what is kept of a block's system between epochs
+    (``streaming.BLOCK_STASHES``).
     """
 
     def __init__(
@@ -480,6 +493,8 @@ class BlockStreamedLeastSquares(LabelEstimator):
         num_iter: int = 3,
         lam: float = 0.0,
         center: bool = True,
+        tile_rows: Optional[int] = None,
+        stash: str = "gram+factor",
     ):
         if not isinstance(bank, CosineBankFeaturize):
             raise TypeError(
@@ -490,12 +505,18 @@ class BlockStreamedLeastSquares(LabelEstimator):
             raise ValueError(
                 f"bank rows {bank.Wrf.shape[0]} != d_feat {d_feat}"
             )
+        if d_feat % block_size:
+            raise ValueError(f"d_feat {d_feat} not divisible by {block_size}")
         self.bank = bank
         self.d_feat = d_feat
         self.block_size = block_size
         self.num_iter = num_iter
         self.lam = lam
         self.center = center
+        self.tile_rows = tile_rows or streaming.pick_tile_rows(
+            block_size, bank.feat_dtype.itemsize
+        )
+        self.stash = stash
 
     @property
     def label(self) -> str:
@@ -504,6 +525,11 @@ class BlockStreamedLeastSquares(LabelEstimator):
     @property
     def weight(self) -> int:
         return self.num_iter + 1
+
+    @property
+    def stash_bytes(self) -> int:
+        """Device bytes of the per-block stash the fit carries."""
+        return block_stash_bytes(self.d_feat, self.block_size, self.stash)
 
     def fit(self, data: Dataset, labels: Dataset) -> StreamingFeaturizedLinearModel:
         import jax as _jax
@@ -529,21 +555,62 @@ class BlockStreamedLeastSquares(LabelEstimator):
             X = _jax.device_put(X, NamedSharding(mesh, P(mesh_lib.DATA_AXIS)))
             Y = _jax.device_put(Y, NamedSharding(mesh, P(mesh_lib.DATA_AXIS)))
         n_true = int(data.n) if data.n != X.shape[0] else None
-        out = streaming.streaming_block_bcd_mesh(
-            X, Y, self.bank.Wrf, self.bank.brf,
-            block_size=self.block_size, lam=self.lam,
-            num_iter=self.num_iter, mesh=mesh, n_true=n_true,
-            center=self.center, feat_dtype=self.bank.feat_dtype,
+        nb = self.d_feat // self.block_size
+        local_rows = X.shape[0] // mesh_lib.axis_size(mesh, mesh_lib.DATA_AXIS)
+        tile_rows = min(self.tile_rows, local_rows)
+        # Over several tiles a step featurizes each tile twice (the sums,
+        # then the update); one tile's slab serves both.
+        passes = 1 if tile_rows == local_rows else 2
+        obs.set_on_open(
+            "estimator.fit", engine="block_stream", block_size=self.block_size,
+            blocks=nb, stash=self.stash, stash_bytes=self.stash_bytes,
         )
-        if self.center:
-            W, fmean, ymean = out
-        else:
-            W, fmean, ymean = out, None, None
+        kw = dict(
+            block_size=self.block_size, mesh=mesh, n_true=n_true,
+            center=self.center, feat_dtype=self.bank.feat_dtype,
+            tile_rows=tile_rows, use_pallas=self.bank.use_pallas,
+            stash=self.stash,
+        )
+
+        def phase_done(residual_sq, epoch_from: int, epoch_to: int) -> None:
+            """The host's one wait of a phase: the read of ‖R‖², a scalar
+            the phase's program returns."""
+            with obs.span("executor.drain", site="block_epoch",
+                          epoch_from=epoch_from, epoch_to=epoch_to):
+                residual_sq = float(residual_sq)
+            steps = (epoch_to - epoch_from + 1) * nb
+            obs.counter_track("block.steps", steps)
+            obs.counter_track(
+                "block.rows_featurized", steps * passes * int(data.n)
+            )
+            obs.counter_track("block.residual_fro", residual_sq ** 0.5)
+
+        span = dict(blocks=nb, block_size=self.block_size, tile_rows=tile_rows)
+        with obs.span("solver.block_epoch", epoch_from=1, epoch_to=1, **span):
+            (R, W, *stashes), ymean, residual_sq = (
+                streaming.block_bcd_first_epoch(
+                    X, Y, self.bank.Wrf, self.bank.brf, self.lam, **kw)
+            )
+        phase_done(residual_sq, 1, 1)
+        if self.num_iter > 1:
+            with obs.span("solver.block_epoch", epoch_from=2,
+                          epoch_to=self.num_iter, **span):
+                R, W, residual_sq = streaming.block_bcd_later_epochs(
+                    R, W, stashes, X, self.bank.Wrf, self.bank.brf,
+                    self.lam, epochs=self.num_iter - 1, **kw)
+            phase_done(residual_sq, 2, self.num_iter)
+        fmean = stashes[2].reshape(self.d_feat) if self.center else None
         return StreamingFeaturizedLinearModel(
             self.bank, W,
             streaming.pick_tile_rows(self.d_feat, 4),
-            fmean=fmean, ymean=ymean,
+            fmean=fmean, ymean=ymean if self.center else None,
         )
+
+
+def block_stash_bytes(d_feat: int, block_size: int, stash: str) -> int:
+    """Bytes of the block tier's per-block stash: d/bs float32 (bs, bs)
+    factors, and as many Gramians under ``"gram+factor"``."""
+    return (8 if stash == "gram+factor" else 4) * d_feat * block_size
 
 
 class StreamingLeastSquaresChoice(LabelEstimator):
@@ -637,22 +704,55 @@ class StreamingLeastSquaresChoice(LabelEstimator):
         )
         return 8.0 * d_feat * d_feat + slab <= self.budget_bytes
 
-    def _block_tier_bs(self, d_feat: int) -> int:
-        """Block size for the block-streamed tier: the hint, shrunk until
-        the per-block Gramian/factor stash (8·d·bs bytes) fits a quarter
-        of the budget."""
-        hint = self.block_size_hint
-        if self.budget_bytes is not None:
-            cap = max(int(self.budget_bytes / (32.0 * d_feat)), 1)
-            hint = min(hint, cap)
-        return pick_block_size(d_feat, hint)
+    def _block_tier_plan(self, d_feat: int, fixed_bytes: float = 0.0):
+        """(block size, stash) the block-streamed tier runs with, given the
+        ``fixed_bytes`` it holds whatever the plan (rows, targets, residual,
+        bank, weights, one slab). The configured block size is KEPT
+        wherever a stash of it fits the WHOLE budget: block Gauss-Seidel
+        iterates depend on the block, so a shrunk block fits another
+        model. Both stashes (8·d·bs bytes) first; where they do not fit the
+        Gramian stash goes (4·d·bs: ``gram @ w`` is rebuilt from the
+        factor); only then does the block shrink, to the largest divisor of
+        d whose factor stash fits — and ``build_estimator`` says so aloud.
+        Where not even a block of one would fit, the configured block
+        stays (with the factor stash alone): shrinking cannot help."""
+        bs = pick_block_size(d_feat, self.block_size_hint)
+        if self.budget_bytes is None:
+            return bs, "gram+factor"
+        room = self.budget_bytes - fixed_bytes
+        for stash in streaming.BLOCK_STASHES:
+            if block_stash_bytes(d_feat, bs, stash) <= room:
+                return bs, stash
+        cap = int(room / (4.0 * d_feat))
+        if cap < 1:
+            # What the fit holds beside its stash is past the budget
+            # already: no block is small enough, so none is tried.
+            return bs, "factor"
+        return pick_block_size(d_feat, min(bs, cap)), "factor"
 
-    def build_estimator(self, featurize, d_feat: int):
-        from keystone_tpu import obs
+    def _block_fixed_bytes(self, local_rows: float, d_feat: int, k: int,
+                           raw: float, bank_bytes: float) -> float:
+        """What a device holds in a block-streamed fit beside the stash:
+        its rows, targets and residual, the bank (the branches' and the
+        joined one), block weights and means, and one block step's slab —
+        (tile, bs) float32, the tile never past ``slab_bytes``, with the
+        matmul's result beside it."""
+        bs = pick_block_size(d_feat, self.block_size_hint)
+        return (
+            local_rows * (raw + 8.0 * k)
+            + 2.0 * bank_bytes
+            + 4.0 * d_feat * (k + 1)
+            + 2.0 * min(4.0 * local_rows * bs, float(self.slab_bytes))
+        )
 
+    def build_estimator(self, featurize, d_feat: int,
+                        local_rows: int = 0, k: int = 0):
+        """The tier's estimator for ``featurize``. ``local_rows`` (rows a
+        device holds) and ``k`` (targets), where the caller knows them,
+        let the block tier reckon what it holds beside its stash."""
         gram_ok = self._gram_tier_ok(d_feat)
 
-        def emit(winner: str, reason: str) -> None:
+        def emit(winner: str, reason: str, **context) -> None:
             # The streaming tier's own cost-model decision, audited like
             # the solver selection (obs plane, ISSUE 9).
             obs.record_cost_decision(obs.CostDecision(
@@ -669,6 +769,7 @@ class StreamingLeastSquaresChoice(LabelEstimator):
                     "d_feat": int(d_feat),
                     "budget_bytes": self.budget_bytes,
                     "featurize": type(featurize).__name__,
+                    **context,
                 },
             ))
 
@@ -687,9 +788,7 @@ class StreamingLeastSquaresChoice(LabelEstimator):
             # only bank featurizers can drive per-block slices. Best
             # effort: run the gram tier anyway (it may exceed the budget)
             # rather than crash a fit the selector already committed to.
-            import logging
-
-            logging.getLogger("keystone_tpu.streaming").warning(
+            logger.warning(
                 "d_feat=%d: (d, d) Gramian exceeds the device budget and "
                 "the block-streamed tier needs a cosine bank featurizer "
                 "(got %s); falling back to the gram tier — the fit may "
@@ -704,10 +803,36 @@ class StreamingLeastSquaresChoice(LabelEstimator):
                     d_feat, 4, slab_bytes=self.slab_bytes
                 ),
             )
-        emit("block", "gramian_exceeds_budget")
+        configured = pick_block_size(d_feat, self.block_size_hint)
+        fixed = self._block_fixed_bytes(
+            local_rows, d_feat, k,
+            self.raw_row_bytes or 4.0 * featurize.Wrf.shape[1],
+            float(featurize.Wrf.nbytes + featurize.brf.nbytes),
+        )
+        bs, stash = self._block_tier_plan(d_feat, fixed)
+        reason = "gramian_exceeds_budget"
+        if bs != configured:
+            reason = "block_shrunk_to_fit_budget"
+            logger.warning(
+                "d_feat=%d: a block of %d with its factor stash (%.2f GB) does "
+                "not fit the %.2f GB budget beside the %.2f GB the fit holds; "
+                "fitting with blocks of %d — block Gauss-Seidel iterates "
+                "depend on the block, so this is NOT the configured model",
+                d_feat, configured,
+                block_stash_bytes(d_feat, configured, "factor") / 1e9,
+                self.budget_bytes / 1e9, fixed / 1e9, bs,
+            )
+        emit("block", reason, block_size=int(bs),
+             configured_block_size=int(configured), stash=stash,
+             stash_bytes=block_stash_bytes(d_feat, bs, stash),
+             fixed_bytes=float(fixed))
         return BlockStreamedLeastSquares(
-            featurize, d_feat=d_feat, block_size=self._block_tier_bs(d_feat),
+            featurize, d_feat=d_feat, block_size=bs,
             num_iter=self.num_iter, lam=self.lam, center=self.center,
+            tile_rows=streaming.pick_tile_rows(
+                bs, 4, slab_bytes=self.slab_bytes
+            ),
+            stash=stash,
         )
 
     def fuse_with_members(self, members) -> "StreamedFitEstimator":
@@ -755,9 +880,30 @@ class StreamingLeastSquaresChoice(LabelEstimator):
         self, n, d, k, sparsity, num_machines, cpu_weight, mem_weight,
         network_weight,
     ) -> float:
-        flops = (n * d * (d + k) + self.num_iter * d * d * k) / num_machines
-        bytes_scanned = n * d / num_machines + 2.0 * d * d
-        network = d * (d + k)  # the single (G, FY) psum
+        """The price of the TIER ``build_estimator`` would pick at this d
+        (the shared ``_gram_tier_ok`` test). Gram tier: one data pass that
+        builds the d × d normal equations, then epochs on them. Block tier:
+        BlockLeastSquares' n·d·(bs + k) for the epoch that builds the
+        per-block Gramians, n·d·k for every later one (the stash spares
+        the Gramians), and the features made anew each epoch — a d/bs-th
+        of the gram tier's leading term."""
+        if self._gram_tier_ok(d):
+            flops = (
+                n * d * (d + k) + self.num_iter * d * d * k
+            ) / num_machines
+            bytes_scanned = n * d / num_machines + 2.0 * d * d
+            network = d * (d + k)  # the single (G, FY) psum
+        else:
+            bs = pick_block_size(d, self.block_size_hint)
+            d_in = (self.raw_row_bytes or 0.0) / 4.0
+            flops = (
+                n * d * (bs + k)
+                + (self.num_iter - 1) * n * d * k
+                + self.num_iter * n * d * d_in
+            ) / num_machines
+            bytes_scanned = self.num_iter * n * d / num_machines + 2.0 * d * bs
+            # a (bs, bs) Gramian a block once, a (bs, k) correlation a step
+            network = d * (bs + self.num_iter * k)
         return (
             self._STREAM_OVERHEAD
             * max(cpu_weight * flops, mem_weight * bytes_scanned)
@@ -817,14 +963,12 @@ class StreamingLeastSquaresChoice(LabelEstimator):
                 + 8.0 * d * bs     # diag/chol block stacks in the solve
                 + slab
             )
-        bs_b = self._block_tier_bs(d)
-        return (
-            common
-            + 4.0 * n * k / num_machines  # residual R alongside Y
-            + 8.0 * d * bs_b              # per-block Gramian + factor stash
-            + 4.0 * (n / num_machines) * bs_b  # one block slab
-            + d * raw                     # bank rows ~ raw row width
+        # bank rows ~ raw row width
+        fixed = self._block_fixed_bytes(
+            n / num_machines, d, k, raw, d * (raw + 4.0)
         )
+        bs_b, stash = self._block_tier_plan(d, fixed)
+        return fixed + block_stash_bytes(d, bs_b, stash)
 
 
 class StreamedFitEstimator(LabelEstimator):
@@ -906,7 +1050,12 @@ class StreamedFitEstimator(LabelEstimator):
             ).shape[-1]
         )
         d_in = int(X.shape[-1])
-        est = self.choice.build_estimator(self._featurize, d_feat)
+        shards = 1 if data.mesh is None else mesh_lib.axis_size(
+            data.mesh, mesh_lib.DATA_AXIS)
+        est = self.choice.build_estimator(
+            self._featurize, d_feat, local_rows=X.shape[0] // shards,
+            k=int(jnp.asarray(labels.array).shape[-1]),
+        )
         model = est.fit(data, labels)
         if d_in == d_feat:
             # Width cannot disambiguate raw vs featurized input. The rule

@@ -138,7 +138,13 @@ class CosineRandomFeaturesModel(Transformer):
                     ),
                 )
             return data.map_batch(self._sharded_fused[1])._rezero_padding()
-        if pallas_ops.pallas_direct_ok(*jtu.tree_leaves(data.data)):
+        leaves = jtu.tree_leaves(data.data)
+        # A batch under one row tile of the kernel (the optimizer's few
+        # sample rows) gains nothing from it and would lower it anew for
+        # this instance's closure — 59 ms a branch, in every fit of a
+        # sweep (PERF.md section 5): XLA's shared eager programs take it.
+        small = bool(leaves) and leaves[0].shape[0] < pallas_ops._TILE_M
+        if not small and pallas_ops.pallas_direct_ok(*leaves):
             # Fused Pallas matmul+cos: the pre-activation never hits HBM.
             return data.map_batch(
                 lambda X: pallas_ops.cosine_features(X, self.W, self.b)

@@ -47,6 +47,36 @@ def _psd_factor(gram, lam):
     return jax.scipy.linalg.cholesky(gram + lam * eye, lower=True)
 
 
+def _rescue_solve(gram, rhs, lam):
+    """The solve a failed f32 Cholesky falls back to: a second Cholesky
+    with a strong scale-relative jitter, then a diagonal-preconditioned
+    step."""
+    d = gram.shape[0]
+    eye = jnp.eye(d, dtype=gram.dtype)
+    # 1e-3·(tr/d) keeps the condition number within f32 Cholesky's
+    # reliable range (~1e6) while shrinking the fit by ~0.1%. Should a
+    # concentrated spectrum defeat even the jittered factorization, the
+    # last resort is a diagonal-preconditioned step — always finite, and
+    # still a descent direction for the BCD sweep.
+    mean_diag = jnp.trace(gram) / d
+    jitter = mean_diag * jnp.asarray(1e-3, gram.dtype) + lam
+    chol_j = jax.scipy.linalg.cholesky(gram + jitter * eye, lower=True)
+    sol_j = jax.scipy.linalg.cho_solve((chol_j, True), rhs)
+    fallback = rhs / (mean_diag + lam + jnp.asarray(1e-30, gram.dtype))
+    return jnp.where(jnp.all(jnp.isfinite(sol_j)), sol_j, fallback)
+
+
+def _accepted(sol, lin_res, rhs):
+    """Acceptance of a Cholesky solve by the linear system's relative
+    residual, not factor finiteness: a failed f32 Cholesky can also produce
+    finite-but-garbage factors (observed on TPU) whose solutions blow up
+    the BCD sweep."""
+    return jnp.all(jnp.isfinite(sol)) & (
+        jnp.linalg.norm(lin_res)
+        <= jnp.asarray(1e-2, rhs.dtype) * (jnp.linalg.norm(rhs) + 1e-30)
+    )
+
+
 def _solve_psd(gram, rhs, lam, chol=None):
     """Solve (gram + lam I) x = rhs via Cholesky (gram PSD).
 
@@ -62,34 +92,35 @@ def _solve_psd(gram, rhs, lam, chol=None):
     the factorization; acceptance is still checked per solve, so a stale or
     unhealthy factor falls into the same rescue path.
     """
-    d = gram.shape[0]
-    eye = jnp.eye(d, dtype=gram.dtype)
     if chol is None:
         chol = _psd_factor(gram, lam)
     sol = jax.scipy.linalg.cho_solve((chol, True), rhs)
+    # The check costs one (d,d)@(d,k) GEMM — noise next to the Gramian build.
+    ok = _accepted(sol, gram @ sol + lam * sol - rhs, rhs)
+    return jax.lax.cond(
+        ok, lambda _: sol, lambda _: _rescue_solve(gram, rhs, lam), None
+    )
+
+
+def _factor_matvec(chol, w, lam):
+    """``gram @ w`` from the factor of (gram + lam I) alone:
+    L (Lᵀ w) − lam w — what lets a sweep keep the factor stash and drop
+    the Gramian's (NORTHSTAR.md section 3)."""
+    return chol @ (chol.T @ w) - lam * w
+
+
+def _solve_psd_from_factor(chol, rhs, lam):
+    """:func:`_solve_psd` for a caller that kept only ``chol``: the same
+    acceptance check (through the factor) and the same rescue, on the
+    Gramian rebuilt from the factor — L Lᵀ − lam I, formed only inside
+    the rescue branch."""
+    sol = jax.scipy.linalg.cho_solve((chol, True), rhs)
+    ok = _accepted(sol, chol @ (chol.T @ sol) - rhs, rhs)
 
     def rescue(_):
-        # 1e-3·(tr/d) keeps the condition number within f32 Cholesky's
-        # reliable range (~1e6) while shrinking the fit by ~0.1%. Should a
-        # concentrated spectrum defeat even the jittered factorization, the
-        # last resort is a diagonal-preconditioned step — always finite, and
-        # still a descent direction for the BCD sweep.
-        mean_diag = jnp.trace(gram) / d
-        jitter = mean_diag * jnp.asarray(1e-3, gram.dtype) + lam
-        chol_j = jax.scipy.linalg.cholesky(gram + jitter * eye, lower=True)
-        sol_j = jax.scipy.linalg.cho_solve((chol_j, True), rhs)
-        fallback = rhs / (mean_diag + lam + jnp.asarray(1e-30, gram.dtype))
-        return jnp.where(jnp.all(jnp.isfinite(sol_j)), sol_j, fallback)
+        eye = jnp.eye(chol.shape[0], dtype=chol.dtype)
+        return _rescue_solve(chol @ chol.T - lam * eye, rhs, lam)
 
-    # Acceptance is by the linear system's relative residual, not factor
-    # finiteness: a failed f32 Cholesky can also produce finite-but-garbage
-    # factors (observed on TPU) whose solutions blow up the BCD sweep. The
-    # check costs one (d,d)@(d,k) GEMM — noise next to the Gramian build.
-    lin_res = gram @ sol + lam * sol - rhs
-    ok = jnp.all(jnp.isfinite(sol)) & (
-        jnp.linalg.norm(lin_res)
-        <= jnp.asarray(1e-2, gram.dtype) * (jnp.linalg.norm(rhs) + 1e-30)
-    )
     return jax.lax.cond(ok, lambda _: sol, rescue, None)
 
 

@@ -849,14 +849,318 @@ def streaming_predict(
     return jnp.concatenate(outs, axis=0) if len(outs) > 1 else outs[0]
 
 
-# ``lam`` is a TRACED operand (λ-sweeps share one compiled sweep).
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "block_size", "num_iter", "mesh", "n_true", "feat_dtype",
-        "center",
-    ),
+# What the block sweep keeps of every block's system between epochs:
+# the Gramian and its factor, or the factor alone (``gram @ w`` is then
+# rebuilt as L(Lᵀw) − λw: half the stash, NORTHSTAR.md section 3).
+BLOCK_STASHES = ("gram+factor", "factor")
+
+
+def _row_tiles(ln: int, tile_rows: Optional[int]) -> Tuple[int, int, int]:
+    """(tile, full tiles, remainder rows) of ``ln`` local rows. One tile
+    holds them all where no tile is asked for or they fit one."""
+    if tile_rows is None or ln <= tile_rows:
+        return ln, 1, 0
+    return tile_rows, ln // tile_rows, ln % tile_rows
+
+
+def _block_sweep(x_local, Wrf, brf, lam_t, valid, *, axis, block_size,
+                 n_eff, feat_dtype, center, tile_rows, use_pallas):
+    """The two steps of the block-streamed sweep over one device's rows —
+    ``first_step`` (epoch 1: builds and stashes the block's system) and
+    ``later_step`` (epochs 2+: reads the stash) — as scan bodies over the
+    block index, for the carry ``(R, Wst, G, C, M)``: residual, block
+    weights, Gramian stash (None under ``stash="factor"``), factor stash,
+    block means. Shared by the one-program form
+    (:func:`streaming_block_bcd_mesh`) and the two-dispatch form
+    (:func:`block_bcd_first_epoch` / :func:`block_bcd_later_epochs`).
+
+    A step walks the local rows in tiles of ``tile_rows`` so that the
+    feature slab it holds is (tile, block_size) whatever n is. Where one
+    tile holds every local row the slab is made once a step and serves
+    the correlation and the update both; over several tiles the update
+    pass featurizes each tile again (nothing of a step outlives it but
+    the (bs, bs) and (bs, k) sums)."""
+    from .linalg import _factor_matvec, _solve_psd_from_factor
+
+    ln, d_in = x_local.shape
+    tile, num_full, rem = _row_tiles(ln, tile_rows)
+    one_slab = num_full == 1 and rem == 0
+    acc = jnp.promote_types(feat_dtype, jnp.float32)
+
+    def bank_slice(b):
+        Wb = jax.lax.dynamic_slice(
+            Wrf, (b * block_size, 0), (block_size, d_in)
+        )
+        bb = jax.lax.dynamic_slice(brf, (b * block_size,), (block_size,))
+        return Wb, bb
+
+    def featurize(bank, x_t, valid_t):
+        Wb, bb = bank
+        with jax.named_scope("ks.block_featurize"):
+            if use_pallas:
+                from keystone_tpu.ops import pallas_ops
+
+                F = pallas_ops.cosine_features(
+                    x_t, Wb, bb, compute_dtype=feat_dtype,
+                    out_dtype=feat_dtype,
+                )
+            else:
+                F = jnp.cos(x_t @ Wb.T + bb).astype(feat_dtype)
+            if valid_t is not None:
+                F = F * valid_t.astype(F.dtype)
+        return F
+
+    def tile_sums(F, R_t, with_gram: bool):
+        """(FᵀR, Σ R rows[, FᵀF, Σ F rows]) of one tile."""
+        with jax.named_scope("ks.block_update"):
+            out = (
+                jax.lax.dot_general(
+                    F, R_t.astype(F.dtype), (((0,), (0,)), ((), ())),
+                    preferred_element_type=acc,
+                ).astype(jnp.float32),
+                jnp.sum(R_t, axis=0),
+            )
+        if with_gram:
+            with jax.named_scope("ks.block_gram"):
+                out += (
+                    jax.lax.dot_general(
+                        F, F, (((0,), (0,)), ((), ())),
+                        preferred_element_type=acc,
+                    ).astype(jnp.float32),
+                    jnp.sum(F, axis=0, dtype=jnp.float32),
+                )
+        return out
+
+    def tile_update(F, R_t, dw, const, valid_t):
+        """R_t − Fc·Δw = R_t − F·Δw + 1·(μᵀΔw); the constant term must
+        not leak into padding rows."""
+        with jax.named_scope("ks.block_update"):
+            delta = jax.lax.dot_general(
+                F, dw.astype(F.dtype), (((1,), (0,)), ((), ())),
+                preferred_element_type=acc,
+            ).astype(R_t.dtype)
+            if const is not None:
+                delta = delta - (
+                    const[None, :] if valid_t is None
+                    else const[None, :] * valid_t.astype(R_t.dtype)
+                )
+            return R_t - delta
+
+    def tiled(a):
+        """(the full tiles of ``a``, stacked; its remainder rows)."""
+        if a is None:
+            return None, None
+        full = a[: num_full * tile].reshape((num_full, tile) + a.shape[1:])
+        return full, (a[num_full * tile:] if rem else None)
+
+    def local_sums(bank, R, with_gram: bool):
+        """The step's sums over every local row, and the slab where one
+        tile holds them all."""
+        if one_slab:
+            F = featurize(bank, x_local, valid)
+            return tile_sums(F, R, with_gram), F
+
+        def one(x_t, R_t, valid_t):
+            return tile_sums(featurize(bank, x_t, valid_t), R_t, with_gram)
+
+        (xs, x_r), (Rs, R_r), (vs, v_r) = tiled(x_local), tiled(R), tiled(valid)
+        k = R.shape[1]
+        zero = (jnp.zeros((block_size, k), jnp.float32),
+                jnp.zeros((k,), jnp.float32))
+        if with_gram:
+            zero += (jnp.zeros((block_size, block_size), jnp.float32),
+                     jnp.zeros((block_size,), jnp.float32))
+
+        def body(sums, t):
+            got = one(xs[t], Rs[t], None if vs is None else vs[t])
+            return tuple(a + g for a, g in zip(sums, got)), None
+
+        sums, _ = jax.lax.scan(body, zero, jnp.arange(num_full))
+        if rem:
+            sums = tuple(a + g for a, g in zip(sums, one(x_r, R_r, v_r)))
+        return sums, None
+
+    def local_update(bank, F, R, dw, const):
+        if one_slab:
+            return tile_update(F, R, dw, const, valid)
+
+        def one(x_t, R_t, valid_t):
+            return tile_update(
+                featurize(bank, x_t, valid_t), R_t, dw, const, valid_t
+            )
+
+        (xs, x_r), (Rs, R_r), (vs, v_r) = tiled(x_local), tiled(R), tiled(valid)
+        _, out = jax.lax.scan(
+            lambda _, t: (None, one(
+                xs[t], Rs[t], None if vs is None else vs[t])),
+            None, jnp.arange(num_full),
+        )
+        out = out.reshape((num_full * tile,) + R.shape[1:])
+        if rem:
+            out = jnp.concatenate([out, one(x_r, R_r, v_r)], axis=0)
+        return out
+
+    def solve_and_update(b, bank, F, R, Wst, local, rsum, gram, chol, mu):
+        """One block solve + residual update from the step's local sums.
+        ``gram``/``chol`` are the (centered, when ``center``) block system
+        (``gram`` None: the factor alone is kept); ``mu`` is the block's
+        feature mean (None when not centering)."""
+        if mu is not None:
+            # Centered correlation: FcᵀR = FᵀR − μ·(Σᵢ Rᵢ)ᵀ. The row
+            # sum rides the SAME psum as the correlation (stacked as
+            # one extra row) — one collective per block step, as the
+            # dossier's cost model states.
+            stacked = jax.lax.psum(
+                jnp.concatenate([local, rsum[None, :]], axis=0), axis
+            )
+            corr = stacked[:-1] - jnp.outer(mu, stacked[-1])
+        else:
+            corr = jax.lax.psum(local, axis)
+        w_old = jax.lax.dynamic_index_in_dim(Wst, b, 0, keepdims=False)
+        if gram is None:
+            rhs = corr + _factor_matvec(chol, w_old, lam_t)
+            w_new = _solve_psd_from_factor(chol, rhs, lam_t)
+        else:
+            rhs = corr + gram @ w_old
+            w_new = _solve_psd(gram, rhs, lam_t, chol=chol)
+        dw = w_new - w_old
+        const = None if mu is None else (mu @ dw).astype(R.dtype)
+        R = local_update(bank, F, R, dw, const)
+        return R, jax.lax.dynamic_update_index_in_dim(Wst, w_new, b, 0)
+
+    def first_step(carry, b):
+        R, Wst, G, C, M = carry
+        bank = bank_slice(b)
+        (local, rsum, gram_l, fsum_l), F = local_sums(bank, R, True)
+        gram = jax.lax.psum(gram_l, axis)
+        if center:
+            fsum = jax.lax.psum(fsum_l, axis)
+            mu = fsum / n_eff
+            gram = gram - jnp.outer(fsum, mu)  # = G − n μμᵀ, exact
+            M = jax.lax.dynamic_update_index_in_dim(M, mu, b, 0)
+        else:
+            mu = None
+        chol = _psd_factor(gram, lam_t)
+        R, Wst = solve_and_update(
+            b, bank, F, R, Wst, local, rsum, gram, chol, mu
+        )
+        if G is not None:
+            G = jax.lax.dynamic_update_index_in_dim(G, gram, b, 0)
+        C = jax.lax.dynamic_update_index_in_dim(C, chol, b, 0)
+        return (R, Wst, G, C, M), None
+
+    def later_step(carry, b):
+        R, Wst, G, C, M = carry
+        bank = bank_slice(b)
+        gram = (
+            None if G is None
+            else jax.lax.dynamic_index_in_dim(G, b, 0, keepdims=False)
+        )
+        chol = jax.lax.dynamic_index_in_dim(C, b, 0, keepdims=False)
+        mu = (
+            jax.lax.dynamic_index_in_dim(M, b, 0, keepdims=False)
+            if center else None
+        )
+        (local, rsum), F = local_sums(bank, R, False)
+        R, Wst = solve_and_update(
+            b, bank, F, R, Wst, local, rsum, gram, chol, mu
+        )
+        return (R, Wst, G, C, M), None
+
+    return first_step, later_step
+
+
+def _block_bcd_shard_fns(*, block_size, mesh, n_pad, n_true, feat_dtype,
+                         center, tile_rows, use_pallas, stash):
+    """(first_epoch, later_epochs): the block-streamed sweep's two phases
+    as per-device bodies (call them inside a ``shard_map`` over the mesh's
+    ``data`` axis).
+
+    ``first_epoch(x_local, y_local, Wrf, brf, lam)`` gives the carry
+    ``(R, Wst, G, C, M)`` after epoch 1 and the label mean (zeros when not
+    centering); ``later_epochs(carry, x_local, Wrf, brf, lam, epochs)``
+    gives the carry after ``epochs`` more. :func:`residual_sq` reads a
+    carry's residual norm."""
+    if stash not in BLOCK_STASHES:
+        raise ValueError(f"stash must be one of {BLOCK_STASHES}, got {stash!r}")
+    axis = mesh_lib.DATA_AXIS
+    num = mesh_lib.axis_size(mesh, axis)
+    ln = n_pad // num
+    n_eff = n_true if n_true is not None else n_pad
+
+    def steps(x_local, Wrf, brf, lam):
+        if n_true is not None and n_true != n_pad:
+            start = jax.lax.axis_index(axis) * ln
+            valid = (
+                (start + jnp.arange(ln)) < n_true
+            ).astype(jnp.float32)[:, None]
+        else:
+            valid = None
+        return valid, _block_sweep(
+            x_local, Wrf, brf, jnp.asarray(lam, jnp.float32), valid,
+            axis=axis, block_size=block_size, n_eff=n_eff,
+            feat_dtype=feat_dtype, center=center, tile_rows=tile_rows,
+            use_pallas=use_pallas,
+        )
+
+    def first_epoch(x_local, y_local, Wrf, brf, lam):
+        valid, (first_step, _) = steps(x_local, Wrf, brf, lam)
+        nb, k = Wrf.shape[0] // block_size, y_local.shape[1]
+        R0 = y_local.astype(jnp.float32)
+        if valid is not None:
+            R0 = R0 * valid
+        ymean = jnp.zeros((k,), jnp.float32)
+        if center:
+            ymean = jax.lax.psum(jnp.sum(R0, axis=0), axis) / n_eff
+            R0 = R0 - (
+                ymean[None, :] if valid is None
+                else ymean[None, :] * valid
+            )
+        stack = jnp.zeros((nb, block_size, block_size), jnp.float32)
+        carry0 = (
+            R0,
+            jnp.zeros((nb, block_size, k), jnp.float32),
+            stack if stash == "gram+factor" else None,
+            stack,
+            jnp.zeros((nb, block_size), jnp.float32),
+        )
+        carry, _ = jax.lax.scan(first_step, carry0, jnp.arange(nb))
+        return carry, ymean
+
+    def later_epochs(carry, x_local, Wrf, brf, lam, epochs: int):
+        _, (_, later_step) = steps(x_local, Wrf, brf, lam)
+        order = jnp.arange(Wrf.shape[0] // block_size)
+
+        def epoch(carry, _):
+            carry, _ = jax.lax.scan(later_step, carry, order)
+            return carry, None
+
+        carry, _ = jax.lax.scan(epoch, carry, None, length=epochs)
+        return carry
+
+    return first_epoch, later_epochs
+
+
+def _residual_sq(R):
+    """‖R‖² over every device's rows (inside the shard_map)."""
+    return jax.lax.psum(jnp.sum(R * R), mesh_lib.DATA_AXIS)
+
+
+def _check_blocks(Wrf, block_size: int) -> None:
+    if Wrf.shape[0] % block_size:
+        raise ValueError(
+            f"d_feat {Wrf.shape[0]} not divisible by {block_size}"
+        )
+
+
+_BLOCK_STATICS = (
+    "block_size", "mesh", "n_true", "feat_dtype", "center", "tile_rows",
+    "use_pallas", "stash",
 )
+
+
+# ``lam`` is a TRACED operand (λ-sweeps share one compiled sweep).
+@functools.partial(jax.jit, static_argnames=_BLOCK_STATICS + ("num_iter",))
 def streaming_block_bcd_mesh(
     X: Array,
     Y: Array,
@@ -870,6 +1174,9 @@ def streaming_block_bcd_mesh(
     n_true: Optional[int] = None,
     feat_dtype=jnp.float32,
     center: bool = False,
+    tile_rows: Optional[int] = None,
+    use_pallas: bool = False,
+    stash: str = "gram+factor",
 ):
     """The north-star program: cosine-featurize + block coordinate descent
     where feature BLOCKS are generated per step and discarded — the plan
@@ -892,6 +1199,14 @@ def streaming_block_bcd_mesh(
     Gramian/factor stash (HBM table in NORTHSTAR.md). Epochs 2+ reuse the
     stashed factors and pay only featurize + correlation + update.
 
+    ``tile_rows`` bounds the slab a step holds to (tile_rows, block_size)
+    (None: every local row in one slab); ``use_pallas`` featurizes through
+    the ``cosine_features`` Mosaic kernel; ``stash`` is what is kept of a
+    block's system between epochs (:data:`BLOCK_STASHES`). The steps are
+    :func:`_block_sweep`'s, shared with the two-dispatch form
+    (:func:`block_bcd_first_epoch` / :func:`block_bcd_later_epochs`), which
+    gives the same weights bit for bit.
+
     Padding rows (``n_true``) are masked AFTER featurization (a zero row
     featurizes to cos(b) ≠ 0). Returns the (nb, bs, k) block weights,
     replicated — or, with ``center=True``, (W, fmean, ymean):
@@ -903,141 +1218,19 @@ def streaming_block_bcd_mesh(
     Block solver at geometries where only this tier runs.
     """
     axis = mesh_lib.DATA_AXIS
-    d_feat = Wrf.shape[0]
-    d_in = X.shape[1]
-    k = Y.shape[1]
-    if d_feat % block_size:
-        raise ValueError(f"d_feat {d_feat} not divisible by {block_size}")
-    nb = d_feat // block_size
-    n_pad = X.shape[0]
-    num = mesh_lib.axis_size(mesh, axis)
-    ln = n_pad // num
-    n_eff = n_true if n_true is not None else n_pad
+    _check_blocks(Wrf, block_size)
+    first_epoch, later_epochs = _block_bcd_shard_fns(
+        block_size=block_size, mesh=mesh, n_pad=X.shape[0], n_true=n_true,
+        feat_dtype=feat_dtype, center=center, tile_rows=tile_rows,
+        use_pallas=use_pallas, stash=stash,
+    )
 
     def body(x_local, y_local, Wrf, brf):
-        lam_t = jnp.asarray(lam, jnp.float32)
-        if n_true is not None and n_true != n_pad:
-            start = jax.lax.axis_index(axis) * ln
-            valid = (
-                (start + jnp.arange(ln)) < n_true
-            ).astype(jnp.float32)[:, None]
-        else:
-            valid = None
-
-        def featurize_block(b):
-            Wb = jax.lax.dynamic_slice(
-                Wrf, (b * block_size, 0), (block_size, d_in)
-            )
-            bb = jax.lax.dynamic_slice(brf, (b * block_size,), (block_size,))
-            F = jnp.cos(x_local @ Wb.T + bb).astype(feat_dtype)
-            if valid is not None:
-                F = F * valid.astype(F.dtype)
-            return F
-
-        def update(b, R, Wst, gram, chol, mu):
-            """One block solve + residual update. ``gram``/``chol`` are the
-            (centered, when ``center``) block system; ``mu`` is the block's
-            feature mean (None when not centering)."""
-            acc = jnp.promote_types(feat_dtype, jnp.float32)
-            F = featurize_block(b)
-            local = jax.lax.dot_general(
-                F, R.astype(F.dtype), (((0,), (0,)), ((), ())),
-                preferred_element_type=acc,
-            ).astype(jnp.float32)
-            if mu is not None:
-                # Centered correlation: FcᵀR = FᵀR − μ·(Σᵢ Rᵢ)ᵀ. The row
-                # sum rides the SAME psum as the correlation (stacked as
-                # one extra row) — one collective per block step, as the
-                # dossier's cost model states.
-                stacked = jax.lax.psum(
-                    jnp.concatenate(
-                        [local, jnp.sum(R, axis=0)[None, :]], axis=0
-                    ),
-                    axis,
-                )
-                corr = stacked[:-1] - jnp.outer(mu, stacked[-1])
-            else:
-                corr = jax.lax.psum(local, axis)
-            w_old = jax.lax.dynamic_index_in_dim(Wst, b, 0, keepdims=False)
-            rhs = corr + gram @ w_old
-            w_new = _solve_psd(gram, rhs, lam_t, chol=chol)
-            dw = w_new - w_old
-            delta = jax.lax.dot_general(
-                F, dw.astype(F.dtype), (((1,), (0,)), ((), ())),
-                preferred_element_type=acc,
-            ).astype(R.dtype)
-            if mu is not None:
-                # R ← R − Fc·Δw = R − F·Δw + 1·(μᵀΔw); the constant term
-                # must not leak into padding rows.
-                const = (mu @ dw).astype(R.dtype)
-                corr_term = (
-                    const[None, :] if valid is None
-                    else const[None, :] * valid.astype(R.dtype)
-                )
-                delta = delta - corr_term
-            R = R - delta
-            return R, jax.lax.dynamic_update_index_in_dim(Wst, w_new, b, 0)
-
-        def first_step(carry, b):
-            R, Wst, G, C, M = carry
-            acc = jnp.promote_types(feat_dtype, jnp.float32)
-            F = featurize_block(b)
-            gram = jax.lax.psum(
-                jax.lax.dot_general(
-                    F, F, (((0,), (0,)), ((), ())),
-                    preferred_element_type=acc,
-                ),
-                axis,
-            )
-            if center:
-                fsum = jax.lax.psum(
-                    jnp.sum(F, axis=0, dtype=jnp.float32), axis
-                )
-                mu = fsum / n_eff
-                gram = gram - jnp.outer(fsum, mu)  # = G − n μμᵀ, exact
-                M = jax.lax.dynamic_update_index_in_dim(M, mu, b, 0)
-            else:
-                mu = None
-            chol = _psd_factor(gram, lam_t)
-            R, Wst = update(b, R, Wst, gram, chol, mu)
-            G = jax.lax.dynamic_update_index_in_dim(G, gram, b, 0)
-            C = jax.lax.dynamic_update_index_in_dim(C, chol, b, 0)
-            return (R, Wst, G, C, M), None
-
-        def later_step(carry, b):
-            R, Wst, G, C, M = carry
-            gram = jax.lax.dynamic_index_in_dim(G, b, 0, keepdims=False)
-            chol = jax.lax.dynamic_index_in_dim(C, b, 0, keepdims=False)
-            mu = (
-                jax.lax.dynamic_index_in_dim(M, b, 0, keepdims=False)
-                if center else None
-            )
-            R, Wst = update(b, R, Wst, gram, chol, mu)
-            return (R, Wst, G, C, M), None
-
-        R0 = y_local.astype(jnp.float32)
-        if valid is not None:
-            R0 = R0 * valid
-        if center:
-            ysum = jax.lax.psum(jnp.sum(R0, axis=0), axis)
-            ymean = ysum / n_eff
-            R0 = R0 - (
-                ymean[None, :] if valid is None
-                else ymean[None, :] * valid
-            )
-        Wst0 = jnp.zeros((nb, block_size, k), jnp.float32)
-        G0 = jnp.zeros((nb, block_size, block_size), jnp.float32)
-        C0 = jnp.zeros((nb, block_size, block_size), jnp.float32)
-        M0 = jnp.zeros((nb, block_size), jnp.float32)
-        order = jnp.arange(nb)
-        carry, _ = jax.lax.scan(first_step, (R0, Wst0, G0, C0, M0), order)
+        carry, ymean = first_epoch(x_local, y_local, Wrf, brf, lam)
         if num_iter > 1:
-            def epoch(carry, _):
-                carry, _ = jax.lax.scan(later_step, carry, order)
-                return carry, None
-            carry, _ = jax.lax.scan(epoch, carry, None, length=num_iter - 1)
+            carry = later_epochs(carry, x_local, Wrf, brf, lam, num_iter - 1)
         if center:
-            return carry[1], carry[4].reshape(d_feat), ymean
+            return carry[1], carry[4].reshape(Wrf.shape[0]), ymean
         return carry[1]
 
     out_specs = (P(), P(), P()) if center else P()
@@ -1048,6 +1241,84 @@ def streaming_block_bcd_mesh(
         out_specs=out_specs,
         check_vma=False,
     )(X, Y, Wrf, brf)
+
+
+def _carry_specs(stash: str):
+    """Partition specs of the sweep's carry ``(R, Wst, G, C, M)``: the
+    residual's rows shard over ``data``, the rest replicates."""
+    return (P(mesh_lib.DATA_AXIS), P(),
+            P() if stash == "gram+factor" else None, P(), P())
+
+
+@functools.partial(jax.jit, static_argnames=_BLOCK_STATICS)
+def block_bcd_first_epoch(
+    X: Array, Y: Array, Wrf: Array, brf: Array, lam, *, block_size: int,
+    mesh, n_true: Optional[int] = None, feat_dtype=jnp.float32,
+    center: bool = False, tile_rows: Optional[int] = None,
+    use_pallas: bool = False, stash: str = "gram+factor",
+):
+    """Epoch 1 of :func:`streaming_block_bcd_mesh` as a program of its own:
+    the sweep that builds every block's system. Returns
+    ``(carry, ymean, residual_sq)`` — the carry ``(R, Wst, G, C, M)`` as
+    device arrays (residual row-sharded, block weights, Gramian stash or
+    None, factor stash, block means), the label mean, and ‖R‖² after the
+    epoch — for :func:`block_bcd_later_epochs` to take up, for a snapshot,
+    or for the host to read between the phases."""
+    axis = mesh_lib.DATA_AXIS
+    _check_blocks(Wrf, block_size)
+    first_epoch, _ = _block_bcd_shard_fns(
+        block_size=block_size, mesh=mesh, n_pad=X.shape[0], n_true=n_true,
+        feat_dtype=feat_dtype, center=center, tile_rows=tile_rows,
+        use_pallas=use_pallas, stash=stash,
+    )
+
+    def body(x_local, y_local, Wrf, brf, lam):
+        carry, ymean = first_epoch(x_local, y_local, Wrf, brf, lam)
+        return carry, ymean, _residual_sq(carry[0])
+
+    return mesh_lib.shard_map(
+        body, mesh=mesh,
+        in_specs=(P(axis), P(axis), P(), P(), P()),
+        out_specs=(_carry_specs(stash), P(), P()),
+        check_vma=False,
+    )(X, Y, Wrf, brf, jnp.asarray(lam, jnp.float32))
+
+
+@functools.partial(
+    jax.jit, static_argnames=_BLOCK_STATICS + ("epochs",),
+    donate_argnames=("R", "Wst"),
+)
+def block_bcd_later_epochs(
+    R: Array, Wst: Array, stashes, X: Array, Wrf: Array, brf: Array, lam, *,
+    epochs: int, block_size: int, mesh, n_true: Optional[int] = None,
+    feat_dtype=jnp.float32, center: bool = False,
+    tile_rows: Optional[int] = None, use_pallas: bool = False,
+    stash: str = "gram+factor",
+):
+    """``epochs`` more sweeps from the carry :func:`block_bcd_first_epoch`
+    left. The residual and the block weights are DONATED and come back
+    updated, with ‖R‖² after the last sweep; ``stashes`` = ``(G, C, M)``
+    is read and stays the caller's (epochs 2+ change none of it)."""
+    axis = mesh_lib.DATA_AXIS
+    _, later_epochs = _block_bcd_shard_fns(
+        block_size=block_size, mesh=mesh, n_pad=X.shape[0], n_true=n_true,
+        feat_dtype=feat_dtype, center=center, tile_rows=tile_rows,
+        use_pallas=use_pallas, stash=stash,
+    )
+
+    def body(R, Wst, stashes, x_local, Wrf, brf, lam):
+        carry = later_epochs(
+            (R, Wst) + tuple(stashes), x_local, Wrf, brf, lam, epochs
+        )
+        return carry[0], carry[1], _residual_sq(carry[0])
+
+    specs = _carry_specs(stash)
+    return mesh_lib.shard_map(
+        body, mesh=mesh,
+        in_specs=(specs[0], specs[1], specs[2:], P(axis), P(), P(), P()),
+        out_specs=(specs[0], specs[1], P()),
+        check_vma=False,
+    )(R, Wst, tuple(stashes), X, Wrf, brf, jnp.asarray(lam, jnp.float32))
 
 
 @functools.partial(

@@ -185,8 +185,12 @@ class TestPhaseRehearsal:
             entry = report[solver]
             assert entry["train_error"] <= chip_smoke.TIMIT_TRAIN_ERROR_BOUND
             # Interpreted dispatches are reported as such, and nothing
-            # claims a Mosaic custom call on this backend.
-            assert "cosine_features" in entry["kernels_interpreted"]
+            # claims a Mosaic custom call on this backend. The streamed
+            # fit featurizes through the kernel; the resident fit's one
+            # dispatch of it was the optimizer's few sample rows, which
+            # take XLA's shared programs since PR 32 (ops/stats.py).
+            assert ("cosine_features" in entry["kernels_interpreted"]) == (
+                solver == "streaming")
             assert entry["kernels_dispatched"] == []
             assert entry["mosaic_custom_calls_in_lowered_text"] == {}
             assert entry["plan_compiled"] in (True, False)

@@ -329,14 +329,17 @@ class TestOutOfCorePipelineFit:
         )
         seen = []
 
-        def spy(X_in, Y_in, Wrf, brf, **kw):
+        def spy(X_in, Y_in, Wrf, brf, lam, **kw):
             seen.append((np.asarray(X_in), np.asarray(Y_in)))
-            return (
-                jnp.zeros((4, 16, 2)), jnp.zeros(d_feat), jnp.zeros(2)
-            )
+            carry = (jnp.zeros_like(Y_in), jnp.zeros((4, 16, 2)), None,
+                     jnp.zeros((4, 16, 16)), jnp.zeros((4, 16)))
+            return carry, jnp.zeros(2), jnp.zeros(())
 
+        # epoch 1's program takes the rows; epochs 2+ the carry it left
+        monkeypatch.setattr(streaming_mod, "block_bcd_first_epoch", spy)
         monkeypatch.setattr(
-            streaming_mod, "streaming_block_bcd_mesh", spy
+            streaming_mod, "block_bcd_later_epochs",
+            lambda R, W, stashes, *a, **kw: (R, W, jnp.zeros(())),
         )
         est.fit(sld.data, sld.labels)
         est.fit(Dataset.of(X), Dataset.of(Y))
