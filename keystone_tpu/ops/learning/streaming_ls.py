@@ -483,6 +483,13 @@ class BlockStreamedLeastSquares(LabelEstimator):
     step holds (None: a ~2 GB slab of ``block_size`` columns); ``stash`` is
     what is kept of a block's system between epochs
     (``streaming.BLOCK_STASHES``).
+
+    Epoch 1's block Gramians F_bᵀF_b take the upper block-triangle alone,
+    whatever the block and the rows: panels of columns (256 wide at a block
+    of 4,096), each against the columns from its own on, plain
+    ``dot_general``s at the slab's own precision, mirrored once a block
+    (``streaming._gram_upper_panels``; ``estimator.fit`` says so as
+    ``gram="sym_dot"``).
     """
 
     def __init__(
@@ -564,6 +571,7 @@ class BlockStreamedLeastSquares(LabelEstimator):
         obs.set_on_open(
             "estimator.fit", engine="block_stream", block_size=self.block_size,
             blocks=nb, stash=self.stash, stash_bytes=self.stash_bytes,
+            gram=streaming.BLOCK_GRAM,
         )
         kw = dict(
             block_size=self.block_size, mesh=mesh, n_true=n_true,
@@ -825,7 +833,7 @@ class StreamingLeastSquaresChoice(LabelEstimator):
         emit("block", reason, block_size=int(bs),
              configured_block_size=int(configured), stash=stash,
              stash_bytes=block_stash_bytes(d_feat, bs, stash),
-             fixed_bytes=float(fixed))
+             fixed_bytes=float(fixed), gram=streaming.BLOCK_GRAM)
         return BlockStreamedLeastSquares(
             featurize, d_feat=d_feat, block_size=bs,
             num_iter=self.num_iter, lam=self.lam, center=self.center,

@@ -863,6 +863,38 @@ def _row_tiles(ln: int, tile_rows: Optional[int]) -> Tuple[int, int, int]:
     return tile_rows, ln // tile_rows, ln % tile_rows
 
 
+# What ``estimator.fit`` of the block tier says of its epoch-1 Gramians
+# (``gram``): the upper block-triangle in plain ``dot_general``s, mirrored.
+BLOCK_GRAM = "sym_dot"
+
+# Least column width of the panels that triangle is made of, and the most
+# panels a block is cut into (wider blocks take wider panels, so that a
+# step's products and their temporaries do not grow in number). Read on a
+# v5e at 131,072 x 4,096 float32 only: 74.0 ms at 256, 77.8 at 512, 86.3 at
+# 1,024, one full dot 137.0 (PERF.md section 6, PR 33).
+_GRAM_PANEL = 256
+_GRAM_PANELS = 16
+
+
+def _gram_upper_panels(F, acc):
+    """The upper block-triangle of FᵀF, zeros under it: each panel of
+    columns against the columns from its own on — 136 of the 256 panel
+    pairs at a block of 4,096 — as plain ``dot_general``s at the slab's own
+    precision (the column slices fuse into the products: no copy of the
+    slab). Any width: a block of one panel is the full product, and a last
+    panel may be narrower."""
+    bs = F.shape[1]
+    width = _GRAM_PANEL * max(1, -(-bs // (_GRAM_PANELS * _GRAM_PANEL)))
+    rows = []
+    for lo in range(0, bs, width):
+        panel = jax.lax.dot_general(
+            F[:, lo:lo + width], F[:, lo:], (((0,), (0,)), ((), ())),
+            preferred_element_type=acc,
+        ).astype(jnp.float32)
+        rows.append(jnp.pad(panel, ((0, 0), (lo, 0))))
+    return jnp.concatenate(rows, axis=0)
+
+
 def _block_sweep(x_local, Wrf, brf, lam_t, valid, *, axis, block_size,
                  n_eff, feat_dtype, center, tile_rows, use_pallas):
     """The two steps of the block-streamed sweep over one device's rows —
@@ -879,7 +911,14 @@ def _block_sweep(x_local, Wrf, brf, lam_t, valid, *, axis, block_size,
     tile holds every local row the slab is made once a step and serves
     the correlation and the update both; over several tiles the update
     pass featurizes each tile again (nothing of a step outlives it but
-    the (bs, bs) and (bs, k) sums)."""
+    the (bs, bs) and (bs, k) sums).
+
+    Epoch 1's Gramian F_tᵀF_t is a tile's upper block-triangle alone
+    (:func:`_gram_upper_panels`: a symmetric rank-k update in plain
+    ``dot_general``s, whatever the rows, the block and the backend); the
+    block's summed — and, on a mesh, psum'd and centred — upper triangle
+    is mirrored once, so that the stash, the factor and ``gram @ w`` see a
+    full matrix, symmetric to the bit."""
     from .linalg import _factor_matvec, _solve_psd_from_factor
 
     ln, d_in = x_local.shape
@@ -923,10 +962,7 @@ def _block_sweep(x_local, Wrf, brf, lam_t, valid, *, axis, block_size,
         if with_gram:
             with jax.named_scope("ks.block_gram"):
                 out += (
-                    jax.lax.dot_general(
-                        F, F, (((0,), (0,)), ((), ())),
-                        preferred_element_type=acc,
-                    ).astype(jnp.float32),
+                    _gram_upper_panels(F, acc),
                     jnp.sum(F, axis=0, dtype=jnp.float32),
                 )
         return out
@@ -1040,6 +1076,9 @@ def _block_sweep(x_local, Wrf, brf, lam_t, valid, *, axis, block_size,
             M = jax.lax.dynamic_update_index_in_dim(M, mu, b, 0)
         else:
             mu = None
+        # The tiles gave the upper triangle. Mirrored once a block, after
+        # their sums, the psum and the centring: symmetric to the bit.
+        gram = jnp.triu(gram) + jnp.triu(gram, 1).T
         chol = _psd_factor(gram, lam_t)
         R, Wst = solve_and_update(
             b, bank, F, R, Wst, local, rsum, gram, chol, mu

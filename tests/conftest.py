@@ -96,6 +96,38 @@ def mesh4x2():
     return mesh_lib.make_mesh((4, 2), (mesh_lib.DATA_AXIS, mesh_lib.MODEL_AXIS))
 
 
+@pytest.fixture(scope="session")
+def one_chip():
+    """One chip of a described v5e host, to compile for (nothing runs)."""
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def compile_for_chip():
+    """``compile_for_chip(fn, *shapes)``: ``fn`` compiled for the described
+    chip the shapes are placed on, as outside the tests: no x64, and no
+    compile cache — a described chip's entry cannot be read back."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    def compile_(fn, *shapes):
+        jax.config.update("jax_enable_compilation_cache", False)
+        compilation_cache.reset_cache()
+        try:
+            with jax.enable_x64(False):
+                return jax.jit(fn).lower(*shapes).compile()
+        finally:
+            jax.config.update("jax_enable_compilation_cache", True)
+            compilation_cache.reset_cache()
+
+    return compile_
+
 
 # Hypothesis: deterministic example generation. Property tests exist to pin
 # invariants in CI, not to fuzz at test time — a fresh random draw that

@@ -2,7 +2,9 @@
 programs of their own against the one-program form (bit for bit), row tiles
 and the factor-only stash against the plain sweep, the block size the tier
 keeps and the refusal aloud where it cannot, and the selector's price of the
-tier it will build."""
+tier it will build. Since PR 33: epoch 1's block Gramians as the upper
+block-triangle, panel by panel and mirrored, against the plain product at
+every block width, and ``gram`` on ``estimator.fit`` saying so."""
 
 import logging
 
@@ -270,3 +272,134 @@ def test_a_small_batch_of_cosine_features_takes_no_kernel(monkeypatch):
     with pallas_ops.record_dispatches() as log:
         rf.batch_apply(Dataset.of(many))
     assert [name for name, _ in log] == ["cosine_features"]
+
+
+# --- epoch 1's Gramians: the upper block-triangle, panel by panel, mirrored once a block (PR 33) ---
+
+
+def _first_epoch(n_pad, n_true, tile_rows, *, bs, devices=1, center=True, use_pallas=False):
+    """Epoch 1 of a two-block toy fit: the program's carry and ‖R‖², whether
+    the program holds a full (bs, bs) product, and the same epoch done
+    plainly here — each block's FᵀF as one float64 product of the float32
+    slab, its system solved, the residual updated — as (R, W, G, ‖Y‖²)."""
+    rng = np.random.default_rng(11)
+    d_feat = 2 * bs
+    Wrf = jnp.asarray(rng.normal(size=(d_feat, D_IN)).astype(np.float32) * 0.3)
+    brf = jnp.asarray(rng.uniform(0, 2 * np.pi, size=(d_feat,)).astype(np.float32))
+    X = jnp.asarray(rng.normal(size=(n_pad, D_IN)).astype(np.float32))
+    Y = jnp.asarray(rng.normal(size=(n_pad, K)).astype(np.float32) + 0.5)
+    mesh = mesh_lib.make_mesh(devices=jax.devices()[:devices])
+    Xs, Ys = mesh_lib.shard_rows(X, mesh), mesh_lib.shard_rows(Y, mesh)
+    kw = dict(block_size=bs, mesh=mesh, n_true=n_true, center=center, tile_rows=tile_rows,
+              use_pallas=use_pallas)
+    text = str(jax.make_jaxpr(
+        lambda *a: streaming.block_bcd_first_epoch(*a, LAM, **kw))(Xs, Ys, Wrf, brf))
+    carry, _, residual_sq = streaming.block_bcd_first_epoch(Xs, Ys, Wrf, brf, LAM, **kw)
+    n = n_pad if n_true is None else n_true
+    R = np.asarray(Y[:n], np.float64)
+    y_sq = float((R * R).sum())
+    R = R - R.mean(0) if center else R
+    W, G = [], []
+    for b in range(2):
+        cols = slice(b * bs, (b + 1) * bs)
+        F = np.asarray(jnp.cos(X[:n] @ Wrf[cols].T + brf[cols]), np.float64)
+        F = F - F.mean(0) if center else F
+        G.append(F.T @ F)
+        W.append(np.linalg.solve(G[-1] + LAM * np.eye(bs), F.T @ R))
+        R = R - F @ W[-1]
+    one_product = f"f32[{bs},{bs}] = dot_general" in text  # FᵀF would be the step's one (bs, bs) product
+    return carry, float(residual_sq), one_product, (R, np.stack(W), np.stack(G), y_sq)
+
+
+def _assert_the_plain_epoch(carry, residual_sq, plain, n_true):
+    """The Gramians to 2e-6 of their largest entry (float32 sums of a
+    thousand products against float64's: they read 6e-7 at most), the stash
+    symmetric to the bit, the factor that of the stash (1e-6 read), weights
+    and residual to 2e-4 (a float32 solve of these toy systems against a
+    float64 one reads 5e-5 and 6e-5 at most)."""
+    (R, W, G, C, _), (R0, W0, G0, y_sq) = carry, plain
+    assert bool(jnp.array_equal(G, jnp.swapaxes(G, 1, 2)))
+    np.testing.assert_allclose(np.asarray(G), G0, atol=2e-6 * np.abs(G0).max(), rtol=0)
+    L = np.tril(np.asarray(C, np.float64))
+    np.testing.assert_allclose(L @ np.swapaxes(L, 1, 2), G0 + LAM * np.eye(G0.shape[-1]),
+                               atol=2e-5 * np.abs(G0).max(), rtol=0)
+    np.testing.assert_allclose(np.asarray(W), W0, atol=2e-4, rtol=2e-4)
+    np.testing.assert_allclose(np.asarray(R)[:n_true], R0, atol=2e-4, rtol=2e-4)
+    assert 0 < residual_sq < y_sq and residual_sq == pytest.approx((R0 * R0).sum(), rel=1e-4)
+
+
+@pytest.mark.parametrize("center", [True, False])
+@pytest.mark.parametrize("devices,n_pad,n_true,tile_rows", [
+    (1, 1024, None, None),   # one slab, cell 4's form
+    (1, 704, 700, 256),      # two whole tiles and a ragged third, its last rows masked
+    (8, 1600, 1594, None),   # a slab a device, the psum before the mirror
+])
+def test_first_epoch_gramians_are_the_plain_product(devices, n_pad, n_true, tile_rows, center):
+    """Epoch 1's carry at the least block made of several panels (3 of its 4
+    panel products are computed) against FᵀF as one product made here."""
+    bs = 2 * streaming._GRAM_PANEL
+    carry, residual_sq, one_product, plain = _first_epoch(
+        n_pad, n_true, tile_rows, bs=bs, devices=devices, center=center)
+    assert not one_product
+    _assert_the_plain_epoch(carry, residual_sq, plain, n_true)
+
+
+@pytest.mark.parametrize("bs", [BS, 256, 384, 512, 768])
+def test_every_block_width_goes_through_the_panels(bs):
+    """Under a panel, one panel, a panel and a half, two, three: one form —
+    a block of one panel is the full product (mirrored all the same), a last
+    panel may be narrower. Rows ragged, masked and tiled with a remainder,
+    the kernels on (interpreted here)."""
+    # twice the rows of the widest block: the toy systems stay well conditioned
+    carry, residual_sq, one_product, plain = _first_epoch(1700, 1690, 512, bs=bs, use_pallas=True)
+    assert one_product == (bs <= streaming._GRAM_PANEL)
+    _assert_the_plain_epoch(carry, residual_sq, plain, 1690)
+
+
+@pytest.mark.parametrize("bs,products", [(4096, 16), (4352, 9), (8192, 16), (16384, 16)])
+def test_a_wider_block_takes_wider_panels_not_more(bs, products):
+    """Sixteen products at most, whatever the block: the width grows in
+    whole 256-column steps (nothing runs: the products are counted)."""
+    text = str(jax.make_jaxpr(lambda F: streaming._gram_upper_panels(F, jnp.float32))(
+        jax.ShapeDtypeStruct((8, bs), jnp.float32)))
+    assert text.count("dot_general") == products
+
+
+@pytest.mark.parametrize("bs", [BS, 256, 384, 512, 768])
+def test_estimator_fit_says_which_form_its_gramians_took(bs):
+    """``gram`` on ``estimator.fit`` and in the ``streaming_tier`` decision:
+    the one form every block width takes."""
+    from keystone_tpu.data import Dataset
+    from keystone_tpu.workflow.pipeline import _stamped_fit
+
+    d_feat = 2 * bs
+    _, bank = _choice(None, d_feat)
+    rng = np.random.default_rng(6)
+    X = rng.normal(size=(300, D_IN)).astype(np.float32)
+    Y = rng.normal(size=(300, K)).astype(np.float32) + 0.3
+    est = BlockStreamedLeastSquares(bank, d_feat, bs, num_iter=2, lam=LAM, tile_rows=128)
+    with obs.tracing() as tracer:
+        model = _stamped_fit(est, lambda: est.fit(Dataset.of(X), Dataset.of(Y)))
+    attrs = next(s["args"] for s in tracer.spans("estimator.fit"))
+    assert attrs["gram"] == "sym_dot"
+    assert bool(jnp.all(jnp.isfinite(model.W_stack)))
+    wide = 16 * bs  # wide enough that the Gramian is past a budget the stash fits
+    choice, bank = _choice(None, wide, hint=bs)
+    choice.budget_bytes = _fixed(choice, bank, wide, 300) + 8 * wide * bs + 1
+    with obs.tracing() as tracer:
+        choice.build_estimator(bank, wide, local_rows=300, k=K)
+    decision = next(e["args"] for e in tracer.events if e["type"] == "event"
+                    and e["name"] == "cost.decision")
+    assert decision["winner"] == "block" and decision["gram"] == "sym_dot"
+
+
+def test_the_cell_gramian_panels_compile_with_no_copy_of_the_slab(one_chip, compile_for_chip):
+    """The panels at ``timit_block_fit_131k``'s slab, 131,072 x 4,096
+    float32, compiled for the described chip (nothing runs): the column
+    slices must fuse into the products — a copy of one would be up to the
+    slab's 2.1 GB."""
+    slab = jax.ShapeDtypeStruct((131072, 4096), jnp.float32, sharding=one_chip)
+    compiled = compile_for_chip(
+        lambda F: streaming._gram_upper_panels(F, jnp.float32), slab)
+    # the panels and their joined triangle: a few (4096, 4096) arrays, nothing of the slab's size
+    assert compiled.memory_analysis().temp_size_in_bytes <= 4 * 4 * 4096 * 4096

@@ -135,37 +135,16 @@ def test_a_fit_says_how_it_densified_and_both_forms_fit_alike(monkeypatch):
     assert (attrs["pallas"], attrs["densify"]) == (True, "contract")
 
 
-@pytest.fixture(scope="module")
-def one_chip():
-    from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
-
-    try:
-        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
-    except Exception as e:
-        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
-
-
 @pytest.mark.parametrize("val_dtype,d_pad", [(jnp.bfloat16, 17408), (jnp.float32, 16896)],
                          ids=["bfloat16", "float32"])
-def test_mosaic_takes_the_kernel_at_the_amazon_cell_chunk_shape(one_chip, val_dtype, d_pad):
+def test_mosaic_takes_the_kernel_at_the_amazon_cell_chunk_shape(one_chip, compile_for_chip, val_dtype, d_pad):
     """Compiled here for the described chip (nothing runs): the interpreter
     cannot say whether Mosaic accepts the strided reads and the row loads."""
-    from jax.experimental.compilation_cache import compilation_cache
-
     rows = jax.ShapeDtypeStruct((65536, 83), jnp.int32, sharding=one_chip)
     vals = jax.ShapeDtypeStruct((65536, 83), jnp.float32, sharding=one_chip)
-    jax.config.update("jax_enable_compilation_cache", False)  # a described chip's entry cannot be read back
-    compilation_cache.reset_cache()
-    try:
-        with jax.enable_x64(False):  # as outside the tests
-            compiled = jax.jit(
-                lambda i, v: densify_rows(i, v, 16385, d_pad, val_dtype, use_pallas=True, interpret=False)
-            ).lower(rows, vals).compile()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", True)
-        compilation_cache.reset_cache()
+    compiled = compile_for_chip(
+        lambda i, v: densify_rows(i, v, 16385, d_pad, val_dtype, use_pallas=True, interpret=False),
+        rows, vals)
     assert "tpu_custom_call" in compiled.as_text() and "sparse_densify" in compiled.as_text()
     # the slab and nothing of its size beside it (the scatter held a second one)
     assert compiled.memory_analysis().temp_size_in_bytes < 0.1 * 65536 * d_pad
