@@ -25,15 +25,25 @@ Transformers consume and produce Datasets; solvers read ``.array`` +
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Callable, List, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec as P
 
+from keystone_tpu import obs
 from keystone_tpu.parallel import mesh as mesh_lib
 
 from .prefetch import ShardSource
+
+
+@functools.partial(jax.jit, static_argnames=("rows",))
+def _pad_rows_on_device(leaf, rows: int):
+    """``rows`` zero rows after a device array's last (a program: the zero
+    is its constant, nothing comes from the host)."""
+    return jnp.pad(leaf, ((0, rows),) + ((0, 0),) * (leaf.ndim - 1))
 
 
 def _is_arraylike(x: Any) -> bool:
@@ -202,19 +212,40 @@ class Dataset:
     # -- distribution -------------------------------------------------------
 
     def shard(self, mesh=None, axis: str = mesh_lib.DATA_AXIS) -> "Dataset":
-        """Pad to divisibility and shard the leading axis over the mesh."""
+        """Pad to divisibility and shard the leading axis over the mesh.
+
+        A leaf that lives on a device stays on the devices: it is padded
+        there (only if it must be) and laid over the mesh by ``device_put``;
+        one that already lies so is returned as it is. Host leaves are
+        padded on the host and placed from there."""
         if self.is_shard_backed:
             return self.materialize().shard(mesh, axis)
         if self.is_host:
             raise ValueError("Host datasets cannot be device-sharded; vectorize first")
         mesh = mesh or mesh_lib.default_mesh()
         size = mesh_lib.axis_size(mesh, axis)
+        moves = set()
 
         def place(leaf):
-            padded, _ = mesh_lib.pad_rows(np.asarray(leaf), size)
-            return mesh_lib.shard_rows(padded, mesh, axis)
+            want = NamedSharding(mesh, P(axis, *([None] * (np.ndim(leaf) - 1))))
+            if not isinstance(leaf, jax.Array):
+                moves.add("host")
+                padded, _ = mesh_lib.pad_rows(np.asarray(leaf), size)
+                return jax.device_put(padded, want)
+            if leaf.shape[0] % size:
+                leaf = _pad_rows_on_device(leaf, (-leaf.shape[0]) % size)
+            elif leaf.sharding.is_equivalent_to(want, leaf.ndim):
+                moves.add("none")
+                return leaf
+            moves.add("device")
+            return jax.device_put(leaf, want)
 
-        data = jax.tree_util.tree_map(place, self.data)
+        leaves = jax.tree_util.tree_leaves(self.data)
+        with obs.span("data.shard", devices=size,
+                      bytes=int(sum(leaf.nbytes for leaf in leaves))) as sp:
+            data = jax.tree_util.tree_map(place, self.data)
+            # the farthest any leaf went: over the host, between devices, nowhere
+            sp.set(moved=next(m for m in ("host", "device", "none") if m in moves))
         return Dataset(data, n=self.n, mesh=mesh)
 
     def cache(self) -> "Dataset":

@@ -106,7 +106,10 @@ class StreamingFeaturizedLeastSquares(LabelEstimator):
     function (e.g. a cosine random-feature bank). The fit is ONE compiled
     program per device (tile scan -> Gramian fold -> BCD epochs on the
     normal equations); sharded input runs the mesh form (per-device folds
-    + one psum). ``tile_rows=None`` sizes tiles to a ~2 GB feature slab.
+    + one psum round, the solve replicated) — the same program keyed the
+    same way, a bank's arrays its operands, so a sweep over new pipelines
+    compiles once on a mesh as on one device. ``tile_rows=None`` sizes
+    tiles to a ~2 GB feature slab.
     """
 
     def __init__(
@@ -183,46 +186,48 @@ class StreamingFeaturizedLeastSquares(LabelEstimator):
     def fit(self, data: Dataset, labels: Dataset) -> StreamingFeaturizedLinearModel:
         X = jnp.asarray(data.array)
         Y = jnp.asarray(labels.array)
-        multi = data.mesh is not None and any(
-            s > 1 for s in dict(data.mesh.shape).values()
+        mesh = data.mesh
+        if mesh is None or not any(s > 1 for s in dict(mesh.shape).values()):
+            mesh = None
+        shards = 1 if mesh is None else mesh_lib.axis_size(
+            mesh, mesh_lib.DATA_AXIS)
+        rows_local = X.shape[0] // shards
+        kw = dict(
+            featurize=self.featurize, d_feat=self.d_feat,
+            tile_rows=min(self.tile_rows, max(rows_local, 1)),
+            block_size=self.block_size, lam=self.lam,
+            num_iter=self.num_iter, mesh=mesh,
+            valid=int(data.n) if data.n != X.shape[0] else None,
         )
+        attrs = dict(rows=int(X.shape[0]), tile_rows=kw["tile_rows"])
+        if mesh is not None:
+            attrs.update(mesh_shape=tuple(mesh.devices.shape),
+                         rows_local=rows_local)
+            psum_bytes = mesh_psum_bytes(self.d_feat, Y.shape[-1], self.center)
+            obs.set_on_open("estimator.fit", engine="stream_mesh",
+                            devices=shards, psum_bytes=psum_bytes)
+            obs.counter_track("mesh.psum_bytes", psum_bytes)
+        # The one program of a streamed fit (``_streaming_fit_bank``, on one
+        # device or over the mesh the rows lie on): its dispatch, and
+        # whatever tracing or compiling it causes.
         fmean = ymean = None
-        if multi:
-            kw = dict(
-                featurize=self.featurize, d_feat=self.d_feat,
-                tile_rows=min(self.tile_rows, max(X.shape[0] // mesh_lib.axis_size(
-                    data.mesh, mesh_lib.DATA_AXIS), 1)),
-                block_size=self.block_size, lam=self.lam,
-                num_iter=self.num_iter, mesh=data.mesh, n_true=data.n,
-            )
+        with obs.span("solver.stream_fit", **attrs):
             if self.center:
-                W, fmean, ymean = streaming.streaming_bcd_fit_mesh_centered(
+                W, fmean, ymean, _ = streaming.streaming_bcd_fit_centered(
                     X, Y, **kw
                 )
             else:
-                W = streaming.streaming_bcd_fit_mesh(X, Y, **kw)
-        else:
-            kw = dict(
-                featurize=self.featurize, d_feat=self.d_feat,
-                tile_rows=min(self.tile_rows, X.shape[0]),
-                block_size=self.block_size, lam=self.lam,
-                num_iter=self.num_iter,
-                valid=int(data.n) if data.n != X.shape[0] else None,
-            )
-            # The one program of a single-device streamed fit
-            # (``_streaming_fit_bank``): its dispatch, and whatever
-            # tracing or compiling it causes.
-            with obs.span("solver.stream_fit", rows=int(X.shape[0]),
-                          tile_rows=kw["tile_rows"]):
-                if self.center:
-                    W, fmean, ymean, _ = streaming.streaming_bcd_fit_centered(
-                        X, Y, **kw
-                    )
-                else:
-                    W, _, _ = streaming.streaming_bcd_fit(X, Y, **kw)
+                W, _, _ = streaming.streaming_bcd_fit(X, Y, **kw)
         return StreamingFeaturizedLinearModel(
             self.featurize, W, self.tile_rows, fmean=fmean, ymean=ymean,
         )
+
+
+def mesh_psum_bytes(d_feat: int, k: int, center: bool = True) -> int:
+    """Bytes one device hands the mesh fit's one all-reduce round: the
+    float32 Gramian, FᵀY and Σy², and the column sums of a centred fit."""
+    return 4 * (d_feat * d_feat + d_feat * k + 1
+                + ((d_feat + k) if center else 0))
 
 
 class CosineBankFeaturize(streaming.BankFeaturize):

@@ -409,24 +409,33 @@ def as_bank(featurize) -> BankFeaturize:
 
 
 def _fit_core(X, Y, featurize, d_feat, tile_rows, block_size, lam,
-              num_iter, use_pallas, valid, labelize, center):
+              num_iter, use_pallas, valid, labelize, center, mesh=None):
     """Shared traceable fit body: tile folds → (optional rank-1 centering)
     → BCD on the normal equations. Returns (W, loss, yty, fmean, ymean);
-    fmean/ymean are None when ``center`` is False (static branch)."""
+    fmean/ymean are None when ``center`` is False (static branch).
+
+    ``mesh``: the rows lie sharded over its data axis; each device folds
+    its own (:func:`gram_stats_mesh`, one psum round) and the solve is
+    replicated. ``valid`` is then the true GLOBAL row count."""
     n_true = valid if valid is not None else (
         X.shape[0] if X.ndim == 2 else X.shape[0] * X.shape[1]
     )
-    if center:
-        G, FY, yty, fsum, ysum = gram_stats(
-            X, Y, featurize, d_feat, tile_rows, use_pallas=use_pallas,
-            valid=valid, labelize=labelize, moments=True,
+    if mesh is not None:
+        if labelize is not None or X.ndim != 2:
+            raise ValueError(
+                "the mesh fold takes (n, d_in) rows and ready targets: "
+                "pre-apply labelize to Y and leave the tiling to the fold"
+            )
+        stats = gram_stats_mesh(
+            X, Y, featurize, d_feat, tile_rows, mesh,
+            use_pallas=use_pallas, n_true=valid, moments=center,
         )
     else:
-        G, FY, yty = gram_stats(
+        stats = gram_stats(
             X, Y, featurize, d_feat, tile_rows, use_pallas=use_pallas,
-            valid=valid, labelize=labelize,
+            valid=valid, labelize=labelize, moments=center,
         )
-        fsum = ysum = None
+    G, FY, yty, fsum, ysum = stats if center else (*stats, None, None)
     # W blocks are laid out [b*block : (b+1)*block] along d, so Wf rows
     # align with G/FY rows (shared solve tail).
     W, loss, fmean, ymean = _solve_from_stats_core(
@@ -439,35 +448,41 @@ def _fit_core(X, Y, featurize, d_feat, tile_rows, block_size, lam,
     jax.jit,
     static_argnames=(
         "featurize", "d_feat", "tile_rows", "block_size", "num_iter",
-        "use_pallas", "valid", "labelize", "center",
+        "use_pallas", "valid", "labelize", "center", "mesh",
     ),
 )
 def _streaming_fit_closure(X, Y, *, featurize, d_feat, tile_rows,
                            block_size, lam, num_iter, use_pallas, valid,
-                           labelize, center):
+                           labelize, center, mesh=None):
     return _fit_core(X, Y, featurize, d_feat, tile_rows, block_size, lam,
-                     num_iter, use_pallas, valid, labelize, center)
+                     num_iter, use_pallas, valid, labelize, center, mesh)
 
 
 @functools.partial(
     jax.jit,
     static_argnames=(
         "bank_type", "bank_key", "d_feat", "tile_rows", "block_size",
-        "num_iter", "use_pallas", "valid", "labelize", "center",
+        "num_iter", "use_pallas", "valid", "labelize", "center", "mesh",
     ),
 )
 def _streaming_fit_bank(X, Y, bank_params, *, bank_type, bank_key, d_feat,
                         tile_rows, block_size, lam, num_iter, use_pallas,
-                        valid, labelize, center):
+                        valid, labelize, center, mesh=None):
     featurize = lambda X_t: bank_type.apply_bank(bank_key, bank_params, X_t)  # noqa: E731
     return _fit_core(X, Y, featurize, d_feat, tile_rows, block_size, lam,
-                     num_iter, use_pallas, valid, labelize, center)
+                     num_iter, use_pallas, valid, labelize, center, mesh)
 
 
 def _dispatch_fit(X, Y, featurize, center, kw):
+    """The one program of a streamed fit, on one device or (``kw["mesh"]``)
+    over a mesh: a bank's arrays ride as operands either way."""
     if isinstance(featurize, BankFeaturize):
+        params = featurize.params
+        if kw["mesh"] is not None:
+            # A bank drawn on one device goes where the rows are.
+            params = mesh_lib.replicate(params, kw["mesh"])
         return _streaming_fit_bank(
-            X, Y, featurize.params, bank_type=type(featurize),
+            X, Y, params, bank_type=type(featurize),
             bank_key=featurize.static_key(), center=center, **kw,
         )
     return _streaming_fit_closure(
@@ -506,33 +521,17 @@ def streaming_bcd_fit(
     ``mesh`` (ISSUE 16): shard the tile folds over the mesh's data axis
     (each device folds its row shard locally; ONE psum of the stats
     crosses the ICI — :func:`gram_stats_mesh`) with a replicated solve —
-    the same iterates as the 1-device fit up to reduction order. X rows
-    must divide evenly over the axis (pad and pass ``valid``);
-    ``labelize`` is not supported on this path (pre-apply it to Y).
+    the same iterates as the 1-device fit up to reduction order, and the
+    same one program a geometry (a bank's arrays are its operands). X rows
+    must divide evenly over the axis (pad and pass the true GLOBAL count
+    as ``valid``; padding rows may hold any value, their feature rows are
+    zeroed); ``labelize`` is not supported on this path (pre-apply it to Y).
     """
-    if mesh is not None:
-        if labelize is not None:
-            raise ValueError(
-                "labelize is not supported with mesh=; pre-apply it to Y "
-                "(the mesh fold shards Y rows alongside X)"
-            )
-        n_true = valid if valid is not None else (
-            X.shape[0] if X.ndim == 2 else X.shape[0] * X.shape[1]
-        )
-        G, FY, yty = gram_stats_mesh(
-            X, Y, featurize, d_feat, tile_rows, mesh,
-            use_pallas=use_pallas, n_true=valid,
-        )
-        W, loss, _, _ = _solve_from_stats_core(
-            G, FY, yty, None, None, n_true, lam, block_size, num_iter,
-            False,
-        )
-        return W, loss, yty
     W, loss, yty, _, _ = _dispatch_fit(
         X, Y, featurize, False,
         dict(d_feat=d_feat, tile_rows=tile_rows, block_size=block_size,
              lam=lam, num_iter=num_iter, use_pallas=use_pallas,
-             valid=valid, labelize=labelize),
+             valid=valid, labelize=labelize, mesh=mesh),
     )
     return W, loss, yty
 
@@ -791,6 +790,7 @@ def streaming_bcd_fit_centered(
     use_pallas: bool = False,
     valid: Optional[int] = None,
     labelize: Optional[Callable[[Array], Array]] = None,
+    mesh=None,
 ) -> Tuple[Array, Array, Array, Array]:
     """Mean-centered one-dispatch streamed fit — the streamed form of
     ``BlockLeastSquaresEstimator`` semantics (per-block feature centering +
@@ -800,13 +800,15 @@ def streaming_bcd_fit_centered(
 
     Returns (W, fmean, ymean, loss): predictions are
     (F − fmean) @ W_flat + ymean — the same affine model BlockLinearMapper
-    applies. ``lam`` is traced (λ-sweeps share one executable).
+    applies. ``lam`` is traced (λ-sweeps share one executable). ``mesh``:
+    as :func:`streaming_bcd_fit` — the column sums ride the same psum
+    round as G and FᵀY.
     """
     W, loss, _, fmean, ymean = _dispatch_fit(
         X, Y, featurize, True,
         dict(d_feat=d_feat, tile_rows=tile_rows, block_size=block_size,
              lam=lam, num_iter=num_iter, use_pallas=use_pallas,
-             valid=valid, labelize=labelize),
+             valid=valid, labelize=labelize, mesh=mesh),
     )
     return W, fmean, ymean, loss
 
@@ -1635,7 +1637,8 @@ def gram_stats_mesh(
             xs, ys, featurize, d_feat, tile_rows, use_pallas=use_pallas,
             valid=valid, moments=moments,
         )
-        return tuple(jax.lax.psum(s, axis) for s in stats)
+        with jax.named_scope("ks.gram_psum"):  # the fit's one collective round
+            return jax.lax.psum(stats, axis)
 
     n_out = 5 if moments else 3
     return mesh_lib.shard_map(
@@ -1645,73 +1648,3 @@ def gram_stats_mesh(
         out_specs=tuple(P() for _ in range(n_out)),
         check_vma=False,
     )(X, Y)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "featurize", "d_feat", "tile_rows", "block_size", "num_iter",
-        "mesh", "use_pallas", "n_true",
-    ),
-)
-def streaming_bcd_fit_mesh(
-    X: Array,
-    Y: Array,
-    *,
-    featurize: Callable[[Array], Array],
-    d_feat: int,
-    tile_rows: int,
-    block_size: int,
-    lam: float,
-    num_iter: int,
-    mesh,
-    use_pallas: bool = False,
-    n_true: Optional[int] = None,
-) -> Array:
-    """Mesh streamed fit: sharded tile folds + one psum + replicated solve.
-
-    X/Y rows sharded (or shardable) over the mesh's data axis; when padded
-    to shard evenly, pass the true global row count as ``n_true`` and the
-    trailing padding is masked per shard (padding rows in X may hold any
-    value — their feature rows are zeroed after featurization).
-    """
-    G, FY, _ = gram_stats_mesh(
-        X, Y, featurize, d_feat, tile_rows, mesh, use_pallas=use_pallas,
-        n_true=n_true,
-    )
-    return bcd_from_gram(G, FY, block_size, lam, num_iter)
-
-
-@functools.partial(
-    jax.jit,
-    static_argnames=(
-        "featurize", "d_feat", "tile_rows", "block_size", "num_iter",
-        "mesh", "use_pallas", "n_true",
-    ),
-)
-def streaming_bcd_fit_mesh_centered(
-    X: Array,
-    Y: Array,
-    *,
-    featurize: Callable[[Array], Array],
-    d_feat: int,
-    tile_rows: int,
-    block_size: int,
-    lam,
-    num_iter: int,
-    mesh,
-    use_pallas: bool = False,
-    n_true: Optional[int] = None,
-) -> Tuple[Array, Array, Array]:
-    """Mesh form of :func:`streaming_bcd_fit_centered`: sharded tile folds
-    (column sums psum'd alongside G/FY — still ONE collective round per
-    fit), rank-1 centering corrections, replicated solve. Returns
-    (W, fmean, ymean)."""
-    G, FY, yty, fsum, ysum = gram_stats_mesh(
-        X, Y, featurize, d_feat, tile_rows, mesh, use_pallas=use_pallas,
-        n_true=n_true, moments=True,
-    )
-    n = n_true if n_true is not None else X.shape[0]
-    Gc, FYc, _, fmean, ymean = center_gram_stats(G, FY, yty, fsum, ysum, n)
-    W = bcd_from_gram(Gc, FYc, block_size, lam, num_iter)
-    return W, fmean, ymean

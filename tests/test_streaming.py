@@ -293,10 +293,10 @@ class TestStreamingCentered:
         Yp = jnp.concatenate(
             [Y, jnp.asarray(rng.normal(size=(pad, K)).astype(np.float32))]
         )
-        W_mesh, fm_m, ym_m = streaming.streaming_bcd_fit_mesh_centered(
+        W_mesh, fm_m, ym_m, _ = streaming.streaming_bcd_fit_centered(
             mesh_lib.shard_rows(Xp, mesh), mesh_lib.shard_rows(Yp, mesh),
             featurize=featurize, d_feat=D_FEAT, tile_rows=64,
-            block_size=BLOCK, lam=LAM, num_iter=2, mesh=mesh, n_true=n_true,
+            block_size=BLOCK, lam=LAM, num_iter=2, mesh=mesh, valid=n_true,
         )
         W_one, fm_1, ym_1, _ = streaming.streaming_bcd_fit_centered(
             X, Y, featurize=featurize, d_feat=D_FEAT, tile_rows=64,
@@ -395,9 +395,9 @@ class TestStreamingMesh:
         )
         Xs = mesh_lib.shard_rows(Xp, mesh)
         Ys = mesh_lib.shard_rows(Yp, mesh)
-        W_mesh = streaming.streaming_bcd_fit_mesh(
+        W_mesh, _, _ = streaming.streaming_bcd_fit(
             Xs, Ys, featurize=featurize, d_feat=D_FEAT, tile_rows=64,
-            block_size=BLOCK, lam=LAM, num_iter=2, mesh=mesh, n_true=n_true,
+            block_size=BLOCK, lam=LAM, num_iter=2, mesh=mesh, valid=n_true,
         )
         W_one, _, _ = streaming.streaming_bcd_fit(
             X, Y, featurize=featurize, d_feat=D_FEAT, tile_rows=64,
