@@ -109,7 +109,9 @@ class StreamingFeaturizedLeastSquares(LabelEstimator):
     + one psum round, the solve replicated) — the same program keyed the
     same way, a bank's arrays its operands, so a sweep over new pipelines
     compiles once on a mesh as on one device. ``tile_rows=None`` sizes
-    tiles to a ~2 GB feature slab.
+    tiles to a ~2 GB feature slab. The fold adds the upper block-triangle
+    of each tile's FᵀF alone, mirrored once a fit (``estimator.fit`` says
+    ``gram="sym_dot"`` and in how many panels, ``gram_panels``).
     """
 
     def __init__(
@@ -170,6 +172,7 @@ class StreamingFeaturizedLeastSquares(LabelEstimator):
 
         def build(params):
             W, fmean, ymean = params
+            _note_gram(d_feat)
             return StreamingFeaturizedLinearModel(
                 bank, W, tile_rows, fmean=fmean, ymean=ymean,
             )
@@ -200,6 +203,7 @@ class StreamingFeaturizedLeastSquares(LabelEstimator):
             valid=int(data.n) if data.n != X.shape[0] else None,
         )
         attrs = dict(rows=int(X.shape[0]), tile_rows=kw["tile_rows"])
+        _note_gram(self.d_feat)
         if mesh is not None:
             attrs.update(mesh_shape=tuple(mesh.devices.shape),
                          rows_local=rows_local)
@@ -221,6 +225,15 @@ class StreamingFeaturizedLeastSquares(LabelEstimator):
         return StreamingFeaturizedLinearModel(
             self.featurize, W, self.tile_rows, fmean=fmean, ymean=ymean,
         )
+
+
+def _note_gram(d_feat: int) -> None:
+    """What ``estimator.fit`` says of a streamed fit's d x d Gramian: the
+    fold adds a tile's upper block-triangle alone (``gram``, the block
+    tier's constant) in ``gram_panels`` panels of columns
+    (``streaming._tile_update``)."""
+    obs.set_on_open("estimator.fit", gram=streaming.BLOCK_GRAM,
+                    gram_panels=streaming.gram_panels(d_feat))
 
 
 def mesh_psum_bytes(d_feat: int, k: int, center: bool = True) -> int:
@@ -391,6 +404,7 @@ def _fit_paired_source(source, featurize, d_feat: int, block_size: int,
     ``KEYSTONE_CHECKPOINT_DIR``) makes the fold resumable — a killed fit
     re-run with the same spec continues from its last snapshot,
     bit-identically (docs/reliability.md)."""
+    _note_gram(d_feat)
     W, fmean, ymean, _ = streaming.streaming_bcd_fit_segments(
         source, bank=streaming.as_bank(featurize), d_feat=d_feat,
         block_size=block_size, lam=lam, num_iter=num_iter, center=center,

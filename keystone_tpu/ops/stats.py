@@ -8,6 +8,7 @@ from them), replacing the reference's implicit global RNG draws.
 
 from __future__ import annotations
 
+import weakref
 from dataclasses import dataclass, field
 from typing import Callable
 
@@ -152,6 +153,44 @@ class CosineRandomFeaturesModel(Transformer):
         return data.map_batch(self.device_fn())._rezero_padding()
 
 
+# Drawn banks by what they were drawn from. A sweep builds a new pipeline a
+# fit and every one draws the same bank from the same seed; arrays are
+# immutable, so equal draws can be ONE set of device buffers. Weak-valued:
+# an entry lives exactly as long as some pipeline holds its arrays — no
+# bound, no eviction order, nothing pinned after a sweep ends.
+_DRAWN_BANKS: "weakref.WeakValueDictionary" = weakref.WeakValueDictionary()
+
+
+def cosine_draw_key(
+    num_input_features: int,
+    num_output_features: int,
+    gamma: float,
+    seed: int,
+    cauchy: bool = False,
+) -> tuple:
+    """What a :func:`CosineRandomFeatures` draw is a function of: its
+    arguments, the dtype the draw comes out in (it follows
+    ``jax_enable_x64``) and the device a draw would land on."""
+    return (
+        "cosine", int(num_input_features), int(num_output_features),
+        float(gamma), int(seed), "cauchy" if cauchy else "gaussian",
+        jnp.result_type(float).name, jax.config.jax_default_device,
+    )
+
+
+def shared_bank(key, draw: Callable[[], tuple]):
+    """``(W, b, shared)``: the bank an earlier ``shared_bank(key, ...)``
+    made, where something still holds both of its arrays (``shared``
+    True), else ``draw()``'s, kept for the next caller for as long as this
+    one keeps them."""
+    W, b = _DRAWN_BANKS.get((key, "W")), _DRAWN_BANKS.get((key, "b"))
+    if W is not None and b is not None:
+        return W, b, True
+    W, b = draw()
+    _DRAWN_BANKS[(key, "W")], _DRAWN_BANKS[(key, "b")] = W, b
+    return W, b, False
+
+
 def CosineRandomFeatures(
     num_input_features: int,
     num_output_features: int,
@@ -160,14 +199,25 @@ def CosineRandomFeatures(
     cauchy: bool = False,
 ) -> CosineRandomFeaturesModel:
     """Draw W ~ gaussian(·γ) (or cauchy(·γ)), b ~ U[0, 2π]
-    (reference: CosineRandomFeatures.scala:50-61)."""
-    kw, kb = jax.random.split(jax.random.key(seed))
-    if cauchy:
-        W = jax.random.cauchy(kw, (num_output_features, num_input_features)) * gamma
-    else:
-        W = jax.random.normal(kw, (num_output_features, num_input_features)) * gamma
-    b = jax.random.uniform(kb, (num_output_features,)) * (2 * jnp.pi)
-    return CosineRandomFeaturesModel(W, b)
+    (reference: CosineRandomFeatures.scala:50-61). Equal draws share their
+    device arrays (:func:`shared_bank`); the model's ``shared_draw`` says
+    whether this one took an earlier draw's."""
+
+    def draw():
+        kw, kb = jax.random.split(jax.random.key(seed))
+        shape = (num_output_features, num_input_features)
+        sample = jax.random.cauchy if cauchy else jax.random.normal
+        return (sample(kw, shape) * gamma,
+                jax.random.uniform(kb, (num_output_features,)) * (2 * jnp.pi))
+
+    W, b, shared = shared_bank(
+        cosine_draw_key(num_input_features, num_output_features, gamma,
+                        seed, cauchy),
+        draw,
+    )
+    model = CosineRandomFeaturesModel(W, b)
+    model.shared_draw = shared
+    return model
 
 
 def padded_pow2(n: int) -> int:

@@ -8,7 +8,8 @@ exists on any machine. This module is the TPU-native analog: features are
 *generated per row tile* inside a scanned sweep (fused featurize kernel),
 each tile contributes
 
-    G  += FₜᵀFₜ          (accumulating symmetric Pallas kernel — syrk)
+    G  += triu(FₜᵀFₜ)    (a symmetric rank-k update: the upper
+                          block-triangle alone, mirrored once a fit)
     FY += FₜᵀYₜ
     yty += ΣYₜ²
 
@@ -94,6 +95,58 @@ def _row_mask(M, valid):
     return jnp.where(idx < valid, M, jnp.zeros((), M.dtype))
 
 
+# What ``estimator.fit`` says of a fit's Gramians (``gram``), in the block
+# tier (epoch 1's, a block) and in the streamed fold (a tile's share of the
+# d x d one): the upper block-triangle alone in plain ``dot_general``s,
+# mirrored once.
+BLOCK_GRAM = "sym_dot"
+
+# Least column width of the panels that triangle is made of, and the most
+# panels a width is cut into (wider slabs take wider panels, so that a
+# step's products and their temporaries do not grow in number). Read on a
+# v5e at 131,072 x 4,096 float32: 74.0 ms at 256, 77.8 at 512, 86.3 at
+# 1,024, one full dot 137.0 (PERF.md section 6, PR 33).
+_GRAM_PANEL = 256
+_GRAM_PANELS = 16
+
+
+def gram_panel_width(d: int) -> int:
+    """Columns a panel of a ``d``-wide slab's triangle takes: 256 up to a
+    width of 4,096, then the sixteenth of the width rounded up to 256s."""
+    return _GRAM_PANEL * max(1, -(-d // (_GRAM_PANELS * _GRAM_PANEL)))
+
+
+def gram_panels(d: int) -> int:
+    """How many panels that makes (``estimator.fit``'s ``gram_panels``)."""
+    return -(-d // gram_panel_width(d))
+
+
+def _gram_panel_products(F, acc):
+    """(first column, product) of each panel of FᵀF's upper block-triangle:
+    the panel's columns against the columns from its own on, as plain
+    ``dot_general``s at the slab's own precision (the column slices fuse
+    into the products: no copy of the slab). Any width: a slab of one panel
+    is the full product, and a last panel may be narrower."""
+    width = gram_panel_width(F.shape[1])
+    for lo in range(0, F.shape[1], width):
+        yield lo, jax.lax.dot_general(
+            F[:, lo:lo + width], F[:, lo:], (((0,), (0,)), ((), ())),
+            preferred_element_type=acc,
+        ).astype(jnp.float32)
+
+
+def _gram_upper_panels(F, acc):
+    """The upper block-triangle of FᵀF, zeros under it — 136 of the 256
+    panel pairs at sixteen panels. The block tier's epoch-1 Gramian of one
+    tile (``_block_sweep``); the streamed fold adds the same products into
+    its carry without joining them (:func:`_tile_update`)."""
+    return jnp.concatenate(
+        [jnp.pad(panel, ((0, 0), (lo, 0)))
+         for lo, panel in _gram_panel_products(F, acc)],
+        axis=0,
+    )
+
+
 def _tile_update(G, FY, yty, fsum, ysum, X_t, Y_t, featurize, use_pallas,
                  valid: Optional[Array]):
     """Fold one row tile into (G, FY, yty, fsum, ysum). ``valid`` (traced
@@ -107,9 +160,12 @@ def _tile_update(G, FY, yty, fsum, ysum, X_t, Y_t, featurize, use_pallas,
     The column sums (fsum, ysum) ride the same pass so the centered
     solvers get their means for free — two vector reductions per tile,
     ~1/d_feat of the syrk's work.
-    """
-    from keystone_tpu.ops import pallas_ops
 
+    G takes the tile's UPPER block-triangle alone (the strict lower
+    triangle of the carry stays what it was): the caller mirrors once,
+    after its last tile. ``use_pallas`` is not read — one Gramian form
+    whatever the entry passes (ROADMAP D4 takes the argument's thread out).
+    """
     # The scopes name the phases in a device profile (trace-time only).
     with jax.named_scope("ks.featurize"):
         F_t = featurize(X_t)
@@ -118,12 +174,13 @@ def _tile_update(G, FY, yty, fsum, ysum, X_t, Y_t, featurize, use_pallas,
             Y_t = _row_mask(Y_t, valid)
     acc = jnp.promote_types(F_t.dtype, jnp.float32)
     with jax.named_scope("ks.gram_fold"):
-        if use_pallas and pallas_ops.gram_acc_ok(F_t):
-            G = pallas_ops.gram_sym_acc(G, F_t)
-        else:
-            G = G + jax.lax.dot_general(
-                F_t, F_t, (((0,), (0,)), ((), ())), preferred_element_type=acc,
-            ).astype(jnp.float32)
+        # The upper block-triangle of F_tᵀF_t alone, panel by panel into
+        # its own slice of the carry: XLA makes each panel one fusion of
+        # the product, the add and the in-place update.
+        for lo, panel in _gram_panel_products(F_t, acc):
+            G = jax.lax.dynamic_update_slice(
+                G, G[lo:lo + panel.shape[0], lo:] + panel, (lo, lo)
+            )
         FY = FY + jax.lax.dot_general(
             F_t, Y_t.astype(F_t.dtype), (((0,), (0,)), ((), ())),
             preferred_element_type=acc,
@@ -175,8 +232,12 @@ def gram_stats(
     zeroed — a zero input row still featurizes to cos(b) ≠ 0). A static
     int masks only the boundary tile (full tiles before it run unmasked,
     tiles past it are skipped at trace time); a traced scalar masks every
-    tile — mesh callers with per-shard counts use that form. Returns G
-    with BOTH triangles valid.
+    tile — mesh callers with per-shard counts use that form.
+
+    Every tile adds the upper block-triangle of its F_tᵀF_t alone
+    (:func:`_gram_panel_products`: sixteen panels of 1,024 columns at
+    d = 16,384, 136 of the 256 panel pairs); the sum is mirrored once,
+    here, so G returns with BOTH triangles valid, symmetric to the bit.
     """
     pre_tiled = X.ndim == 3
     if pre_tiled:
@@ -272,8 +333,7 @@ def gram_stats(
             carry = fold(carry, X_r, y_r, rv)
 
     G, FY, yty, fsum, ysum = carry
-    # The Pallas accumulation writes upper-triangle blocks only; mirroring
-    # from triu is also exact for the XLA path (G symmetric).
+    # The fold adds upper-triangle panels only: the fit's one mirror.
     G = jnp.triu(G) + jnp.triu(G, 1).T
     if moments:
         return G, FY, yty, fsum, ysum
@@ -548,6 +608,9 @@ def _dense_segment_fold(carry, X_seg, Y_seg, valid_rows, bank_params, *,
     ``valid_rows`` (traced) masks the ragged tail of the LAST segment.
     The featurize bank rides as traced operands (BankFeaturize contract:
     one compiled fold for every segment and every logically-equal bank).
+    G's carry holds the UPPER block-triangle of the sum (every tile adds
+    that alone, :func:`_tile_update`); the caller mirrors it once, after
+    the last segment.
     """
     featurize = lambda X_t: bank_type.apply_bank(bank_key, bank_params, X_t)  # noqa: E731
     G, FY, yty, fsum, ysum = carry
@@ -863,38 +926,6 @@ def _row_tiles(ln: int, tile_rows: Optional[int]) -> Tuple[int, int, int]:
     if tile_rows is None or ln <= tile_rows:
         return ln, 1, 0
     return tile_rows, ln // tile_rows, ln % tile_rows
-
-
-# What ``estimator.fit`` of the block tier says of its epoch-1 Gramians
-# (``gram``): the upper block-triangle in plain ``dot_general``s, mirrored.
-BLOCK_GRAM = "sym_dot"
-
-# Least column width of the panels that triangle is made of, and the most
-# panels a block is cut into (wider blocks take wider panels, so that a
-# step's products and their temporaries do not grow in number). Read on a
-# v5e at 131,072 x 4,096 float32 only: 74.0 ms at 256, 77.8 at 512, 86.3 at
-# 1,024, one full dot 137.0 (PERF.md section 6, PR 33).
-_GRAM_PANEL = 256
-_GRAM_PANELS = 16
-
-
-def _gram_upper_panels(F, acc):
-    """The upper block-triangle of FᵀF, zeros under it: each panel of
-    columns against the columns from its own on — 136 of the 256 panel
-    pairs at a block of 4,096 — as plain ``dot_general``s at the slab's own
-    precision (the column slices fuse into the products: no copy of the
-    slab). Any width: a block of one panel is the full product, and a last
-    panel may be narrower."""
-    bs = F.shape[1]
-    width = _GRAM_PANEL * max(1, -(-bs // (_GRAM_PANELS * _GRAM_PANEL)))
-    rows = []
-    for lo in range(0, bs, width):
-        panel = jax.lax.dot_general(
-            F[:, lo:lo + width], F[:, lo:], (((0,), (0,)), ((), ())),
-            preferred_element_type=acc,
-        ).astype(jnp.float32)
-        rows.append(jnp.pad(panel, ((0, 0), (lo, 0))))
-    return jnp.concatenate(rows, axis=0)
 
 
 def _block_sweep(x_local, Wrf, brf, lam_t, valid, *, axis, block_size,

@@ -17,7 +17,11 @@ from keystone_tpu import obs
 from keystone_tpu.data.loaders import TimitFeaturesDataLoader, synthetic_timit
 from keystone_tpu.evaluation import MulticlassClassifierEvaluator
 from keystone_tpu.ops.learning.block import BlockLeastSquaresEstimator
-from keystone_tpu.ops.stats import CosineRandomFeatures
+from keystone_tpu.ops.stats import (
+    CosineRandomFeatures,
+    cosine_draw_key,
+    shared_bank,
+)
 from keystone_tpu.ops.util import (
     ClassLabelIndicatorsFromIntLabels,
     MaxClassifier,
@@ -64,6 +68,27 @@ class TimitConfig:
     streaming: bool = False
 
 
+def _branch_draw(config: TimitConfig, i: int) -> tuple:
+    """Branch ``i``'s arguments to ``CosineRandomFeatures`` (and to the key
+    its draw is shared under): its own seed, everything else the config's."""
+    return (NUM_INPUT_FEATURES, config.block_size, config.gamma,
+            config.seed + i, config.rf_type == "cauchy")
+
+
+def _draw_branches(config: TimitConfig) -> list:
+    """One cosine bank a branch."""
+    return [CosineRandomFeatures(*_branch_draw(config, i))
+            for i in range(config.num_cosines)]
+
+
+def _note_banks(span, branches: int, shared: int) -> None:
+    """What ``pipeline.build`` says of its banks: how many branches' arrays
+    were drawn anew and how many are an earlier equal draw's buffers (a
+    sweep's every fit after its first shares them all)."""
+    span.set(banks_drawn=branches - shared, banks_shared=shared)
+    obs.counter_track("bank.shared", shared)
+
+
 def build_featurizer(config: TimitConfig) -> Pipeline:
     """numCosines branches of 4096 random features each
     (TimitPipeline.scala:61-78: numCosineFeatures = 4096 per batch)."""
@@ -71,18 +96,12 @@ def build_featurizer(config: TimitConfig) -> Pipeline:
     # Drawing the banks and building the graph: what every new fit pays
     # before ``pipeline.fit`` opens.
     with obs.span("pipeline.build", entry="featurizer",
-                  branches=config.num_cosines):
-        branches = [
-            CosineRandomFeatures(
-                NUM_INPUT_FEATURES,
-                config.block_size,
-                config.gamma,
-                seed=config.seed + i,
-                cauchy=(config.rf_type == "cauchy"),
-            ).to_pipeline()
-            for i in range(config.num_cosines)
-        ]
-        return Pipeline.gather(branches).and_then(VectorCombiner())
+                  branches=config.num_cosines) as span:
+        rfs = _draw_branches(config)
+        _note_banks(span, len(rfs), sum(rf.shared_draw for rf in rfs))
+        return Pipeline.gather(
+            [rf.to_pipeline() for rf in rfs]
+        ).and_then(VectorCombiner())
 
 
 def streaming_estimator(config: TimitConfig):
@@ -98,20 +117,29 @@ def streaming_estimator(config: TimitConfig):
 
     follow_profiler()
     with obs.span("pipeline.build", entry="streaming",
-                  branches=config.num_cosines):
-        rfs = [
-            CosineRandomFeatures(
-                NUM_INPUT_FEATURES, config.block_size, config.gamma,
-                seed=config.seed + i, cauchy=(config.rf_type == "cauchy"),
-            )
-            for i in range(config.num_cosines)
-        ]
-        bank = cosine_bank_featurize(
-            jnp.concatenate([rf.W for rf in rfs]),
-            jnp.concatenate([rf.b for rf in rfs]),
+                  branches=config.num_cosines) as span:
+        rfs = []
+
+        def join():
+            rfs.extend(_draw_branches(config))
+            return (jnp.concatenate([rf.W for rf in rfs]),
+                    jnp.concatenate([rf.b for rf in rfs]))
+
+        # The joined bank is shared by its branches' keys: where an
+        # earlier estimator's is still held, no branch is drawn at all.
+        Wrf, brf, joined_shared = shared_bank(
+            ("joined",) + tuple(cosine_draw_key(*_branch_draw(config, i))
+                                for i in range(config.num_cosines)),
+            join,
+        )
+        _note_banks(
+            span, config.num_cosines,
+            config.num_cosines if joined_shared
+            else sum(rf.shared_draw for rf in rfs),
         )
         return StreamingFeaturizedLeastSquares(
-            bank, d_feat=config.num_cosines * config.block_size,
+            cosine_bank_featurize(Wrf, brf),
+            d_feat=config.num_cosines * config.block_size,
             block_size=config.block_size, num_iter=config.num_epochs,
             lam=config.lam,
         )

@@ -406,3 +406,128 @@ class TestStreamingMesh:
         np.testing.assert_allclose(
             np.asarray(W_mesh), np.asarray(W_one), atol=2e-3, rtol=2e-3
         )
+
+
+# ---------------------------------------------------------------------------
+# The fold adds the upper block-triangle of each tile's FᵀF alone
+# ---------------------------------------------------------------------------
+
+
+def _cos_bank(d_feat, seed=0, dtype=jnp.float32):
+    rng = np.random.default_rng(seed)
+    Wr = jnp.asarray(rng.normal(size=(d_feat, D_IN)).astype(np.float32) * 0.3)
+    br = jnp.asarray(rng.uniform(0, 2 * np.pi, size=(d_feat,)).astype(np.float32))
+    return lambda X_t: jnp.cos(X_t @ Wr.T + br).astype(dtype)
+
+
+def _full_stats(X, Y, featurize, valid=None):
+    """The full product, float32 at ``highest``: what the fold's mirrored
+    triangle has to equal. Rows from ``valid`` on count for nothing."""
+    slab = featurize(X)
+    F = slab.astype(jnp.float32)
+    if valid is not None:
+        keep = (jnp.arange(X.shape[0]) < valid)[:, None]
+        F, Y = F * keep, Y * keep
+    hi = jax.lax.Precision.HIGHEST
+    Y_slab = Y.astype(slab.dtype).astype(jnp.float32)  # FᵀY runs at the slab's type
+    return (jnp.matmul(F.T, F, precision=hi), jnp.matmul(F.T, Y_slab, precision=hi),
+            jnp.sum(Y * Y), jnp.sum(F, axis=0), jnp.sum(Y, axis=0))
+
+
+def _fold_one_device(d_feat, n, tile, valid=None, traced=False, pre_tiled=False,
+                     moments=False, dtype=jnp.float32):
+    featurize = _cos_bank(d_feat, dtype=dtype)
+    X, Y = _problem(n, seed=d_feat + n)
+    want = _full_stats(X, Y, featurize, valid)
+    Xa, Ya = (X.reshape(-1, tile, D_IN), Y.reshape(-1, tile, K)) if pre_tiled else (X, Y)
+    if traced:
+        got = jax.jit(lambda X, Y, v: streaming.gram_stats(
+            X, Y, featurize, d_feat, tile, valid=v, moments=moments))(
+                Xa, Ya, jnp.asarray(valid, jnp.int32))
+    else:
+        got = jax.jit(lambda X, Y: streaming.gram_stats(
+            X, Y, featurize, d_feat, tile, valid=valid, moments=moments))(Xa, Ya)
+    return got, want[: len(got)]
+
+
+def _fold_mesh(d_feat=512, n=4 * 96, tile=32):
+    featurize = _cos_bank(d_feat)
+    X, Y = _problem(n, seed=17)
+    mesh = mesh_lib.make_mesh(devices=jax.devices()[:4])
+    got = jax.jit(lambda X, Y: streaming.gram_stats_mesh(
+        X, Y, featurize, d_feat, tile, mesh, n_true=n - 10, moments=True))(
+            mesh_lib.shard_rows(X, mesh), mesh_lib.shard_rows(Y, mesh))
+    return got, _full_stats(X, Y, featurize, n - 10)
+
+
+def _fold_segments(d_feat=512, tile=32, tiles_a_segment=3):
+    """Two segments, the second's tail masked: the carry crosses a dispatch,
+    and the weights are those of the one-program fit over the same rows."""
+    featurize = _cos_bank(d_feat)
+    seg = tile * tiles_a_segment
+    n = 2 * seg - 20
+    X, Y = _problem(2 * seg, seed=23)
+
+    def source(s):
+        rows = slice(s * seg, (s + 1) * seg)
+        return (X[rows].reshape(tiles_a_segment, tile, D_IN),
+                Y[rows].reshape(tiles_a_segment, tile, K), min(seg, n - s * seg))
+
+    kw = dict(d_feat=d_feat, tile_rows=tile, block_size=128, lam=LAM, num_iter=2)
+    W, fmean, ymean, _ = streaming.streaming_bcd_fit_segments(
+        source, num_segments=2, n_true=n, bank=featurize, **kw)
+    W1, f1, y1, _ = streaming.streaming_bcd_fit_centered(
+        X, Y, featurize=featurize, valid=n, **kw)
+    return (W, fmean, ymean), (W1, f1, y1)
+
+
+def _fold_flops(d_feat=16 * 256, tile=256):
+    """``cost_analysis()`` of the one-device fit program at sixteen panels
+    (a scan's body is counted once: one tile's fold), over the full
+    product's 2·tile·d²: the triangle is 136/256 of it, and the rest of the
+    fit a few hundredths more. A fold that slides back to the full ``dot``
+    reads over 1."""
+    from keystone_tpu.ops.learning.streaming_ls import CosineBankFeaturize
+
+    assert streaming.gram_panels(d_feat) == 16
+    shape = jax.ShapeDtypeStruct
+    compiled = streaming._streaming_fit_bank.lower(
+        shape((2 * tile, D_IN), jnp.float32), shape((2 * tile, K), jnp.float32),
+        (shape((d_feat, D_IN), jnp.float32), shape((d_feat,), jnp.float32)),
+        bank_type=CosineBankFeaturize, bank_key=("float32", False), d_feat=d_feat,
+        tile_rows=tile, block_size=1024, lam=shape((), jnp.float32), num_iter=1,
+        use_pallas=False, valid=None, labelize=None, center=True).compile()
+    share = compiled.cost_analysis()["flops"] / (2 * tile * d_feat * d_feat)
+    return (jnp.asarray(share < 0.6),), (jnp.asarray(True),)
+
+
+FOLD_CASES = {
+    # d_feat <= 256: one panel, the old full product
+    "one_panel": lambda: _fold_one_device(128, 192, 64),
+    "whole_panels": lambda: _fold_one_device(3 * 256, 192, 64),
+    "ragged_last_panel": lambda: _fold_one_device(2 * 256 + 128, 192, 64),
+    # past sixteen panels of 256 the panels widen: 512 here, the last one 256
+    "wider_panels": lambda: _fold_one_device(17 * 256, 96, 32),
+    "masked_boundary_tile_static": lambda: _fold_one_device(512, 256, 64, valid=150),
+    "masked_boundary_tile_traced": lambda: _fold_one_device(512, 256, 64, valid=150, traced=True),
+    "ragged_row_remainder": lambda: _fold_one_device(512, 3 * 64 + 37, 64),
+    "pre_tiled": lambda: _fold_one_device(512, 256, 64, pre_tiled=True),
+    "moments": lambda: _fold_one_device(512, 3 * 64 + 37, 64, moments=True),
+    "bfloat16_slab": lambda: _fold_one_device(512, 192, 64, dtype=jnp.bfloat16),
+    "mesh_of_four": _fold_mesh,
+    "two_segments": _fold_segments,
+    "fit_program_flops": _fold_flops,
+}
+
+
+@pytest.mark.parametrize("case", sorted(FOLD_CASES))
+def test_triangle_fold_equals_the_full_product_mirrored(case):
+    got, want = FOLD_CASES[case]()
+    assert len(got) == len(want)
+    for g, w in zip(got, want):
+        assert g.shape == w.shape and g.dtype == w.dtype
+        scale = float(jnp.abs(w).max()) or 1.0
+        np.testing.assert_allclose(np.asarray(g, np.float64), np.asarray(w, np.float64),
+                                   atol=2e-5 * scale, rtol=0)
+    if got[0].ndim == 2 and got[0].shape[0] == got[0].shape[1]:
+        assert jnp.array_equal(got[0], got[0].T)  # mirrored once, symmetric to the bit
