@@ -310,6 +310,12 @@ class Tracer:
         self._ids = itertools.count(1)
         self._open_spans: Dict[int, Dict[str, Any]] = {}
         self._tls = threading.local()
+        # A session that follows a jax profile (``utils.profiling.
+        # follow_profiler``): where that profile is written, and what
+        # ``obs.device.device_account`` made of it when it ended (None
+        # until then, and where it held no device plane — the CPU).
+        self.profile_dir: Optional[str] = None
+        self.device_account: Optional[Dict[str, Any]] = None
 
     # -- record plumbing ---------------------------------------------------
 
@@ -606,14 +612,17 @@ def start_session(annotate: Callable[..., Any]) -> Optional["Tracer"]:
     return t
 
 
-def end_session() -> None:
+def end_session() -> Optional["Tracer"]:
     """Deactivate the active tracer if :func:`start_session` started it
-    (a tracer of :func:`tracing` is never touched). It stays readable
-    through :func:`last_session`."""
+    (a tracer of :func:`tracing` is never touched) and return it; None
+    where there was nothing to end. It stays readable through
+    :func:`last_session`."""
     global _ACTIVE
     with _ACTIVE_LOCK:
-        if _ACTIVE is not None and _ACTIVE is _SESSION:
-            _ACTIVE = None
+        if _ACTIVE is None or _ACTIVE is not _SESSION:
+            return None
+        ended, _ACTIVE = _ACTIVE, None
+        return ended
 
 
 def last_session() -> Optional["Tracer"]:

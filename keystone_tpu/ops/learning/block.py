@@ -14,6 +14,7 @@ from __future__ import annotations
 
 from typing import List, Optional, Sequence
 
+import jax
 import jax.numpy as jnp
 
 from keystone_tpu import obs
@@ -130,6 +131,24 @@ class BlockLinearMapper(Transformer):
             acc = partial if acc is None else acc + partial
             preds = acc if self.b_opt is None else acc + self.b_opt
             evaluator(Dataset(preds, n=data.n, mesh=data.mesh)._rezero_padding())
+
+
+# ``jnp.stack`` dispatches a lifting copy a block and one concatenate, eagerly:
+# each a program of its own that takes no name scope from its caller. The
+# concatenate is that program as it was, under the scope ``ks.stack``; the
+# copies are the compiler's own (a lifted block is a bitcast that may not alias
+# its argument) and carry no metadata a scope could reach.
+
+
+@jax.jit
+def _joined(*lifted):
+    with jax.named_scope("ks.stack"):
+        return jnp.concatenate(lifted, axis=0)
+
+
+def _stack_blocks(A_blocks):
+    """``jnp.stack(A_blocks)``: the blocks as one (blocks, n, width) array."""
+    return _joined(*[jnp.expand_dims(a, 0) for a in A_blocks])
 
 
 def _stack_fits_memory(A_blocks, num_iter: int) -> bool:
@@ -250,7 +269,7 @@ class BlockLeastSquaresEstimator(LabelEstimator):
             # cleanly and match the unsharded reduction order); so do fits
             # whose stacked copy would not fit beside the blocks in HBM.
             with obs.span("solver.stack"):
-                stacked = jnp.stack(A_blocks)
+                stacked = _stack_blocks(A_blocks)
                 del A_blocks  # the stack is a full second copy; drop the list
             with obs.span("solver.bcd", epochs=self.num_iter):
                 W_stack = linalg.bcd_least_squares_fused(

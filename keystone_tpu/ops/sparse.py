@@ -283,11 +283,13 @@ def sparse_gram_init(d: int, k: int, val_dtype=jnp.float32):
     )
 
 
+@jax.named_scope("ks.sparse_gram_acc")  # the fold's one mirror
 def gram_finalize(G):
     """Mirror the accumulated upper triangle into a full symmetric G."""
     return jnp.triu(G) + jnp.triu(G, 1).T
 
 
+@jax.named_scope("ks.sparse_gram_acc")  # the chunk loop's own copies of its carry too
 def sparse_gram_fold(
     carry,
     cids,

@@ -26,6 +26,24 @@ from keystone_tpu.workflow import Estimator, Transformer
 # ---------------------------------------------------------------------------
 
 
+# An eagerly dispatched operation is a program of its own and takes no name
+# scope from its caller, so a device profile cannot say what phase it served.
+# The two below are the scaler's eager operations as they were — one program
+# each — under the scope ``ks.center``.
+
+
+@jax.jit
+def _column_sums(X):
+    with jax.named_scope("ks.center"):
+        return jnp.sum(X, axis=0)
+
+
+@jax.jit
+def _subtract_mean(X, mean):
+    with jax.named_scope("ks.center"):
+        return X - mean
+
+
 class StandardScalerModel(Transformer):
     """Subtract column means (and optionally divide by stds)
     (reference: nodes/stats/StandardScaler.scala:16-32)."""
@@ -35,7 +53,9 @@ class StandardScalerModel(Transformer):
         self.std = None if std is None else jnp.asarray(std)
 
     def apply(self, x):
-        out = jnp.asarray(x) - self.mean
+        # Traced inside another program (a fused chain) the subtraction is
+        # inlined there and reads as ``ks.center`` within that phase.
+        out = _subtract_mean(jnp.asarray(x), self.mean)
         if self.std is not None:
             out = out / self.std
         return out
@@ -57,7 +77,7 @@ class StandardScaler(Estimator):
         X = jnp.asarray(data.array)
         n = data.n
         # Padding rows are zero: sums are exact; divide by the true count.
-        total = jnp.sum(X, axis=0)
+        total = _column_sums(X)
         mean = total / n
         if not self.normalize_std_dev:
             return StandardScalerModel(mean)

@@ -7,6 +7,7 @@ sparse-feature nodes live in :mod:`keystone_tpu.ops.nlp_sparse`.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass
 from typing import List, Optional
 
@@ -261,6 +262,15 @@ class Shuffler(Transformer):
         return out.shard(data.mesh) if data.mesh is not None else out
 
 
+@functools.partial(jax.jit, static_argnames=("width",))
+def _column_block(arr, start, width: int):
+    """``arr[:, start:start + width]`` of a device array as the eager slice
+    was — one program for every ``start`` — under a name scope, which an
+    eagerly dispatched operation does not take from its caller."""
+    with jax.named_scope("ks.split"):
+        return jax.lax.dynamic_slice_in_dim(arr, start, width, axis=1)
+
+
 class VectorSplitter(FunctionNode):
     """Split a (n, d) dataset into feature-axis blocks — the model-parallel
     partitioner (reference: nodes/util/VectorSplitter.scala:10-36).
@@ -280,7 +290,10 @@ class VectorSplitter(FunctionNode):
         blocks = []
         for start in range(0, d, self.block_size):
             stop = min(start + self.block_size, d)
-            blocks.append(Dataset(arr[:, start:stop], n=data.n, mesh=data.mesh))
+            # a host dataset's blocks stay on the host
+            block = (_column_block(arr, start, stop - start)
+                     if isinstance(arr, jax.Array) else arr[:, start:stop])
+            blocks.append(Dataset(block, n=data.n, mesh=data.mesh))
         return blocks
 
     def split_vector(self, vec):

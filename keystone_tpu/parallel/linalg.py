@@ -339,6 +339,7 @@ def bcd_least_squares(
     jax.jit,
     static_argnames=("num_iter", "use_pallas", "sym", "cache_stash"),
 )
+@jax.named_scope("ks.bcd_step")  # the sweeps' own slices of the stacked blocks too
 def _bcd_fused_kernel(A_stack, B, W0, lam, num_iter: int,
                       use_pallas: bool, sym: bool, cache_stash: bool = True):
     def first_epoch_step(R, xs):

@@ -194,6 +194,7 @@ def _tile_update(G, FY, yty, fsum, ysum, X_t, Y_t, featurize, use_pallas,
     return G, FY, yty + jnp.sum(Yf * Yf), fsum, ysum
 
 
+@jax.named_scope("ks.gram_fold")  # the loop's own copies and the mirror too
 def gram_stats(
     X: Array,
     Y: Array,
@@ -798,13 +799,18 @@ def _solve_from_stats_core(G, FY, yty, fsum, ysum, n_true, lam,
     ``G`` must have BOTH triangles valid. Returns
     (W, loss, fmean, ymean) — fmean/ymean None when not centering."""
     fmean = ymean = None
+    # The fold ends with the sums; what is made of them is the solve's: the
+    # rank-1 centring before it and the fitted loss (one more product on G)
+    # after it read as ``ks.bcd`` in a device profile, with the sweeps.
     if center:
-        G, FY, yty, fmean, ymean = center_gram_stats(
-            G, FY, yty, fsum, ysum, n_true
-        )
+        with jax.named_scope("ks.bcd"):
+            G, FY, yty, fmean, ymean = center_gram_stats(
+                G, FY, yty, fsum, ysum, n_true
+            )
     W = bcd_from_gram(G, FY, block_size, lam, num_iter)
-    Wf = W.reshape(G.shape[0], W.shape[2])
-    loss = (yty - 2.0 * jnp.vdot(Wf, FY) + jnp.vdot(Wf, G @ Wf)) / n_true
+    with jax.named_scope("ks.bcd"):
+        Wf = W.reshape(G.shape[0], W.shape[2])
+        loss = (yty - 2.0 * jnp.vdot(Wf, FY) + jnp.vdot(Wf, G @ Wf)) / n_true
     return W, loss, fmean, ymean
 
 
@@ -1069,6 +1075,7 @@ def _block_sweep(x_local, Wrf, brf, lam_t, valid, *, axis, block_size,
             out = jnp.concatenate([out, one(x_r, R_r, v_r)], axis=0)
         return out
 
+    @jax.named_scope("ks.block_update")
     def solve_and_update(b, bank, F, R, Wst, local, rsum, gram, chol, mu):
         """One block solve + residual update from the step's local sums.
         ``gram``/``chol`` are the (centered, when ``center``) block system
@@ -1097,6 +1104,13 @@ def _block_sweep(x_local, Wrf, brf, lam_t, valid, *, axis, block_size,
         R = local_update(bank, F, R, dw, const)
         return R, jax.lax.dynamic_update_index_in_dim(Wst, w_new, b, 0)
 
+    # A device profile names the innermost scope. ``ks.block_gram`` is the
+    # tiles' panels and column sums alone (``tile_sums``); what epoch 1 makes
+    # of them — psum, centring, mirror, Cholesky factor, the stash's writes —
+    # is ``ks.block_factor``, so a change to the factor does not move the
+    # Gramian's time. The stash's and the bank's slices of a later step read
+    # with the update they serve.
+    @jax.named_scope("ks.block_factor")
     def first_step(carry, b):
         R, Wst, G, C, M = carry
         bank = bank_slice(b)
@@ -1121,6 +1135,7 @@ def _block_sweep(x_local, Wrf, brf, lam_t, valid, *, axis, block_size,
         C = jax.lax.dynamic_update_index_in_dim(C, chol, b, 0)
         return (R, Wst, G, C, M), None
 
+    @jax.named_scope("ks.block_update")
     def later_step(carry, b):
         R, Wst, G, C, M = carry
         bank = bank_slice(b)

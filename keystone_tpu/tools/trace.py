@@ -31,6 +31,12 @@ order, under which weights" (docs/placement.md).
 JSONL rows (e.g. after post-processing, or when only the event log was
 shipped off-box). Exits non-zero on an unreadable/invalid trace dir.
 
+``--device <profile_dir>`` reads a jax PROFILE instead (the directory
+handed to ``jax.profiler.start_trace`` / ``jax.profiler.trace``, or an
+``.xplane.pb``): the device's time by ``ks.*`` phase and program, the
+unscoped remainder, and every idle gap split by the program span open
+over it (``obs/device.py``; docs/observability.md, "The device account").
+
 An offline reader: it does no device work, and ``bin/trace`` pins
 ``JAX_PLATFORMS=cpu`` (unless set) so inspecting a trace never takes the
 chip from the run that is writing it.
@@ -272,12 +278,30 @@ def _render_decisions(records: List[Dict[str, Any]]) -> str:
     return "\n".join(lines)
 
 
+def _device(profile: str, top: int) -> int:
+    """``--device``: the account of a kept jax profile (what the session
+    that followed it took, less the spans recorded after the fact — the
+    ``jax.compile`` spans are in no profile: their part of a gap reads
+    under the span that caused them)."""
+    from keystone_tpu.obs import device
+
+    try:
+        found = device.device_account(profile)
+    except (OSError, ValueError, IndexError) as e:  # absent, or not an .xplane.pb
+        print(f"trace: cannot read a profile at {profile!r}: "
+              f"{type(e).__name__}: {e}", file=sys.stderr)
+        return 1
+    print(device.render(found, top))
+    return 0
+
+
 def main(argv: Optional[Sequence[str]] = None) -> int:
     parser = argparse.ArgumentParser(
         "keystone-trace", description=__doc__,
         formatter_class=argparse.RawDescriptionHelpFormatter,
     )
-    parser.add_argument("trace_dir", help="directory a traced run wrote")
+    parser.add_argument("trace_dir", help="directory a traced run wrote (with "
+                        "--device: a jax profile's directory or .xplane.pb)")
     parser.add_argument("--top", type=int, default=12,
                         help="span names in the self-time table")
     parser.add_argument("--perfetto", default="",
@@ -286,7 +310,13 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                         help="print the merged chronological decision "
                              "log (all *.decision streams) instead of "
                              "the span summary")
+    parser.add_argument("--device", action="store_true",
+                        help="trace_dir is a jax profile: print the device's "
+                             "time by ks.* phase and its idle gaps by program "
+                             "span (obs.device.device_account)")
     args = parser.parse_args(list(argv) if argv is not None else None)
+    if args.device:
+        return _device(args.trace_dir, args.top)
     try:
         records = load_events(args.trace_dir)
     except OSError as e:

@@ -390,20 +390,64 @@ def trace(log_dir: str):
 
 def follow_profiler() -> None:
     """Make the program's tracing follow a jax profile; called at the
-    public fit entries (``Pipeline.fit``, ``pipelines/timit.py``).
+    public fit entries (``Pipeline.fit``, ``pipelines/timit.py``) and at
+    the eager scoring entry (``FittedPipeline.apply``).
 
     A profile is being taken and no tracer is active: activate an
     in-memory one whose spans are also ``ks.<name>`` annotations in the
     profile (under whatever annotation the caller holds open, on the
-    clock of the device lines). No profile and the active tracer is one
-    this function started: deactivate it; ``obs.last_session()`` keeps
+    clock of the device lines), and note where the profile is written.
+    No profile and the active tracer is one this function started:
+    deactivate it and take the device's account of the profile it
+    followed (``Tracer.device_account``); ``obs.last_session()`` keeps
     it readable. A tracer of ``obs.tracing`` / ``KEYSTONE_TRACE`` is
     never touched. With no profile and no tracer this is two reads."""
     if TraceAnnotation.is_enabled():
         if not _tracer.enabled():
-            _tracer.start_session(TraceAnnotation)
+            session = _tracer.start_session(TraceAnnotation)
+            if session is not None:
+                session.profile_dir = _profile_dir()
     elif _tracer.enabled():
-        _tracer.end_session()
+        session = _tracer.end_session()
+        if session is not None:
+            _take_device_account(session)
+
+
+def _profile_dir() -> Optional[str]:
+    """Where the running jax profile is written. JAX keeps it in a
+    private place; where that has moved there is no account, never an
+    error."""
+    try:
+        from jax._src import profiler as _jax_profiler
+
+        return _jax_profiler._profile_state.log_dir
+    except Exception as e:  # pragma: no cover - depends on the jax version
+        logger.info("the running profile's directory cannot be read (%s): "
+                    "no device account will be taken", e)
+        return None
+
+
+def _take_device_account(session) -> None:
+    """The account of the profile ``session`` followed, once, when the
+    session ends (``obs.device`` is imported here and nowhere earlier).
+
+    Taken here and not on the first read of ``Tracer.device_account``: the
+    profile is the caller's to remove once it has stopped (the benchmark's
+    harness removes it before any reader runs), and the newest
+    ``.xplane.pb`` under the directory is this session's only now. The fit
+    or the apply that finds the profile over pays the read — about 10 us
+    an operation event of the profile, seconds for a long one."""
+    if session.profile_dir is None:
+        logger.info("the ended session knows no profile directory: no device account")
+        return
+    try:
+        from keystone_tpu.obs import device
+
+        session.device_account = device.device_account(
+            session.profile_dir, session.spans())
+    except Exception:  # a reader's fault must not reach the fit or the apply
+        logger.warning("no device account of the profile under %s",
+                       session.profile_dir, exc_info=True)
 
 
 class CompileClock:

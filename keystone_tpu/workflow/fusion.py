@@ -290,7 +290,9 @@ class _FusedTransformer(Transformer):
 
         def build():
             def composed(params, X):
-                return apply(static_key, params, X)
+                # names the phase in a device profile (trace-time only)
+                with jax.named_scope("ks.featurize"):
+                    return apply(static_key, params, X)
 
             return jax.jit(composed)
 

@@ -13,14 +13,18 @@ yields a serializable transformer-only pipeline.
 from __future__ import annotations
 
 import functools
+import types
 
 import cloudpickle as pickle
 from typing import Any, Callable, Dict, Generic, List, Optional, Sequence, Tuple, TypeVar, Union
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
+from keystone_tpu import obs
 from keystone_tpu.data import Dataset
+from keystone_tpu.utils.profiling import follow_profiler
 
 from .executor import GraphExecutor
 from .graph import Graph, GraphId, NodeId, SinkId, SourceId
@@ -183,9 +187,6 @@ class Pipeline(Chainable[A, B]):
         API would have raised — fails HERE with node-level coordinates,
         not deep inside an estimator fit. ``KEYSTONE_VERIFY=off``
         disables the pre-pass."""
-        from keystone_tpu import obs
-        from keystone_tpu.utils.profiling import follow_profiler
-
         from .env import PipelineEnv
         from .rules import UnusedBranchRemovalRule
         from .verify import verify_fit_graph
@@ -407,6 +408,16 @@ class FittedPipeline(Generic[A, B]):
             return program
 
     def apply(self, data: Any) -> Any:
+        """Score ``data`` eagerly. The entry also lets the program's
+        tracing follow a jax profile (two reads when nothing is on): a
+        profiled apply is a root ``pipeline.apply`` span, and the first
+        apply after a profile has stopped ends the session that followed
+        it, which then takes its device account."""
+        follow_profiler()
+        with obs.span("pipeline.apply"):
+            return self._apply(data)
+
+    def _apply(self, data: Any) -> Any:
         from . import analysis
 
         is_dataset = isinstance(data, (Dataset, PipelineDataset))
@@ -661,20 +672,62 @@ class Identity(Transformer[A, A]):
 # ---------------------------------------------------------------------------
 
 
+def _held_arrays(fitted) -> list:
+    """Every device array a fitted transformer reaches: through containers
+    and registered pytrees (``jax.tree_util``), through attributes, and
+    through what a closure, a partial or a bound method has captured — a
+    class made inside a fit (``cost.py``'s ``Chained``) holds its model in
+    its methods' closure cells. Modules and ordinary classes are not
+    entered."""
+    found: list = []
+    seen: set = set()
+
+    def walk(obj, depth: int) -> None:
+        if id(obj) in seen or depth > 10:
+            return
+        seen.add(id(obj))
+        if isinstance(obj, jax.Array):
+            found.append(obj)
+            return
+        if obj is None or isinstance(obj, (str, bytes, int, float, np.ndarray,
+                                           types.ModuleType)):
+            return
+        leaves = jax.tree_util.tree_leaves(obj)
+        if len(leaves) != 1 or leaves[0] is not obj:  # a container: its leaves
+            held = leaves
+        elif isinstance(obj, type):
+            held = list(vars(obj).values()) if "<locals>" in obj.__qualname__ else []
+        else:
+            held = list(getattr(obj, "__dict__", {}).values())
+            if "<locals>" in type(obj).__qualname__:
+                held.append(type(obj))
+            for cell in getattr(obj, "__closure__", None) or ():
+                try:
+                    held.append(cell.cell_contents)
+                except ValueError:  # an empty cell
+                    pass
+            if isinstance(obj, functools.partial):
+                held += [obj.func, obj.args, obj.keywords]
+            elif isinstance(obj, types.MethodType):
+                held.append(obj.__self__)
+        for child in held:
+            walk(child, depth + 1)
+
+    walk(fitted, 0)
+    return found
+
+
 def _sync_fitted(fitted) -> None:
     """Execution barrier for the measured-outcome stamp: jax dispatch is
     async, so a fit-call wall can close before the device work it priced
-    has run. Blocks on every device array in the fitted transformer's
-    state; a device error surfaces here, inside the fit that caused it.
-    Results whose arrays hide in closures (chained transformers) are not
-    reached — the calibrator's span-window join still sees their fold
-    spans. Only a traced fit holds this barrier; the wait is its own
+    has run. Blocks on every device array the fitted transformer reaches
+    (:func:`_held_arrays`: a chained transformer's arrays hide in
+    closures); a device error surfaces here, inside the fit that caused
+    it. Only a traced fit holds this barrier; the wait is its own
     ``executor.drain`` span (the ``executor.node`` above it names the
     node)."""
-    from keystone_tpu import obs
-
     with obs.span("executor.drain", site="estimator_sync"):
-        jax.block_until_ready(getattr(fitted, "__dict__", None))
+        jax.block_until_ready(_held_arrays(fitted))
 
 
 def _stamped_fit(est, thunk):
@@ -690,8 +743,6 @@ def _stamped_fit(est, thunk):
     bogus measurement and a re-fit never double-stamps. Estimators with
     no pending decision get the span alone — no barrier, no stamp."""
     import time as _time
-
-    from keystone_tpu import obs
 
     ref = getattr(est, "_pending_cost_outcome", None)
     if ref is not None:

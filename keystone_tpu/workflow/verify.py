@@ -427,13 +427,38 @@ def _sig_from_result(res, in_sig: ArraySig) -> Sig:
                     mesh=in_sig.mesh)
 
 
-def _eval_device_fn(fn, sig: ArraySig):
-    """jax.eval_shape the operator's batched function on the incoming
-    signature. Returns (result_struct, None) or (None, error_message)."""
+def _eval_shape(fn, spec):
+    """``jax.eval_shape(fn, spec)``, with a trace that fails let go before the
+    failure is raised on.
+
+    jax (0.9.0) invalidates a trace only where it ends well:
+    ``core.ensure_no_leaks`` has no ``finally``. A trace that ends by an
+    exception keeps its equations, and ``weakref.finalize``'s registry keeps
+    the trace until the tracers of its constants die — tracers those
+    equations hold. Every device array the operator closed over (a random
+    feature bank, 7 MB a branch) then lives as long as the process does, and
+    its entry of ``ops.stats._DRAWN_BANKS`` with it. The traces are found
+    through the tracers in the frames of the failure's traceback: the
+    operator's own frames, which jax's traceback filtering keeps."""
     import jax
 
     try:
-        res = jax.eval_shape(fn, _spec_for(sig))
+        return jax.eval_shape(fn, spec)
+    except Exception as e:
+        tb = e.__traceback__
+        while tb is not None:
+            for held in tb.tb_frame.f_locals.values():
+                if isinstance(held, jax.core.Tracer):
+                    held._trace.invalidate()
+            tb = tb.tb_next
+        raise
+
+
+def _eval_device_fn(fn, sig: ArraySig):
+    """jax.eval_shape the operator's batched function on the incoming
+    signature. Returns (result_struct, None) or (None, error_message)."""
+    try:
+        res = _eval_shape(fn, _spec_for(sig))
     except Exception as e:  # noqa: BLE001 — any trace failure is the finding
         msg = str(e).strip().split("\n")[0]
         return None, (msg[:300] or type(e).__name__)
@@ -617,8 +642,6 @@ def _infer_and_check(
             return UNKNOWN  # branches not fully known: nothing to check
         fn = combine_get()
         if fn is not None:
-            import jax
-
             tup = in_sigs[0]
             branch_dtypes = {e.dtype for e in tup.elements}
             if (
@@ -637,7 +660,7 @@ def _infer_and_check(
                 )
             specs = [_spec_for(e) for e in tup.elements]
             try:
-                res = jax.eval_shape(fn, specs)
+                res = _eval_shape(fn, specs)
             except Exception as e:  # noqa: BLE001
                 report.add(
                     SHAPE_MISMATCH, node, op,

@@ -96,6 +96,42 @@ class TestSeededViolations:
         assert f.node in applied.executor.graph.nodes
         assert f.severity == "error"
 
+    def test_a_rejected_plan_lets_its_operators_arrays_go(self):
+        """A trace that fails is let go with its constants: jax alone keeps
+        it, and with it the operator's bank, for the life of the process (a
+        later test file of the same process then finds ``_DRAWN_BANKS`` held)."""
+        import gc
+        import weakref
+
+        rf = CosineRandomFeatures(8, 16, 1.0, seed=0)
+        bank = weakref.ref(rf.W)
+        applied = rf.to_pipeline().apply(PipelineDataset.of(_data(d=5)))
+        assert verify_graph(applied.executor.graph).by_code(SHAPE_MISMATCH)
+        del rf, applied
+        gc.collect()
+        assert bank() is None
+
+    @pytest.mark.parametrize("inner_jit", [False, True])
+    def test_a_failed_abstract_evaluation_keeps_no_constant(self, inner_jit):
+        import gc
+        import weakref
+
+        import jax
+        from keystone_tpu.workflow.verify import _eval_shape
+
+        W = jnp.ones((16, 8))
+        held = weakref.ref(W)
+
+        def fn(X):
+            return X @ W.T
+
+        with pytest.raises(TypeError):
+            _eval_shape(jax.jit(fn) if inner_jit else fn,
+                        jax.ShapeDtypeStruct((4, 5), jnp.float32))
+        del W, fn
+        gc.collect()
+        assert held() is None
+
     def test_dtype_drift_is_reported(self):
         chain = RandomSignNode.create(5).and_then(_CastsToBf16()).and_then(
             LinearRectifier()
