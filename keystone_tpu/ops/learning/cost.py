@@ -755,7 +755,7 @@ class LeastSquaresEstimator(OptimizableLabelEstimator):
         # The compressed-resident storage class (data/resident.py,
         # ISSUE 8): the SAME gram iterates over int16+bf16 operands at
         # 4 B/nnz — half the raw COO's residency, feasible only while
-        # every index (intercept lane included) fits int16. Priced as a
+        # every index fits int16. Priced as a
         # third tier between HBM-raw and disk: identical cost model
         # (the fold runs the same bf16 slabs), so selection is driven
         # by the capacity cut — raw-infeasible, compressed-feasible

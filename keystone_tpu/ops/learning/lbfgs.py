@@ -246,27 +246,64 @@ def _lbfgs_core(X, Y, W0, lam, num_iterations, tol, n):
 
 
 @jax.jit
-def _lbfgs_gram_core(G, AtY, yty, W0, lam, num_iterations, tol, n):
+def _lbfgs_gram_core(G, AtY, yty, W0, lam, num_iterations, tol, n,
+                     border=None):
     """L-BFGS on the accumulated normal equations: hvp = G·/n + λ· — the
     same operator as the data-pass core (G = AᵀA), so the iterates match
     the gather path while each iteration costs one (d, d)×(d, k) GEMM
     instead of a full data pass. Used by the streamed sparse tier, where
-    G is folded once over (regenerated or resident) chunks."""
+    G is folded once over (regenerated or resident) chunks.
+
+    ``border=(s, ysum)`` — the column sums Xᵀ1 (d,) and 1ᵀY (k,) of a
+    bordered fold (``sparse.sparse_gram_fold``) — solves for the model
+    with an intercept, ``[X, 1]``, without the (d + 1)-wide Gramian ever
+    being assembled: ``W0`` carries the intercept as its LAST row, the
+    data part of the Hessian apply is ``[G·P_d + s·p₀ ; sᵀP_d + n·p₀]``
+    and the constant term ``[XᵀY ; 1ᵀY]`` — block for block what the
+    appended ones column puts in G's last row and column. The intercept
+    takes λ with the rest (LBFGS.scala:208-281)."""
+    HIGHEST = jax.lax.Precision.HIGHEST
+
+    if border is None:
+        def gram_apply(P):
+            return jnp.dot(G, P, precision=HIGHEST)
+    else:
+        s, ysum = border
+        AtY = jnp.concatenate([AtY, ysum[None]])
+
+        def gram_apply(P):
+            Pd, p0 = P[:-1], P[-1:]
+            return jnp.concatenate([
+                jnp.dot(G, Pd, precision=HIGHEST) + s[:, None] * p0,
+                jnp.dot(s[None], Pd, precision=HIGHEST) + n * p0,
+            ])
 
     def hvp(P):
-        return (
-            jnp.dot(G, P, precision=jax.lax.Precision.HIGHEST) / n + lam * P
-        )
+        return gram_apply(P) / n + lam * P
 
     with jax.named_scope("ks.lbfgs_gram"):  # names the phase in a device profile
         W, count = _lbfgs_quad_loop(hvp, AtY / n, W0, lam, num_iterations, tol)
         # ½‖AW−Y‖²/n + ½λ‖W‖² expanded through G/AtY/yty (no data pass).
         data_loss = 0.5 * (
-            jnp.sum(W * jnp.dot(G, W, precision=jax.lax.Precision.HIGHEST))
-            - 2.0 * jnp.sum(W * AtY)
-            + yty
+            jnp.sum(W * gram_apply(W)) - 2.0 * jnp.sum(W * AtY) + yty
         ) / n
         return W, data_loss + 0.5 * lam * jnp.sum(W * W), count
+
+
+def _solve_gram_carry(carry, d: int, k: int, border: bool, hyper):
+    """``(W, loss, iterations)`` from a FINALIZED fold carry. Solved at the
+    padded width: padded rows of AtY are zero and G's padded rows/cols are
+    zero, so those W rows stay exactly zero through every iterate (pure-λ
+    ridge on a zero gradient). With ``border`` W is (d + 1, k), the
+    intercept its last row — the layout an appended ones column gives."""
+    G, AtY, yty, *ysum = carry
+    d_pad = G.shape[0]
+    W, loss, count = _lbfgs_gram_core(
+        G, AtY[:, :k], yty,
+        jnp.zeros((d_pad + int(border), k), jnp.float32), *hyper,
+        border=(AtY[:, k], ysum[0]) if border else None,
+    )
+    return jnp.concatenate([W[:d], W[d_pad:]]), loss, count
 
 
 class DenseLBFGSwithL2(LabelEstimator):
@@ -420,6 +457,7 @@ def run_lbfgs_gram_streamed(
     mesh=None,
     mesh_axis: Optional[str] = None,
     info: Optional[dict] = None,
+    border: bool = False,
 ):
     """Streamed sparse ridge fit: fold G = AᵀA over COO chunks ONCE
     (``sparse.sparse_gram_stream`` — chunks may be regenerated/loaded per
@@ -432,6 +470,13 @@ def run_lbfgs_gram_streamed(
     ``lam``, ``num_iterations``, ``convergence_tol`` and ``n`` ride into
     the compiled programs as OPERANDS: a ridge sweep reuses one program
     (as constants every new ``lam`` compiled the whole chunk scan anew).
+
+    ``d`` and ``k`` are the chunk's own columns and targets. ``border``:
+    ``chunk_fn`` hands ``[Y, live]`` (k + 1 target columns, the last the
+    0/1 mask of the rows the chunk folds) and the fit learns an intercept
+    from the fold's border (``sparse.sparse_gram_fold``): W comes back
+    (d + 1, k), the intercept its last row, regularised with the rest.
+    A static key of the fold and solve programs, on every tier below.
 
     ``operands``: arrays ``chunk_fn`` slices from, passed as
     ``chunk_fn(cid, *operands)``. Resident buffers MUST ride here — a
@@ -529,7 +574,7 @@ def run_lbfgs_gram_streamed(
         return _solved(info, _run_lbfgs_gram_streamed_mesh(
             chunk_fn, int(num_chunks), int(d), int(k), mesh,
             mesh_axis=mesh_axis, hyper=hyper, use_pallas=use_pallas,
-            val_dtype=val_dtype, operands=operands,
+            val_dtype=val_dtype, border=bool(border), operands=operands,
             max_chunks_per_dispatch=max_chunks_per_dispatch,
             segment_sources=segment_source, inflight=inflight,
             prefetch_depth=prefetch_depth, prefetch_stats=prefetch_stats,
@@ -570,6 +615,7 @@ def run_lbfgs_gram_streamed(
         program = _gram_streamed_program(
             chunk_fn, int(num_chunks), int(d), int(k),
             bool(use_pallas), jnp.dtype(val_dtype), bool(pipeline),
+            bool(border),
         )
         # One dispatch holds the fold AND the solve: both spans' work.
         with obs.span("solver.gram_fold", chunks=int(num_chunks),
@@ -585,13 +631,15 @@ def run_lbfgs_gram_streamed(
         fold = _gram_fold_program_rel(
             chunk_fn, int(num_chunks), int(d), int(k), int(seg),
             bool(use_pallas), jnp.dtype(val_dtype), bool(pipeline),
+            bool(border),
         )
     else:
         fold = _gram_fold_program(
             chunk_fn, int(num_chunks), int(d), int(k), int(seg),
             bool(use_pallas), jnp.dtype(val_dtype), bool(pipeline),
+            bool(border),
         )
-    solve = _gram_solve_program(int(d), int(k), jnp.dtype(val_dtype))
+    solve = _gram_solve_program(int(d), int(k), bool(border))
     num_segs = -(-int(num_chunks) // int(seg))
     carry = None
     start_seg = 0
@@ -619,11 +667,30 @@ def run_lbfgs_gram_streamed(
                 source if source is not None else segment_source
             ),
         }
+        if border:
+            fingerprint["border"] = True
         arrays, start_seg = checkpoint.restore(fingerprint)
         if arrays is not None:
+            # A snapshot is a carry of THIS fold or it is refused: a fit
+            # that lanes its intercept holds the ones column inside a G
+            # one column wider, a bordered fit holds it beside G — the
+            # one is never read as the other.
+            want = [
+                tuple(a.shape) for a in jax.eval_shape(
+                    lambda: sparse_gram_init(d, k, val_dtype, border))
+            ]
+            got = [tuple(np.shape(a)) for a in arrays]
+            if got != want:
+                raise ValueError(
+                    f"checkpoint under {checkpoint.directory!r} holds a "
+                    f"carry of shapes {got}, this fold's is {want} (an "
+                    f"intercept laned as a slab column and one kept as "
+                    f"the fold's border do not resume each other): "
+                    f"discard the checkpoint and restart the fit"
+                )
             carry = tuple(jnp.asarray(a) for a in arrays)
     if carry is None:
-        carry = sparse_gram_init(d, k, val_dtype)
+        carry = sparse_gram_init(d, k, val_dtype, border)
     throttle = BoundedInflight(inflight)
     step = _fold_stepper(throttle, prefetch_stats)
 
@@ -774,7 +841,7 @@ def run_lbfgs_gram_hybrid(
                               seg):
                 folded(fold_tail, cid0, ())
 
-    solve = _gram_solve_program(int(d), int(k), jnp.dtype(val_dtype))
+    solve = _gram_solve_program(int(d), int(k))
     return _solved(None, solve(
         carry, _solve_operands(lam, num_iterations, convergence_tol, n)
     ))
@@ -782,7 +849,7 @@ def run_lbfgs_gram_hybrid(
 
 @functools.lru_cache(maxsize=16)
 def _gram_fold_program(chunk_fn, num_chunks, d, k, seg, use_pallas,
-                       val_dtype, pipeline=True):
+                       val_dtype, pipeline=True, border=False):
     """Compiled fold of ``seg`` consecutive chunks into the (G, AtY, yty)
     carry; the starting chunk id is a traced operand so every segment —
     including the phantom-padded final one — reuses this one executable.
@@ -803,6 +870,7 @@ def _gram_fold_program(chunk_fn, num_chunks, d, k, seg, use_pallas,
         return sparse_gram_fold(
             carry, cid0 + jnp.arange(seg), cf, d, k,
             use_pallas=use_pallas, val_dtype=val_dtype, pipeline=pipeline,
+            border=border,
         )
 
     return fold
@@ -810,7 +878,7 @@ def _gram_fold_program(chunk_fn, num_chunks, d, k, seg, use_pallas,
 
 @functools.lru_cache(maxsize=16)
 def _gram_fold_program_rel(chunk_fn, num_chunks, d, k, seg, use_pallas,
-                           val_dtype, pipeline=True):
+                           val_dtype, pipeline=True, border=False):
     """Segment fold over SEGMENT-RELATIVE chunk ids: operands hold only
     this segment's ``seg`` chunks (a disk-backed loader's slice), so
     ``chunk_fn`` slices by rel id while liveness masks by the absolute
@@ -831,6 +899,7 @@ def _gram_fold_program_rel(chunk_fn, num_chunks, d, k, seg, use_pallas,
         return sparse_gram_fold(
             carry, jnp.arange(seg), cf, d, k,
             use_pallas=use_pallas, val_dtype=val_dtype, pipeline=pipeline,
+            border=border,
         )
 
     return fold
@@ -857,21 +926,16 @@ def _solved(info: Optional[dict], result):
 
 
 @functools.lru_cache(maxsize=16)
-def _gram_solve_program(d, k, val_dtype):
+def _gram_solve_program(d, k, border=False):
     """Compiled finalize + L-BFGS-on-G tail of the segmented fold:
     ``solve(carry, hyper)`` with ``hyper`` of :func:`_solve_operands`."""
-    from keystone_tpu.ops.sparse import gram_finalize, gram_pad_dim
-
-    d_pad = gram_pad_dim(d, val_dtype)
+    from keystone_tpu.ops.sparse import gram_finalize
 
     @jax.jit
     def solve(carry, hyper):
-        G, AtY, yty = carry
-        W, loss, count = _lbfgs_gram_core(
-            gram_finalize(G), AtY, yty,
-            jnp.zeros((d_pad, k), jnp.float32), *hyper,
-        )
-        return W[:d], loss, count
+        G, *rest = carry
+        return _solve_gram_carry(
+            (gram_finalize(G), *rest), d, k, border, hyper)
 
     return solve
 
@@ -889,27 +953,26 @@ def _mesh_fold_axis(mesh, mesh_axis: Optional[str]) -> str:
     return axis
 
 
-def _mesh_gram_init(d, k, val_dtype, mesh, axis):
+def _mesh_gram_init(d, k, val_dtype, mesh, axis, border=False):
     """Per-device zero carries: stacked (m, ...) arrays sharded over
     ``axis`` so device j's partial lives only in device j's HBM."""
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    from keystone_tpu.ops.sparse import gram_pad_dim
+    from keystone_tpu.ops.sparse import sparse_gram_init
 
     m = int(mesh.shape[axis])
-    d_pad = gram_pad_dim(d, val_dtype)
     sharding = NamedSharding(mesh, P(axis))
-
-    def put(*shape):
-        return jax.device_put(np.zeros(shape, np.float32), sharding)
-
-    return (put(m, d_pad, d_pad), put(m, d_pad, k), put(m))
+    pieces = jax.eval_shape(lambda: sparse_gram_init(d, k, val_dtype, border))
+    return tuple(
+        jax.device_put(np.zeros((m, *p.shape), np.float32), sharding)
+        for p in pieces
+    )
 
 
 @functools.lru_cache(maxsize=8)
 def _gram_fold_program_mesh(chunk_fn, num_chunks, d, k, seg, use_pallas,
                             val_dtype, pipeline, mesh, axis,
-                            segment_relative):
+                            segment_relative, border=False):
     """Mesh-sharded segment fold: each device folds ``seg`` chunks of ITS
     contiguous chunk shard into ITS local (G, AtY, yty) partial. NO
     collective runs here — the single per-fit psum lives in
@@ -951,57 +1014,59 @@ def _gram_fold_program_mesh(chunk_fn, num_chunks, d, k, seg, use_pallas,
                 jnp.where(live, Yc, jnp.zeros_like(Yc)),
             )
 
-        G, AtY, yty = sparse_gram_fold(
-            (carry[0][0], carry[1][0], carry[2][0]),
+        folded = sparse_gram_fold(
+            tuple(piece[0] for piece in carry),
             cid0 + jnp.arange(seg), cf, d, k,
             use_pallas=use_pallas, val_dtype=val_dtype, pipeline=pipeline,
+            border=border,
         )
-        return G[None], AtY[None], yty[None]
+        return tuple(piece[None] for piece in folded)
 
     sharded = P(axis)
     fold = mesh_lib.shard_map(
         local,
         mesh=mesh,
-        in_specs=((sharded, sharded, sharded), P(), sharded),
-        out_specs=(sharded, sharded, sharded),
+        in_specs=(sharded, P(), sharded),  # a spec a carry, whatever its pieces
+        out_specs=sharded,
         check_vma=False,
     )
     return functools.partial(jax.jit, donate_argnums=(0,))(fold)
 
 
 @functools.lru_cache(maxsize=8)
-def _gram_mesh_solve_program(d, k, val_dtype, mesh, axis):
+def _gram_mesh_solve_program(d, k, mesh, axis, border=False):
     """The fit's ONE cross-device collective: ``lax.psum`` of the
-    (G, AtY, yty) pytree over ``axis`` (a pytree psum lowers to a single
-    fused all-reduce over the ICI), replicated out, then the standard
-    finalize + L-BFGS-on-G solve — identical iterates to the 1-device
-    fold up to the reduction's float reassociation."""
+    (G, AtY, yty) pytree over ``axis`` — the border's pieces with the
+    rest — (a pytree psum lowers to a single fused all-reduce over the
+    ICI), replicated out, then the standard finalize + L-BFGS-on-G
+    solve — identical iterates to the 1-device fold up to the
+    reduction's float reassociation."""
     from jax.sharding import PartitionSpec as P
 
     from keystone_tpu.parallel import mesh as mesh_lib
 
-    def local(G, AtY, yty):
-        return jax.lax.psum((G[0], AtY[0], yty[0]), axis)
+    def local(carry):
+        return jax.lax.psum(tuple(piece[0] for piece in carry), axis)
 
     reduce = mesh_lib.shard_map(
         local,
         mesh=mesh,
-        in_specs=(P(axis), P(axis), P(axis)),
-        out_specs=(P(), P(), P()),
+        in_specs=(P(axis),),
+        out_specs=P(),
         check_vma=False,
     )
-    solve = _gram_solve_program(d, k, val_dtype)
+    solve = _gram_solve_program(d, k, border)
 
     def run(carry, hyper):
-        return solve(reduce(*carry), hyper)
+        return solve(reduce(carry), hyper)
 
     return run
 
 
 def _run_lbfgs_gram_streamed_mesh(
     chunk_fn, num_chunks, d, k, mesh, *, mesh_axis, hyper, use_pallas,
-    val_dtype, operands, max_chunks_per_dispatch, segment_sources, inflight,
-    prefetch_depth, prefetch_stats,
+    val_dtype, border, operands, max_chunks_per_dispatch, segment_sources,
+    inflight, prefetch_depth, prefetch_stats,
 ):
     """Mesh driver for :func:`run_lbfgs_gram_streamed` (ISSUE 16): the
     host loop dispatches one shard_map fold per LOCAL segment (all
@@ -1039,10 +1104,8 @@ def _run_lbfgs_gram_streamed_mesh(
             prefetch_stats.add_busy("compute", _time.perf_counter() - t0)
         return carry
 
-    carry = _mesh_gram_init(d, k, val_dtype, mesh, axis)
-    solve = _gram_mesh_solve_program(
-        int(d), int(k), jnp.dtype(val_dtype), mesh, axis,
-    )
+    carry = _mesh_gram_init(d, k, val_dtype, mesh, axis, border)
+    solve = _gram_mesh_solve_program(int(d), int(k), mesh, axis, border)
 
     if segment_sources is not None:
         from keystone_tpu.data.prefetch import iter_mesh_segments
@@ -1062,7 +1125,7 @@ def _run_lbfgs_gram_streamed_mesh(
         fold = _gram_fold_program_mesh(
             chunk_fn, int(num_chunks), int(d), int(k), int(seg),
             bool(use_pallas), jnp.dtype(val_dtype), bool(pipeline_ok(seg)),
-            mesh, axis, True,
+            mesh, axis, True, border,
         )
         for s, payloads in iter_mesh_segments(
             sources, prefetch_depth=prefetch_depth, stats=prefetch_stats,
@@ -1095,7 +1158,7 @@ def _run_lbfgs_gram_streamed_mesh(
     ops = tuple(ops)
     fold = _gram_fold_program_mesh(
         chunk_fn, int(num_chunks), int(d), int(k), seg, bool(use_pallas),
-        jnp.dtype(val_dtype), False, mesh, axis, False,
+        jnp.dtype(val_dtype), False, mesh, axis, False, border,
     )
     for cid0 in range(0, cpd, seg):
         carry = step(fold, carry, cid0, ops)
@@ -1110,7 +1173,7 @@ def pipeline_ok(seg: int) -> bool:
 
 @functools.lru_cache(maxsize=16)
 def _gram_streamed_program(chunk_fn, num_chunks, d, k, use_pallas, val_dtype,
-                           pipeline=True):
+                           pipeline=True, border=False):
     """Compiled streamed-fit program, cached per (chunk_fn identity, fit
     geometry). Building the jit inside every call would make EVERY fit —
     including the timed second run of a warm benchmark — retrace and
@@ -1118,34 +1181,28 @@ def _gram_streamed_program(chunk_fn, num_chunks, d, k, use_pallas, val_dtype,
     therefore pass a STABLE chunk_fn (module-level function or one object
     reused across fits), with per-fit arrays in ``operands`` and the
     hyperparameters in ``hyper`` (:func:`_solve_operands`)."""
-    from keystone_tpu.ops.sparse import gram_pad_dim, sparse_gram_stream
-
-    d_pad = gram_pad_dim(d, val_dtype)
+    from keystone_tpu.ops.sparse import sparse_gram_stream
 
     @jax.jit
     def _run(operands, hyper):
         def cf(cid):
             return chunk_fn(cid, *operands)
 
-        G, AtY, yty = sparse_gram_stream(
+        carry = sparse_gram_stream(
             cf, num_chunks, d, k, use_pallas=use_pallas,
-            val_dtype=val_dtype, pipeline=pipeline,
+            val_dtype=val_dtype, pipeline=pipeline, border=border,
         )
-        # Solve at the padded width: padded rows of AtY are zero and G's
-        # padded rows/cols are zero, so those W rows stay exactly zero
-        # through every iterate (pure-λ ridge on a zero gradient).
-        W, loss, count = _lbfgs_gram_core(
-            G, AtY, yty, jnp.zeros((d_pad, k), jnp.float32), *hyper,
-        )
-        return W[:d], loss, count
+        return _solve_gram_carry(carry, d, k, border, hyper)
 
     return _run
 
 
-# Largest per-row sum of |value| the range probe lets through: with the
-# intercept lane's 1 every slab entry (a row's lanes that share an id add)
-# is an integer of magnitude <= 256 — the last integer below which
-# bfloat16 (8 significant bits) has no gaps.
+# Largest per-row sum of |value| the range probe lets through: every slab
+# entry (a row's lanes that share an id add) is then an integer of magnitude
+# <= 255, under 256 — the last integer below which bfloat16 (8 significant
+# bits) has no gaps. One to spare: it leaves room for an intercept lane's 1
+# on the same entry, which a caller's own ones column may put there — the
+# estimator's rides the fold's targets and never enters the slab.
 _BF16_EXACT_ROW_SUM = 255.0
 
 
@@ -1160,12 +1217,14 @@ def _slabs_exact_in_bf16(values, Y):
       are. The densify ADDS a row's lanes that share an id (a contraction
       over the lanes, summed in float32 and rounded to the slab's type),
       so a row that repeats an id sums its values there: under this bound
-      every entry is an integer of magnitude <= 256, which bfloat16 holds
-      exactly, even when a stray id lands on the intercept column's 1.
-      Masked lanes are counted as if live (refuses more, never wrongly
-      admits); a NaN fails the integer test, an infinity the sum.
+      every entry is an integer of magnitude <= 255, which bfloat16 holds
+      exactly (no intercept's 1 shares the slab: the ones column rides
+      the targets, and an id outside [0, d) is dropped). Masked lanes
+      are counted as if live (refuses more, never wrongly admits); a
+      NaN fails the integer test, an infinity the sum.
     - the targets survive the round trip through bfloat16, because the
-      accumulate kernels round them to the slab's type.
+      accumulate kernels round them to the slab's type (the border's
+      0/1 column beside them does).
 
     A product of two such slab entries, or of one and a target, is then
     exact in the float32 accumulator (<= 17 significant bits), so G and
@@ -1199,34 +1258,30 @@ def _with_intercept_lane(indices, values, d: int, n: int):
 class _LanedRowChunks:
     """Chunk source over the caller's OWN padded-COO rows: chunk ``cid`` is
     rows ``[cid·c, (cid+1)·c)`` sliced from the ``(n_pad, w)`` operands
-    inside the fold program, the intercept lane (index ``d``, value 1)
-    appended to the slice — a ``(c, w+1)`` transient where the laned and
-    tiled copies were two more datasets in HBM. A ragged last chunk
-    starts at ``n_pad − c`` (a slice never leaves the array) and masks
-    the rows an earlier chunk already folded; rows past the true ``n``
-    are masked dead like any padding. Frozen, so equal sources hash
-    alike and fits of one geometry share a compiled program."""
+    inside the fold program — no laned or tiled copy of the dataset, and
+    no lane for the intercept: the ones column goes to the TARGETS, as
+    the 0/1 mask of the rows this chunk folds (``[Y, live]``, the bordered
+    fold's contract — ``sparse.sparse_gram_fold``), so the slab is the
+    rows' own ``d`` columns wide. A ragged last chunk starts at
+    ``n_pad − c`` (a slice never leaves the array) and masks the rows an
+    earlier chunk already folded; rows past the true ``n`` are masked
+    dead like any padding. Frozen, so equal sources hash alike and fits
+    of one geometry share a compiled program."""
 
     chunk_rows: int
-    d: int
     n: int
 
     def __call__(self, cid, indices, values, Y):
         c = self.chunk_rows
         start = jnp.minimum(cid * c, indices.shape[0] - c)
         rows = start + jnp.arange(c)
-        live = (rows >= cid * c) & (rows < self.n)
+        live = ((rows >= cid * c) & (rows < self.n))[:, None]
         idx = jax.lax.dynamic_slice_in_dim(indices, start, c, 0)
         val = jax.lax.dynamic_slice_in_dim(values, start, c, 0)
         Yc = jax.lax.dynamic_slice_in_dim(Y, start, c, 0)
-        idx1 = jnp.concatenate(
-            [jnp.where(live[:, None], idx, -1),
-             jnp.where(live, self.d, -1)[:, None].astype(idx.dtype)], axis=1,
-        )
-        val1 = jnp.concatenate(
-            [val, jnp.ones((c, 1), val.dtype)], axis=1
-        )
-        return idx1, val1, jnp.where(live[:, None], Yc, 0).astype(jnp.float32)
+        targets = jnp.concatenate(
+            [jnp.where(live, Yc, 0), live], axis=1, dtype=jnp.float32)
+        return jnp.where(live, idx, -1), val, targets
 
 
 class SparseLBFGSwithL2(LabelEstimator):
@@ -1236,9 +1291,13 @@ class SparseLBFGSwithL2(LabelEstimator):
     gather/segment-sum kernels (the TPU form of the reference's active-index
     gradient loops, Gradient.scala:58-123) — the dense design matrix never
     exists, so Amazon-scale problems (n·d ≈ 1e12 dense elements at
-    sparsity 0.005) fit in HBM. The append-ones intercept trick of the
-    reference is kept: every row gets one extra active index at column d
-    with value 1. Dense input datasets take the ordinary dense core.
+    sparsity 0.005) fit in HBM. The append-ones intercept of the
+    reference is kept, regularised with the weights: the gather engine
+    gives every row one extra active index at column d with value 1; the
+    gram engine learns the same intercept from the BORDER of its fold
+    (column sums, target sums and n — ``sparse.sparse_gram_fold``), so
+    its slabs stay d columns wide. Dense input datasets take the
+    ordinary dense core.
 
     ``solver`` picks the iteration engine for sparse input:
       - "gather" (default, the reference-shaped path): every L-BFGS
@@ -1269,9 +1328,8 @@ class SparseLBFGSwithL2(LabelEstimator):
     bit-identical), at HALF the resident operand. This is a capacity
     play: the cost model prices it as a third tier between HBM-raw and
     disk, so working sets that bust HBM raw but fit compressed stay
-    chip-resident with no flag. Requires every index (including the
-    intercept lane at d) to fit int16 — encode raises at the overflow
-    boundary rather than ever wrapping.
+    chip-resident with no flag. Requires every index to fit int16 —
+    encode raises at the overflow boundary rather than ever wrapping.
     """
 
     def __init__(
@@ -1381,37 +1439,37 @@ class SparseLBFGSwithL2(LabelEstimator):
         """Gram-engine fit over RESIDENT padded-COO buffers: fold G once
         over chunks of ``gram_chunk_rows`` rows, iterate on it. The raw
         tier folds straight from the caller's arrays — each chunk is
-        sliced, and its intercept lane appended, INSIDE the fold program
-        (:class:`_LanedRowChunks`), so no laned or tiled copy of the
-        dataset is ever made (at the Amazon cell's 4.2M rows each such
-        copy is 2.8 GB beside the caller's own). With
-        ``compress="int16_bf16"`` the operands are encoded through the
-        compressed-resident tier (``data/resident.py``) first — 4
-        bytes/nnz resident, decode fused into the fold's densify casts."""
+        sliced INSIDE the fold program (:class:`_LanedRowChunks`), so no
+        laned or tiled copy of the dataset is ever made (at the Amazon
+        cell's 4.2M rows each such copy is 2.8 GB beside the caller's
+        own). With ``compress="int16_bf16"`` the operands are encoded
+        through the compressed-resident tier (``data/resident.py``)
+        first — 4 bytes/nnz resident, decode fused into the fold's
+        densify casts. Either way the intercept is the fold's border:
+        the chunks hand ``[Y, live]`` and no ones lane."""
         from keystone_tpu import obs
         from keystone_tpu.ops import pallas_ops
-        from keystone_tpu.ops.sparse import gram_pad_dim
+        from keystone_tpu.ops.sparse import gram_pad_dim, gram_tile_pairs
         from keystone_tpu.ops.sparse_densify import densify_form
 
-        d1 = d + 1
-        npad, lanes = int(indices.shape[0]), int(indices.shape[1]) + 1
+        npad, lanes = int(indices.shape[0]), int(indices.shape[1])
         c = min(self.gram_chunk_rows, npad)
         with obs.span("solver.chunk_tiles", compress=self.compress):
             if self.compress == "int16_bf16":
                 from keystone_tpu.data.resident import CompressedCOOChunks
 
-                idx1, val1 = _with_intercept_lane(indices, values, d, n)
+                live = (np.arange(npad) < n).astype(np.float32)[:, None]
                 chunks = CompressedCOOChunks.encode(
-                    np.asarray(idx1), np.asarray(val1), np.asarray(B),
-                    chunk_rows=c, d=d1, n_true=n,
+                    np.asarray(indices), np.asarray(values),
+                    np.concatenate([np.asarray(B, np.float32), live], axis=1),
+                    chunk_rows=c, d=d, n_true=n,
                 )
-                del idx1, val1
                 operands = chunks.operands()
                 chunk_fn = _resident_chunk_fn  # stable identity -> program reuse
                 nchunks = chunks.num_chunks
             else:
                 operands = (indices, values, B)
-                chunk_fn = _LanedRowChunks(c, d, int(n))  # equal across fits
+                chunk_fn = _LanedRowChunks(c, int(n))  # equal across fits
                 nchunks = -(-npad // c)
 
         probed = {}  # slab_exact, where the range probe decided and no flag
@@ -1438,21 +1496,23 @@ class SparseLBFGSwithL2(LabelEstimator):
             val_dtype = jnp.bfloat16 if exact else jnp.float32
             obs.counter_track("sparse.exact_bf16_fits", int(exact))
         use_pallas = pallas_ops.pallas_direct_ok(*operands)
-        d_pad = gram_pad_dim(d1, val_dtype)
+        d_pad = gram_pad_dim(d, val_dtype)
         obs.set_on_open(
             "estimator.fit", engine="gram", compress=self.compress,
             slab_dtype=jnp.dtype(val_dtype).name, chunks=nchunks,
-            d_pad=d_pad, pallas=bool(use_pallas),
+            d_pad=d_pad, tile_pairs=gram_tile_pairs(d, val_dtype),
+            intercept="border", pallas=bool(use_pallas),
             densify=densify_form(use_pallas, c, d_pad, val_dtype),
             **probed,
         )
         solved: dict = {}
         W, final_loss = run_lbfgs_gram_streamed(
-            chunk_fn, nchunks, d1, B.shape[1],
+            chunk_fn, nchunks, d, B.shape[1],
             lam=self.lam, num_iterations=self.num_iterations,
             convergence_tol=self.convergence_tol, n=n,
             use_pallas=use_pallas,
             val_dtype=val_dtype,
+            border=True,
             operands=operands,
             # Resident operands already hold the whole dataset: the
             # double-buffered second slab would be pure extra HBM beside
@@ -1518,8 +1578,8 @@ class SparseLBFGSwithL2(LabelEstimator):
         if self.compress is not None:
             from keystone_tpu.data import resident as resident_mod
 
-            # +1: the append-ones intercept lane lives at index d.
-            if not resident_mod.compressible_dim(d + 1):
+            # The intercept is the fold's border: no lane at index d.
+            if not resident_mod.compressible_dim(d):
                 return float("inf")
             bytes_per_nnz = resident_mod.COMPRESSED_BYTES_PER_NNZ
         else:

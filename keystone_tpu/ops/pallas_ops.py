@@ -821,10 +821,14 @@ def gram_sym_acc(G, F, interpret: Optional[bool] = None):
     """G + FᵀF, accumulating only upper-triangle blocks of the Gramian.
 
     G: (d, d) float32 with a *meaningful upper triangle only*; F: (n, d).
-    Returns a NEW (d, d) buffer whose upper-triangle blocks hold the
-    accumulation and whose strictly-lower blocks are UNDEFINED memory
-    (never written by any grid step — do not read them). Callers mirror
-    once after the last accumulation
+    G is updated IN PLACE — the operand is aliased to the output, so a
+    loop that carries G (``sparse.sparse_gram_fold``'s chunk scan) hands
+    the kernel its carry and copies nothing; a caller that still uses
+    its G afterwards gets a copy made for it by XLA, as for any donated
+    operand. The upper-triangle blocks of the result hold the
+    accumulation; the strictly-lower blocks are written by no grid step
+    and keep WHAT WENT IN (the carry's zeros), not a sum — do not read
+    them. Callers mirror once after the last accumulation
     (``jnp.triu(G) + jnp.triu(G, 1).T``). This is the
     per-partition Gramian accumulation of the reference's streaming
     solvers (BlockWeightedLeastSquares.scala:177-313's per-partition
@@ -864,6 +868,11 @@ def gram_sym_acc(G, F, interpret: Optional[bool] = None):
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((d, d), jnp.float32),
+        # G is updated in place (operand 2, after the two scalar-prefetch
+        # arrays, is output 0): block (i, j) is read at its pair's first
+        # row tile and written back when the pair ends, and no later pair
+        # reads it, so the pipeline never sees a block it has overwritten.
+        input_output_aliases={2: 0},
         # The riding G operand (f32 in + out at (ti, ti)) pushes scoped
         # VMEM to ~20 MB at 1024-wide bf16 tiles — past the compiler's
         # conservative 16 MB default but well under the chip's 128 MB.
@@ -896,8 +905,8 @@ def _gram_corr_sym_acc_kernel(
     with the correlation riding the diagonal pairs exactly like
     :func:`gram_corr_sym` — Fᵢ's tiles are already resident there, so the
     correlation adds one (tk, tr) R stream and zero extra reads of F. The
-    running (G, C) ride through as operands (same contract as
-    :func:`gram_sym_acc`): the per-chunk contribution never materializes
+    running (G, C) ride through as operands aliased to the outputs (same
+    contract as :func:`gram_sym_acc`): the per-chunk contribution never materializes
     as separate (d, d)/(d, k) buffers + adds, and the separate XLA FᵀR
     GEMM — which re-read the whole chunk slab from HBM — disappears."""
     p = pl.program_id(0)
@@ -935,9 +944,10 @@ def gram_corr_sym_acc(G, C, F, R, interpret: Optional[bool] = None):
     ``gram_sym_acc(G, F)`` + an XLA ``FᵀR`` GEMM.
 
     G: (d, d) f32 with a *meaningful upper triangle only* (the
-    :func:`gram_sym_acc` contract — strictly-lower blocks of the result
-    are UNDEFINED memory; mirror once after the last accumulation).
-    C: (d, k) f32, fully valid in and out. F: (n, d), R: (n, k) — R is
+    :func:`gram_sym_acc` contract — updated in place, strictly-lower
+    blocks of the result keep what went in; mirror once after the last
+    accumulation). C: (d, k) f32, fully valid in and out (its
+    lane-padded copy is the aliased operand). F: (n, d), R: (n, k) — R is
     quantized to F's compute dtype inside the kernel, matching the
     unfused composition's ``FᵀR.astype(F.dtype)`` recipe bit-for-bit in
     operand precision. Requires :func:`gram_corr_acc_ok`.
@@ -994,6 +1004,11 @@ def gram_corr_sym_acc(G, C, F, R, interpret: Optional[bool] = None):
             jax.ShapeDtypeStruct((d, d), jnp.float32),
             jax.ShapeDtypeStruct((d, tr), jnp.float32),
         ],
+        # G and the lane-padded C are updated in place (operands 2 and 3,
+        # after the two scalar-prefetch arrays, are outputs 0 and 1): see
+        # gram_sym_acc; C's row block is read on the row's diagonal pair,
+        # the row's first, and written back at the row boundary.
+        input_output_aliases={2: 0, 3: 1},
         # Riding G in+out at (ti, ti) f32 plus the corr/R tiles measures
         # ~22 MB scoped VMEM at 1024-wide bf16 tiles — past the compiler's
         # conservative 16 MB default, well under the chip's 128 MB (same

@@ -215,13 +215,31 @@ def sparse_matmul_t(indices, values, V, d: int):
     return out[:d]
 
 
+def _gram_tile(val_dtype) -> int:
+    """Column tile of the accumulating syrk: 1,024 for bfloat16 slabs, 512
+    for float32 ones (``pallas_ops._strided_ti``)."""
+    return 1024 if jnp.dtype(val_dtype) == jnp.bfloat16 else 512
+
+
 def gram_pad_dim(d: int, val_dtype) -> int:
     """Column padding for :func:`sparse_gram_stream`'s dense slabs: round d
     up to the accumulating-syrk column tile (zero columns contribute zero
     Gramian rows/cols, and zero-initialized solver blocks stay exactly
-    zero, so callers may solve on the padded shape and slice)."""
-    tile = 1024 if jnp.dtype(val_dtype) == jnp.bfloat16 else 512
+    zero, so callers may solve on the padded shape and slice). ``d`` is
+    the width of the chunk's OWN columns: an intercept learnt as a border
+    (:func:`sparse_gram_fold`, ``border=True``) adds no column, so a
+    hashing width that is a multiple of the tile pads to itself — one
+    column more would cost a whole tile row of the fold."""
+    tile = _gram_tile(val_dtype)
     return -(-d // tile) * tile
+
+
+def gram_tile_pairs(d: int, val_dtype) -> int:
+    """Upper-triangle tile pairs one accumulate call multiplies at width
+    ``d``: nt·(nt + 1)/2 over the nt column tiles of :func:`gram_pad_dim`
+    (136 at 16,384 bfloat16 columns, 153 at 16,385)."""
+    nt = gram_pad_dim(d, val_dtype) // _gram_tile(val_dtype)
+    return nt * (nt + 1) // 2
 
 
 def sparse_gram_stream(
@@ -232,6 +250,7 @@ def sparse_gram_stream(
     use_pallas: bool = False,
     val_dtype=jnp.float32,
     pipeline: bool = True,
+    border: bool = False,
 ):
     """Fold (G = AᵀA, AᵀY, ΣY²) over padded-COO row chunks — the sparse
     arm of the out-of-core streaming tier (parallel/streaming.py).
@@ -257,7 +276,9 @@ def sparse_gram_stream(
     the per-partition kernel.
 
     Returns (G, AtY, yty) at d_pad = :func:`gram_pad_dim` (slice [:d] to
-    drop the padding). Traceable — call under jit. For dispatch-bounded
+    drop the padding) — with ``border`` the four pieces of
+    :func:`sparse_gram_fold`'s bordered carry, G mirrored. Traceable —
+    call under jit. For dispatch-bounded
     SEGMENTED folding (a long chunk stream run as one multi-minute program
     can be neither checkpointed nor cancelled), use :func:`sparse_gram_fold`
     over cid ranges and :func:`gram_finalize` once at the end.
@@ -265,22 +286,26 @@ def sparse_gram_stream(
     pass False when an extra resident chunk slab would bust HBM (e.g. the
     bench's resident-capacity probe beside a 9.8 GB COO).
     """
-    carry = sparse_gram_fold(
+    G, *rest = sparse_gram_fold(
         None, jnp.arange(num_chunks), chunk_fn, d, k,
         use_pallas=use_pallas, val_dtype=val_dtype, pipeline=pipeline,
+        border=border,
     )
-    G, AtY, yty = carry
-    return gram_finalize(G), AtY, yty
+    return (gram_finalize(G), *rest)
 
 
-def sparse_gram_init(d: int, k: int, val_dtype=jnp.float32):
-    """Zero (G_raw, AtY, yty) carry for :func:`sparse_gram_fold`."""
+def sparse_gram_init(d: int, k: int, val_dtype=jnp.float32,
+                     border: bool = False):
+    """Zero (G_raw, AtY, yty) carry for :func:`sparse_gram_fold`; with
+    ``border`` AtY is one column wider (the column sums Xᵀ1 ride it) and
+    a fourth piece, 1ᵀY (k,), follows."""
     d_pad = gram_pad_dim(d, val_dtype)
-    return (
+    carry = (
         jnp.zeros((d_pad, d_pad), jnp.float32),
-        jnp.zeros((d_pad, k), jnp.float32),
+        jnp.zeros((d_pad, k + int(border)), jnp.float32),
         jnp.zeros((), jnp.float32),
     )
+    return carry + (jnp.zeros((k,), jnp.float32),) if border else carry
 
 
 @jax.named_scope("ks.sparse_gram_acc")  # the fold's one mirror
@@ -299,12 +324,30 @@ def sparse_gram_fold(
     use_pallas: bool = False,
     val_dtype=jnp.float32,
     pipeline: bool = True,
+    border: bool = False,
 ):
     """Fold the chunk ids ``cids`` into the (G_raw, AtY, yty) carry.
 
     ``carry=None`` starts fresh (:func:`sparse_gram_init`). G_raw carries
     the accumulating-syrk upper-triangle contract — call
     :func:`gram_finalize` after the LAST fold. Traceable.
+
+    The fold is generic over the chunk's columns (``d`` of them) and its
+    target columns (``k``): a caller that wants an intercept may lane a
+    ones column as the last of its own ``d`` and pay a column for it.
+    ``border=True`` is the other way to the same normal equations: the
+    chunk hands ``[Y, live]`` — k + 1 target columns, the last the 0/1
+    mask of the rows it folds — and the ones column never enters the
+    slab. The correlation the chunk step computes anyway then returns
+    ``Xᵀ[Y, 1] = [XᵀY, s]`` (AtY is (d_pad, k + 1), its last column the
+    column sums), a fourth carry piece takes ``liveᵀY = 1ᵀY`` (k,), and
+    ``yty`` stays the sum over Y alone: with the fit's n these are the
+    last row and column of the (d + 1)-wide Gramian, to the bit where
+    the sums are exact — ``lbfgs._lbfgs_gram_core`` solves the bordered
+    system from them. At a width that is a multiple of the column tile
+    the appended column would cost a whole tile row (17 of 153 pairs at
+    d = 16,384); the border costs one more of the 128 lanes the
+    correlation's operand is padded to.
 
     Two chunk-loop structures (identical results — same chunk order, same
     per-chunk arithmetic):
@@ -331,7 +374,7 @@ def sparse_gram_fold(
     from keystone_tpu.ops.sparse_densify import densify_rows
 
     if carry is None:
-        carry = sparse_gram_init(d, k, val_dtype)
+        carry = sparse_gram_init(d, k, val_dtype, border)
     d_pad = carry[0].shape[0]
 
     @jax.named_scope("ks.sparse_densify")  # names the phase in a device profile
@@ -347,7 +390,8 @@ def sparse_gram_fold(
     fused = use_pallas and pallas_ops.gram_corr_acc_ok(slab_shape)
 
     @jax.named_scope("ks.sparse_gram_acc")
-    def fold_slab(G, AtY, yty, dense, Yc):
+    def fold_slab(carry, dense, Yc):
+        G, AtY, yty, *ysum = carry
         if fused:
             G, AtY = pallas_ops.gram_corr_sym_acc(G, AtY, dense, Yc)
         else:
@@ -363,7 +407,17 @@ def sparse_gram_fold(
                 preferred_element_type=jnp.float32,
             )
         Yf = Yc.astype(jnp.float32)
-        return G, AtY, yty + jnp.sum(Yf * Yf)
+        if border:
+            # liveᵀY as the correlation takes XᵀY: targets in the slab's
+            # type, float32 sums — the appended column's row of AtY.
+            Yq = Yc.astype(dense.dtype)
+            ysum = [ysum[0] + jax.lax.dot_general(
+                Yq[:, k], Yq[:, :k], (((0,), (0,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
+                preferred_element_type=jnp.float32,
+            )]
+            Yf = Yf[:, :k]
+        return (G, AtY, yty + jnp.sum(Yf * Yf), *ysum)
 
     cids = jnp.asarray(cids)
     num = int(cids.shape[0])
@@ -371,18 +425,15 @@ def sparse_gram_fold(
         staged = densify_chunk(cids[0])
 
         def body(state, cid_next):
-            (G, AtY, yty), (dense, Yc) = state
+            carry, (dense, Yc) = state
             nxt = densify_chunk(cid_next)  # independent of the fold below
-            G, AtY, yty = fold_slab(G, AtY, yty, dense, Yc)
-            return ((G, AtY, yty), nxt), None
+            return (fold_slab(carry, dense, Yc), nxt), None
 
         (carry, last), _ = jax.lax.scan(body, (carry, staged), cids[1:])
-        carry = fold_slab(*carry, *last)
-        return carry
+        return fold_slab(carry, *last)
 
     def body(carry, cid):
-        dense, Yc = densify_chunk(cid)
-        return fold_slab(*carry, dense, Yc), None
+        return fold_slab(carry, *densify_chunk(cid)), None
 
     carry, _ = jax.lax.scan(body, carry, cids)
     return carry

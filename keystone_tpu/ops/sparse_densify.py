@@ -92,6 +92,19 @@ def kernel_block_rows(c: int, d_pad: int, val_dtype) -> Optional[int]:
     return None
 
 
+def _scratch_height(tiles: int) -> int:
+    """Sublanes one row's product takes in the kernel's scratch: its lane
+    tiles, rounded up to an ODD number of 8-sublane tiles. The read-back
+    strides over the scratch by this height, and at an even number of
+    tiles its eight reads collide: a 65,536-row bfloat16 chunk of 128 lane
+    tiles (16,384 columns) takes 4.67 ms at a height of 128, 3.42 at 136
+    — 628 GB/s, what 136 lane tiles read at their own height; heights of
+    32, 64, 96, 128 and 160 are each the slow ones of their sweep, in both
+    slab types (v5e)."""
+    eights = -(-tiles // 8)
+    return 8 * (eights + 1 - eights % 2)
+
+
 def _split3(v):
     """v = v1 + v2 + v3 exactly, each part a bfloat16 (24 = 3 × 8 bits)."""
     v1 = v.astype(jnp.bfloat16)
@@ -112,7 +125,7 @@ def _contract_kernel(ids_ref, val_ref, out_ref, rows_ref):
     the stores."""
     lanes = ids_ref.shape[1]
     tiles, group = out_ref.shape[1] // _LANES, _group_rows(out_ref.dtype)
-    height = rows_ref.shape[0] // group  # tiles, rounded up to 8 sublanes
+    height = rows_ref.shape[0] // group  # _scratch_height(tiles)
     exact_f32 = out_ref.dtype == jnp.float32
     tile_of = jax.lax.broadcasted_iota(jnp.int32, (height, lanes), 0)
     lane_of = jax.lax.broadcasted_iota(jnp.int32, (_LANES, lanes), 0)
@@ -154,7 +167,7 @@ def contract_kernel(safe, vals, d_pad: int, interpret: Optional[bool] = None):
     rows = kernel_block_rows(c, d_pad, val_dtype)
     if rows is None:
         raise ValueError(f"no kernel for a {(c, d_pad)} slab of {val_dtype}")
-    height = -(-d_pad // _LANES // 8) * 8
+    height = _scratch_height(d_pad // _LANES)
     lanes = -(-w // _LANES) * _LANES
     pad = ((0, 0), (0, lanes - w))  # dead lanes: id 0, value 0
     chunk = pl.BlockSpec((rows, lanes), lambda i: (i, 0))

@@ -5,6 +5,7 @@ kernel code paths that run on TPU are validated on the CPU test platform —
 the kernel-level analog of the "Spark local mode" strategy (SURVEY.md §4).
 """
 
+import jax
 import numpy as np
 import jax.numpy as jnp
 import pytest
@@ -409,6 +410,55 @@ class TestGramCorrSymAcc:
                                    atol=1e-3)
         np.testing.assert_allclose(np.asarray(A_pl), np.asarray(A_ref),
                                    atol=1e-3)
+
+    @pytest.mark.parametrize("kernel", ["gram_sym_acc", "gram_corr_sym_acc"])
+    def test_two_calls_on_one_running_gramian_update_it_in_place(self, kernel):
+        """PR 38: the running operands are aliased to the outputs. Two
+        calls on the same G give the two-slab sum on the upper triangle,
+        the strictly-lower blocks are WHAT WENT IN (they were undefined
+        memory before), and the caller's own G0 is not touched."""
+        n, d, k = 512, 1024, 2  # two 512-column tiles: block (1, 0) is never written
+        F1, F2 = (rng.normal(size=(n, d)).astype(np.float32) for _ in range(2))
+        R1, R2 = (rng.normal(size=(n, k)).astype(np.float32) for _ in range(2))
+        G0 = jnp.asarray(rng.normal(size=(d, d)).astype(np.float32))
+        C0 = jnp.asarray(rng.normal(size=(d, k)).astype(np.float32))
+        kept = np.array(G0)
+        if kernel == "gram_sym_acc":
+            G = po.gram_sym_acc(po.gram_sym_acc(G0, F1, interpret=True), F2,
+                                interpret=True)
+        else:
+            G, C = po.gram_corr_sym_acc(G0, C0, F1, R1, interpret=True)
+            G, C = po.gram_corr_sym_acc(G, C, F2, R2, interpret=True)
+            np.testing.assert_allclose(
+                np.asarray(C), np.asarray(C0) + F1.T @ R1 + F2.T @ R2, atol=2e-3)
+        G = np.asarray(G)
+        want = kept + F1.T @ F1 + F2.T @ F2
+        np.testing.assert_allclose(np.triu(G), np.triu(want), atol=2e-3)
+        np.testing.assert_array_equal(G[512:, :512], kept[512:, :512])
+        np.testing.assert_array_equal(np.asarray(G0), kept)
+
+    @pytest.mark.parametrize("kernel,aliases", [
+        ("gram_sym_acc", ((2, 0),)),
+        ("gram_corr_sym_acc", ((2, 0), (3, 1))),
+    ])
+    def test_the_running_operands_are_aliased_to_the_outputs(self, kernel, aliases):
+        """The jaxpr's ``pallas_call`` carries ``input_output_aliases`` (the
+        operand index counts the two scalar-prefetch arrays). Whether XLA
+        then drops the chunk loop's copy of its carry is the chip's to say
+        (the cell's device account)."""
+        n, d, k = 512, 1024, 2
+        G = jnp.zeros((d, d), jnp.float32)
+        C = jnp.zeros((d, k), jnp.float32)
+        F = jnp.zeros((n, d), jnp.bfloat16)
+        R = jnp.zeros((n, k), jnp.float32)
+        if kernel == "gram_sym_acc":
+            call, args = (lambda G, F: po.gram_sym_acc(G, F, interpret=False)), (G, F)
+        else:
+            call, args = (lambda *a: po.gram_corr_sym_acc(*a, interpret=False)), (G, C, F, R)
+        eqns = [e for e in jax.make_jaxpr(call)(*args).jaxpr.eqns
+                if e.primitive.name == "pallas_call"]
+        assert len(eqns) == 1
+        assert tuple(eqns[0].params["input_output_aliases"]) == aliases
 
     def test_pipelined_fold_bit_identical_to_serial(self):
         from keystone_tpu.ops.sparse import sparse_gram_stream

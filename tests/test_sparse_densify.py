@@ -109,6 +109,8 @@ def test_the_kernel_is_left_where_it_does_not_apply():
         densify_rows(jnp.zeros((8, 4), jnp.int32), jnp.zeros((8, 4)), 100, 200, jnp.float32)
     for d in (1, 511, 512, 513, 16385):  # the ids split at 128 with no remainder: assert it
         assert gram_pad_dim(d, jnp.float32) % 512 == 0 and gram_pad_dim(d, jnp.bfloat16) % 1024 == 0
+    # the scratch's stride is an odd number of 8-sublane tiles, never under the row's lane tiles
+    assert [sparse_densify._scratch_height(t) for t in (1, 8, 9, 64, 120, 128, 136)] == [8, 8, 24, 72, 120, 136, 136]
 
 
 def sparse_fit(chunk_rows=128):
@@ -135,16 +137,46 @@ def test_a_fit_says_how_it_densified_and_both_forms_fit_alike(monkeypatch):
     assert (attrs["pallas"], attrs["densify"]) == (True, "contract")
 
 
-@pytest.mark.parametrize("val_dtype,d_pad", [(jnp.bfloat16, 17408), (jnp.float32, 16896)],
-                         ids=["bfloat16", "float32"])
-def test_mosaic_takes_the_kernel_at_the_amazon_cell_chunk_shape(one_chip, compile_for_chip, val_dtype, d_pad):
+@pytest.mark.parametrize("val_dtype,d_pad,lanes", [
+    (jnp.bfloat16, 17408, 83), (jnp.float32, 16896, 83),  # a laned ones column: d + 1 = 16,385
+    (jnp.bfloat16, 16384, 82), (jnp.float32, 16384, 82),  # the cell's since PR 38: the scratch strides by 136, not 128
+], ids=["bfloat16", "float32", "bfloat16-16384", "float32-16384"])
+def test_mosaic_takes_the_kernel_at_the_amazon_cell_chunk_shape(one_chip, compile_for_chip, val_dtype, d_pad, lanes):
     """Compiled here for the described chip (nothing runs): the interpreter
     cannot say whether Mosaic accepts the strided reads and the row loads."""
-    rows = jax.ShapeDtypeStruct((65536, 83), jnp.int32, sharding=one_chip)
-    vals = jax.ShapeDtypeStruct((65536, 83), jnp.float32, sharding=one_chip)
+    rows = jax.ShapeDtypeStruct((65536, lanes), jnp.int32, sharding=one_chip)
+    vals = jax.ShapeDtypeStruct((65536, lanes), jnp.float32, sharding=one_chip)
     compiled = compile_for_chip(
-        lambda i, v: densify_rows(i, v, 16385, d_pad, val_dtype, use_pallas=True, interpret=False),
+        lambda i, v: densify_rows(i, v, 16302 + lanes, d_pad, val_dtype, use_pallas=True, interpret=False),
         rows, vals)
     assert "tpu_custom_call" in compiled.as_text() and "sparse_densify" in compiled.as_text()
     # the slab and nothing of its size beside it (the scatter held a second one)
     assert compiled.memory_analysis().temp_size_in_bytes < 0.1 * 65536 * d_pad
+
+
+def test_the_amazon_cell_chunk_loop_keeps_its_gramian_in_its_carry(one_chip, compile_for_chip, monkeypatch):
+    """PR 38, compiled here for the described chip (nothing runs): the
+    estimator's own fold + solve program at the cell's shapes — the slab is
+    the rows' 16,384 columns wide (no column for the intercept), and the
+    chunk loop's body, where the accumulate kernel updates its aliased
+    Gramian, holds no copy of it (the parent copied 1.21 GB in front of
+    each of the 64 calls). What the copy cost is the chip's to say."""
+    from keystone_tpu.ops import pallas_ops
+    from keystone_tpu.ops.learning import lbfgs
+
+    monkeypatch.setattr(pallas_ops, "_interpret", lambda: False)  # Mosaic, not the interpreter
+    n, w, d, k, c = 4194304, 82, 16384, 2, 65536
+    shape = lambda dims, dtype: jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+    program = lbfgs._gram_streamed_program.__wrapped__(  # no cache entry: the kernels are patched
+        lbfgs._LanedRowChunks(c, n), n // c, d, k, True, jnp.dtype(jnp.bfloat16), False, True)
+    compiled = compile_for_chip(
+        program, (shape((n, w), jnp.int32), shape((n, w), jnp.float32), shape((n, k), jnp.float32)),
+        (shape((), jnp.float32), shape((), jnp.int32), shape((), jnp.float32), shape((), jnp.float32)))
+    text = compiled.as_text()
+    assert "bf16[65536,16384]" in text and "[65536,17408]" not in text
+    blocks = text.split("\n\n")  # one computation a block
+    (loop_body,) = [b for b in blocks if 'custom_call_target="tpu_custom_call"' in b and "gram_corr_sym_acc" in b]
+    gramian_copies = [line for line in loop_body.splitlines() if "= f32[16384,16384]" in line and " copy(" in line]
+    assert gramian_copies == []
+    # the slab, the Gramian and its mirror: 5.1 GB where 17,408 columns took 5.5
+    assert compiled.memory_analysis().temp_size_in_bytes < 5.2e9

@@ -1,9 +1,11 @@
 """The sparse L-BFGS fit as the Amazon cell drives it (PR 28): chunks sliced
-and laned inside the fold program instead of copied, hyperparameters as
-operands of the compiled solve, the iteration count on the fitted mapper,
-the spans, attributes and counters the fit leaves under a tracer, and the
-slab type that follows the values' range (PR 29): rows and targets that
-bfloat16 holds exactly fold through bfloat16 slabs to the same Gramian."""
+inside the fold program instead of copied, hyperparameters as operands of
+the compiled solve, the iteration count on the fitted mapper, the spans,
+attributes and counters the fit leaves under a tracer, the slab type that
+follows the values' range (PR 29): rows and targets that bfloat16 holds
+exactly fold through bfloat16 slabs to the same Gramian — and the intercept
+as a border of the fold (PR 38): the ones column rides the targets, the
+slab is the rows' own d columns wide."""
 
 import jax
 import jax.numpy as jnp
@@ -14,7 +16,7 @@ from keystone_tpu import obs
 from keystone_tpu.data import Dataset
 from keystone_tpu.ops.learning import lbfgs
 from keystone_tpu.ops.learning.lbfgs import SparseLBFGSwithL2
-from keystone_tpu.ops.sparse import sparse_gram_stream
+from keystone_tpu.ops.sparse import gram_pad_dim, gram_tile_pairs, sparse_gram_stream
 
 
 def rows(n=700, d=96, w=5, seed=0):
@@ -56,18 +58,19 @@ def test_padding_rows_past_n_fold_nothing():
                                fit(data, labels), rtol=0, atol=2e-5)
 
 
-def test_laned_row_chunks_are_equal_across_fits_and_lane_the_intercept():
-    source = lbfgs._LanedRowChunks(4, 9, 6)
-    assert source == lbfgs._LanedRowChunks(4, 9, 6) and hash(source) == hash(lbfgs._LanedRowChunks(4, 9, 6))
+def test_laned_row_chunks_are_equal_across_fits_and_hand_the_ones_column_to_the_targets():
+    source = lbfgs._LanedRowChunks(4, 6)
+    assert source == lbfgs._LanedRowChunks(4, 6) and hash(source) == hash(lbfgs._LanedRowChunks(4, 6))
     idx = jnp.arange(12, dtype=jnp.int32).reshape(6, 2) % 9
     val = jnp.full((6, 2), 2.0)
     Y = jnp.arange(6.0)[:, None]
     i1, v1, y = source(jnp.int32(1), idx, val, Y)  # rows 2..5 re-sliced; 4 and 5 are chunk 1's
-    assert i1.shape == (4, 3) and v1.shape == (4, 3)
+    assert i1.shape == (4, 2) and v1.shape == (4, 2)  # no lane for the intercept
     np.testing.assert_array_equal(np.asarray(i1[:2]), -1)  # chunk 0 folded rows 2 and 3
-    np.testing.assert_array_equal(np.asarray(i1[2:, :2]), np.asarray(idx[4:]))
-    np.testing.assert_array_equal(np.asarray(i1[2:, 2]), 9)  # the ones column's lane
+    np.testing.assert_array_equal(np.asarray(i1[2:]), np.asarray(idx[4:]))
+    assert y.shape == (4, 2) and y.dtype == jnp.float32
     np.testing.assert_array_equal(np.asarray(y[:, 0]), [0, 0, 4, 5])
+    np.testing.assert_array_equal(np.asarray(y[:, 1]), [0, 0, 1, 1])  # the ones column: the rows it folds
 
 
 def test_a_ridge_sweep_reuses_one_compiled_solve():
@@ -106,13 +109,14 @@ def test_spans_attributes_and_counters_under_a_tracer_and_nothing_without():
     gram, gather = tracer.spans("estimator.fit")
     assert gram["args"] == {"estimator": "SparseLBFGSwithL2", "engine": "gram", "compress": None,
                             "slab_dtype": "float32", "slab_exact": False, "chunks": 4, "d_pad": 512,
+                            "tile_pairs": 1, "intercept": "border",
                             "pallas": False, "densify": "contract"}  # normal values: the probe ran and refused
     assert gather["args"]["engine"] == "gather"
     sites = [s["args"].get("site") for s in tracer.spans("executor.drain")]
     assert sites.count("solver_loss") == 2  # the one wait of each fit, filed as a wait
     assert sites.count("slab_probe") == 1  # and the gram fit's read of the probe's verdict
     counters = [(e["name"], e["value"]) for e in tracer.events if e["type"] == "counter"]
-    assert ("sparse.rows_folded", 512.0) in counters and ("sparse.nnz_folded", 512.0 * 6) in counters
+    assert ("sparse.rows_folded", 512.0) in counters and ("sparse.nnz_folded", 512.0 * 5) in counters  # the lanes that exist
     assert counters.count(("lbfgs.iterations", 6.0)) == 2
     assert counters.count(("sparse.exact_bf16_fits", 0.0)) == 1
 
@@ -129,8 +133,8 @@ def test_set_on_open_reaches_the_innermost_span_of_that_name():
 
 
 def test_name_scopes_of_the_sparse_fold_are_in_the_lowered_program():
-    source = lbfgs._LanedRowChunks(128, 96, 512)
-    program = lbfgs._gram_streamed_program(source, 4, 97, 2, False, jnp.dtype(jnp.float32), False)
+    source = lbfgs._LanedRowChunks(128, 512)
+    program = lbfgs._gram_streamed_program(source, 4, 96, 2, False, jnp.dtype(jnp.float32), False, True)
     data, labels = rows(n=512)
     text = program.lower((data.data["indices"], data.data["values"], labels.array),
                          lbfgs._solve_operands(1e-3, 5, 1e-4, 512)).as_text(debug_info=True)
@@ -165,13 +169,16 @@ def with_values(data, fill):
 
 
 def folded(data, labels, val_dtype, d=96, chunk_rows=256):
-    """(G, AtY) of the fit's own fold on the common (d + 1) block."""
-    source = lbfgs._LanedRowChunks(chunk_rows, d, data.n)
+    """(G, AtY) of the fit's own fold, its border laid around the (d, d)
+    block: the normal equations of [X, 1] on the common (d + 1) block."""
+    source = lbfgs._LanedRowChunks(chunk_rows, data.n)
     fold = jax.jit(lambda i, v, y: sparse_gram_stream(
-        lambda cid: source(cid, i, v, y), -(-data.n // chunk_rows), d + 1, 2,
-        val_dtype=val_dtype, pipeline=False))
-    G, AtY, _ = fold(data.data["indices"], data.data["values"], labels.array)
-    return np.asarray(G)[:d + 1, :d + 1], np.asarray(AtY)[:d + 1]
+        lambda cid: source(cid, i, v, y), -(-data.n // chunk_rows), d, 2,
+        val_dtype=val_dtype, pipeline=False, border=True))
+    G, AtY, _, ysum = map(np.asarray, fold(data.data["indices"], data.data["values"], labels.array))
+    s = AtY[:d, 2]
+    return (np.block([[G[:d, :d], s[:, None]], [s[None], np.float32(data.n)]]),
+            np.concatenate([AtY[:d, :2], ysum[None]]))
 
 
 def traced_fit(data, labels, **how):
@@ -210,7 +217,7 @@ REFUSED = {
     "normal_values": lambda data, labels: (rows()[0], labels),
     "a_value_of_a_tenth": lambda data, labels: (with_values(data, 0.1), labels),
     "a_row_summing_to_257": lambda data, labels: (with_values(data, [253, 1, 1, 1, 1]), labels),
-    # 256 + the intercept's 1 would be 257 should a stray id land on the ones column
+    # drawn when the intercept's 1 shared the slab (256 + 1 = 257); it rides the targets now, the line stays
     "a_row_summing_to_256": lambda data, labels: (with_values(data, [-252, 1, 1, 1, 1]), labels),
     "a_nan": lambda data, labels: (with_values(data, np.nan), labels),
     "targets_of_a_tenth": lambda data, labels: (data, Dataset.of(0.1 * labels.array)),
@@ -286,3 +293,48 @@ def test_the_selectors_gram_choice_folds_binary_rows_in_bfloat16_and_counts_the_
     counted = [e["value"] for e in tracer.events
                if e["type"] == "counter" and e["name"] == "sparse.exact_bf16_fits"]
     assert sum(counted) == 2.0
+
+
+# -- the intercept is a border of the fold (PR 38) ----------------------------
+
+
+@pytest.mark.parametrize("gram_dtype,tile", [("f32", 512), (None, 1024)])
+@pytest.mark.parametrize("d", [96, 512, 513, 1024])
+def test_the_fit_says_its_intercept_is_a_border_and_pads_its_own_width(d, gram_dtype, tile):
+    """``d_pad`` is ``gram_pad_dim(d)``, not of d + 1: a width that is a
+    multiple of the tile pads to itself, and the pairs a chunk call folds
+    are the pairs of that width."""
+    data, labels = exact_rows(n=300, d=d)
+    _, attrs, _ = traced_fit(data, labels, num_features=d, num_iterations=2, gram_dtype=gram_dtype)
+    val_dtype = jnp.float32 if gram_dtype == "f32" else jnp.bfloat16
+    tiles = -(-d // tile)
+    assert attrs["intercept"] == "border"
+    assert attrs["d_pad"] == gram_pad_dim(d, val_dtype) == tiles * tile
+    assert attrs["tile_pairs"] == gram_tile_pairs(d, val_dtype) == tiles * (tiles + 1) // 2
+    assert attrs["d_pad"] <= gram_pad_dim(d + 1, val_dtype)  # never worse than the laned column
+
+
+def test_the_intercept_is_regularised_with_the_rest():
+    """Targets of mean 3: a small ridge learns the mean as the intercept, a
+    large one drives it to 0 with the weights (LBFGS.scala:208-281 — the
+    ones column is a feature like another), as the gather engine's does."""
+    data, labels = exact_rows()
+    labels = Dataset.of(labels.array + 3.0)
+    fit = lambda lam, **how: SparseLBFGSwithL2(**{**GRAM, **how, "lam": lam}).fit(data, labels)
+    free, held = fit(1e-6), fit(1e4)
+    assert np.all(np.abs(np.asarray(free.b_opt)) > 1.0)
+    assert np.all(np.abs(weights(held)) < 1e-3) and np.all(np.asarray(held.b_opt) > 0)
+    gather = fit(1e4, solver="gather")
+    np.testing.assert_allclose(weights(held), weights(gather), rtol=1e-4, atol=1e-9)
+
+
+def test_a_stray_id_at_d_is_dropped_not_added_to_the_intercept():
+    """An id outside [0, d) adds to no column: the slab has no intercept
+    column for it to land on."""
+    data, labels = exact_rows()
+    idx = np.array(data.data["indices"])
+    stray = Dataset({"indices": jnp.asarray(np.where(idx == idx[7, 0], 96, idx)),
+                     "values": data.data["values"]}, n=data.n)
+    G, _ = folded(stray, labels, jnp.bfloat16)
+    assert G[96, 96] == 700.0 and G[idx[7, 0]].sum() == 0.0
+    assert G[:96, 96].sum() == 700.0 * 5 - (idx == idx[7, 0]).sum()  # the column sums count every lane kept

@@ -206,3 +206,157 @@ class TestGramSolverMatchesGather:
         np.testing.assert_allclose(
             np.asarray(W_s), np.asarray(W_ref), rtol=5e-3, atol=5e-4
         )
+
+
+# -- the intercept as a border of the fold (PR 38) ----------------------------
+
+
+def _binary_rows(n, d, w=6, n_pad=0, seed=0):
+    """0/1 rows with +-1 targets, ``n_pad`` rows of junk past the true n."""
+    rng = np.random.default_rng(seed)
+    idx = np.stack([
+        np.sort(rng.choice(d, w, replace=False)) for _ in range(n + n_pad)
+    ]).astype(np.int32)
+    vals = np.ones((n + n_pad, w), np.float32)
+    Y = np.where(rng.normal(size=(n + n_pad, 2)) > 0, 1.0, -1.0)
+    return idx, vals, Y.astype(np.float32)
+
+
+def _row_chunks(n, c, border, d=None):
+    """Chunk function over (n_pad, .) operands, ragged last chunk and rows
+    past ``n`` masked: with ``border`` the ones column is handed over with
+    the targets; without, it is a lane at index ``d`` of the caller's own —
+    what the estimator did before PR 38 and what any caller may still do."""
+    from keystone_tpu.ops.learning.lbfgs import _LanedRowChunks
+
+    source = _LanedRowChunks(c, n)
+    if border:
+        return source
+
+    def laned(cid, indices, values, Y):
+        idx, val, targets = source(cid, indices, values, Y)
+        live = targets[:, -1:]
+        lane = jnp.where(live > 0, d, -1).astype(idx.dtype)
+        return (
+            jnp.concatenate([idx, lane], axis=1),
+            jnp.concatenate([val, jnp.ones_like(live, val.dtype)], axis=1),
+            targets[:, :-1],
+        )
+
+    return laned
+
+
+class TestInterceptBorder:
+    @pytest.mark.parametrize("val_dtype", [jnp.float32, jnp.bfloat16])
+    @pytest.mark.parametrize("pipeline", [False, True])
+    def test_border_is_the_last_row_and_column_of_the_laned_gramian(
+        self, val_dtype, pipeline
+    ):
+        """0/1 rows, ±1 targets: every sum is an integer float32 holds, so
+        the border's pieces ARE the (d + 1)-wide Gramian's last row and
+        column — bit for bit, in both slab types."""
+        import jax
+
+        n, d, c = 700, 96, 256  # 2 x 256 + a ragged 188, 68 junk rows past n
+        ops = tuple(map(jnp.asarray, _binary_rows(n, d, n_pad=68)))
+        nchunks = -(-ops[0].shape[0] // c)
+
+        def fold(border, width):
+            cf = _row_chunks(n, c, border, d)
+            return jax.jit(lambda *o: sparse_gram_stream(
+                lambda cid: cf(cid, *o), nchunks, width, 2,
+                val_dtype=val_dtype, pipeline=pipeline, border=border,
+            ))(*ops)
+
+        G1, AtY1, yty1 = map(np.asarray, fold(False, d + 1))
+        G, AtY, yty, ysum = map(np.asarray, fold(True, d))
+        assert AtY.shape[1] == 3 and ysum.shape == (2,)
+        np.testing.assert_array_equal(G[:d, :d], G1[:d, :d])
+        np.testing.assert_array_equal(AtY[:d, :2], AtY1[:d])
+        np.testing.assert_array_equal(AtY[:d, 2], G1[:d, d])  # s = Xᵀ1
+        np.testing.assert_array_equal(AtY[:d, 2], G1[d, :d])
+        np.testing.assert_array_equal(ysum, AtY1[d])  # 1ᵀY
+        assert G1[d, d] == n and yty == yty1 == 2.0 * n
+        assert np.all(AtY[d:] == 0) and np.all(G[d:] == 0)
+
+    @pytest.mark.parametrize("d", [512, 513, 511])
+    @pytest.mark.parametrize("seg", [None, 2])
+    def test_border_fit_equals_the_laned_fit_through_the_generic_fold(
+        self, d, seg
+    ):
+        """One model, two ways to its normal equations — at a width that
+        is a multiple of the float32 tile (where the lane costs a tile
+        row), one over and one under; a ragged last chunk, rows past n;
+        in one dispatch and segmented (phantom chunk ids past the end)."""
+        n, c = 700, 256
+        idx, vals, Y = _binary_rows(n, d, n_pad=68, seed=d)
+        vals = vals * np.random.default_rng(1).normal(size=vals.shape)
+        ops = (jnp.asarray(idx), jnp.asarray(vals.astype(np.float32)),
+               jnp.asarray(Y))
+        how = dict(lam=1e-1, num_iterations=80, convergence_tol=1e-7, n=n,
+                   operands=ops, max_chunks_per_dispatch=seg)
+        nchunks = -(-idx.shape[0] // c)
+        W_b, loss_b = run_lbfgs_gram_streamed(
+            _row_chunks(n, c, True), nchunks, d, 2, border=True, **how)
+        W_l, loss_l = run_lbfgs_gram_streamed(
+            _row_chunks(n, c, False, d), nchunks, d + 1, 2, **how)
+        assert W_b.shape == W_l.shape == (d + 1, 2)  # the intercept last
+        scale = np.abs(np.asarray(W_l)).max()
+        np.testing.assert_allclose(
+            np.asarray(W_b), np.asarray(W_l), rtol=0, atol=1e-6 * scale)
+        assert float(loss_b) == pytest.approx(float(loss_l), rel=1e-6)
+
+    def test_mesh_fold_reduces_the_border_with_the_rest(self):
+        """The border's pieces are psum'd with G and AᵀY: the mesh fit is
+        the one-device fit."""
+        import jax
+
+        from keystone_tpu.parallel.mesh import make_mesh
+
+        n, d, c = 1024, 64, 128
+        idx, vals, Y = _binary_rows(n, d)
+        ops = tuple(a.reshape(n // c, c, -1) for a in (
+            idx, vals, np.concatenate([Y, np.ones((n, 1), np.float32)], 1)))
+
+        def cf(cid, it, vt, yt):
+            return it[cid], vt[cid], yt[cid]
+
+        how = dict(lam=1e-3, num_iterations=30, n=n, border=True)
+        W1, _ = run_lbfgs_gram_streamed(
+            cf, n // c, d, 2, operands=tuple(map(jnp.asarray, ops)), **how)
+        W4, _ = run_lbfgs_gram_streamed(
+            cf, n // c, d, 2, operands=ops,
+            mesh=make_mesh((4,), ("data",), jax.devices()[:4]), **how)
+        assert W4.shape == (d + 1, 2)
+        np.testing.assert_allclose(
+            np.asarray(W4), np.asarray(W1), rtol=1e-5, atol=1e-6)
+
+    def test_a_checkpoint_a_column_wider_is_refused_by_shape(
+        self, tmp_path, monkeypatch
+    ):
+        """A snapshot written by a fit that laned its intercept holds a
+        (d + 1)-wide carry: refused, never reinterpreted."""
+        from keystone_tpu.data.durable import CheckpointSpec
+        from keystone_tpu.ops.sparse import sparse_gram_init
+
+        n, d, c = 1024, 600, 256  # d + 1 pads like d here: only G's width tells
+        ops = tuple(map(jnp.asarray, _binary_rows(n, d)))
+        wide = [np.asarray(a) for a in sparse_gram_init(d + 1025, 2)]
+        monkeypatch.setattr(
+            CheckpointSpec, "restore", lambda self, fingerprint: (wide, 1))
+        with pytest.raises(ValueError, match="discard the checkpoint"):
+            run_lbfgs_gram_streamed(
+                _row_chunks(n, c, True), n // c, d, 2, n=n, border=True,
+                operands=ops, max_chunks_per_dispatch=2,
+                checkpoint=CheckpointSpec(str(tmp_path / "ck")),
+            )
+        # ... and so is a laned fit's three-piece carry of the SAME width
+        laned = [np.asarray(a) for a in sparse_gram_init(d, 2)]
+        monkeypatch.setattr(
+            CheckpointSpec, "restore", lambda self, fingerprint: (laned, 1))
+        with pytest.raises(ValueError, match="discard the checkpoint"):
+            run_lbfgs_gram_streamed(
+                _row_chunks(n, c, True), n // c, d, 2, n=n, border=True,
+                operands=ops, max_chunks_per_dispatch=2,
+                checkpoint=CheckpointSpec(str(tmp_path / "ck")),
+            )
