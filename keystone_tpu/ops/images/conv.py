@@ -65,6 +65,15 @@ def normalize_patch_rows(patches, var_constant: float):
     return centered / jnp.sqrt(var + var_constant)
 
 
+# What a fused featurize program that starts with a convolution may hold
+# for its batch of images at once: the conv map (out_x · out_y · K float32
+# an image) and a two-sided rectified copy of it (twice that), three maps
+# an image. For CIFAR-10 at K = 1,600 a map is 27 · 27 · 1,600 · 4 B =
+# 4.67 MB, so 2 GiB takes 152 images where the whole 50,000 would need
+# 233 GB for the map alone.
+CONV_BATCH_BYTES = 2 << 30
+
+
 class Convolver(Transformer):
     """Convolve images with a filter bank via im2col + one GEMM
     (reference: nodes/images/Convolver.scala:20-221).
@@ -72,7 +81,15 @@ class Convolver(Transformer):
     ``filters`` is ``(num_filters, patch_size²·channels)``, already whitened
     if a whitener is supplied (see :meth:`build`). Output image is
     ``(X-p+1, Y-p+1, num_filters)``.
+
+    In a fused chain (``workflow/fusion.py``) the convolution names the
+    program's scope, ``ks.conv_featurize``, asks it to take
+    :meth:`device_row_batch` images at a time, and counts the images the
+    program takes on the counter track ``conv.images_featurized``.
     """
+
+    device_scope = "ks.conv_featurize"
+    rows_counter = "conv.images_featurized"
 
     def __init__(
         self,
@@ -92,6 +109,16 @@ class Convolver(Transformer):
         self.normalize_patches = normalize_patches
         self.var_constant = var_constant
         self.patch_size = int(round((self.filters.shape[1] / img_channels) ** 0.5))
+
+    def device_row_batch(self) -> Optional[int]:
+        """Images a fused program featurizes at a time: three conv maps an
+        image within :data:`CONV_BATCH_BYTES`, a multiple of 8; None where
+        the image size is not known (:meth:`build`)."""
+        if self.img_x <= 0 or self.img_y <= 0:
+            return None
+        out = (self.img_x - self.patch_size + 1) * (self.img_y - self.patch_size + 1)
+        per_image = 3 * out * self.filters.shape[0] * 4
+        return max(8, CONV_BATCH_BYTES // per_image // 8 * 8)
 
     @staticmethod
     def pack_filters(filter_images) -> jnp.ndarray:

@@ -133,22 +133,24 @@ class BlockLinearMapper(Transformer):
             evaluator(Dataset(preds, n=data.n, mesh=data.mesh)._rezero_padding())
 
 
-# ``jnp.stack`` dispatches a lifting copy a block and one concatenate, eagerly:
-# each a program of its own that takes no name scope from its caller. The
-# concatenate is that program as it was, under the scope ``ks.stack``; the
-# copies are the compiler's own (a lifted block is a bitcast that may not alias
-# its argument) and carry no metadata a scope could reach.
+# An eager ``jnp.stack`` dispatches a lifting copy a block and one
+# concatenate: a full second copy of the blocks beside the stack, in programs
+# that take no name scope from their caller. Stacked inside one program the
+# blocks are read once into the stack, under the scope ``ks.stack``.
 
 
 @jax.jit
-def _joined(*lifted):
-    with jax.named_scope("ks.stack"):
-        return jnp.concatenate(lifted, axis=0)
-
-
-def _stack_blocks(A_blocks):
+def _stack_blocks(*A_blocks):
     """``jnp.stack(A_blocks)``: the blocks as one (blocks, n, width) array."""
-    return _joined(*[jnp.expand_dims(a, 0) for a in A_blocks])
+    with jax.named_scope("ks.stack"):
+        return jnp.stack(A_blocks)
+
+
+@jax.jit
+def _unstacked(W_stack):
+    """The stacked blocks' weights one by one, in one program (an eager
+    ``W_stack[i]`` sends ``i`` from the host)."""
+    return tuple(W_stack[i] for i in range(W_stack.shape[0]))
 
 
 def _stack_fits_memory(A_blocks, num_iter: int) -> bool:
@@ -258,8 +260,13 @@ class BlockLeastSquaresEstimator(LabelEstimator):
             )
 
         multi_device = _is_multi(labels) or any(_is_multi(b) for b in blocks)
+        # A last block narrower than the others (d not a multiple of the
+        # block) rides the same program as a tail after the stack.
+        head, tail = A_blocks, None
+        if len(A_blocks) > 1 and A_blocks[-1].shape[1] < A_blocks[0].shape[1]:
+            head, tail = A_blocks[:-1], A_blocks[-1]
         if (
-            len({a.shape for a in A_blocks}) == 1
+            len({a.shape for a in head}) == 1
             and not multi_device
             and _stack_fits_memory(A_blocks, self.num_iter)
         ):
@@ -269,13 +276,17 @@ class BlockLeastSquaresEstimator(LabelEstimator):
             # cleanly and match the unsharded reduction order); so do fits
             # whose stacked copy would not fit beside the blocks in HBM.
             with obs.span("solver.stack"):
-                stacked = _stack_blocks(A_blocks)
-                del A_blocks  # the stack is a full second copy; drop the list
+                stacked = _stack_blocks(*head)
+                # the stack is a full second copy; drop the lists
+                del A_blocks, head
             with obs.span("solver.bcd", epochs=self.num_iter):
-                W_stack = linalg.bcd_least_squares_fused(
-                    stacked, B, lam=self.lam, num_iter=self.num_iter
+                fitted = linalg.bcd_least_squares_fused(
+                    stacked, B, lam=self.lam, num_iter=self.num_iter, tail=tail
                 )
-                Ws = [W_stack[i] for i in range(W_stack.shape[0])]
+                W_stack, W_tail = (fitted, None) if tail is None else fitted
+                Ws = list(_unstacked(W_stack))
+                if W_tail is not None:
+                    Ws.append(W_tail)
         else:
             mesh = next(
                 (d.mesh for d in [labels, *blocks] if d.mesh is not None), None

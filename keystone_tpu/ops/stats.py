@@ -8,6 +8,7 @@ from them), replacing the reference's implicit global RNG draws.
 
 from __future__ import annotations
 
+import functools
 import weakref
 from dataclasses import dataclass, field
 from typing import Callable
@@ -28,20 +29,39 @@ from keystone_tpu.workflow import Estimator, Transformer
 
 # An eagerly dispatched operation is a program of its own and takes no name
 # scope from its caller, so a device profile cannot say what phase it served.
-# The two below are the scaler's eager operations as they were — one program
-# each — under the scope ``ks.center``.
+# The scaler's operations are the small programs below, under the scope
+# ``ks.center``; the row count is static, so no scalar crosses to the device.
 
 
-@jax.jit
-def _column_sums(X):
+@functools.partial(jax.jit, static_argnames=("n",))
+def _column_means(X, n: int):
     with jax.named_scope("ks.center"):
-        return jnp.sum(X, axis=0)
+        return jnp.sum(X, axis=0) / n
 
 
 @jax.jit
 def _subtract_mean(X, mean):
     with jax.named_scope("ks.center"):
         return X - mean
+
+
+@functools.partial(jax.jit, static_argnames=("n", "eps"))
+def _column_moments(X, n: int, eps: float):
+    """Column means and standard deviations over ``n`` rows, padding rows
+    (zero) aside: sum((x - mean)^2) over real rows = sum(x^2) - n*mean^2,
+    over n - 1; a deviation that is not a number or under ``eps`` reads 1."""
+    with jax.named_scope("ks.center"):
+        mean = jnp.sum(X, axis=0) / n
+        var = (jnp.sum(X * X, axis=0) - n * mean * mean) / max(n - 1, 1)
+        std = jnp.sqrt(jnp.maximum(var, 0.0))
+        return mean, jnp.where(
+            jnp.isnan(std) | jnp.isinf(std) | (jnp.abs(std) < eps), 1.0, std)
+
+
+@jax.jit
+def _standardize(X, mean, std):
+    with jax.named_scope("ks.center"):
+        return (X - mean) / std
 
 
 class StandardScalerModel(Transformer):
@@ -55,10 +75,9 @@ class StandardScalerModel(Transformer):
     def apply(self, x):
         # Traced inside another program (a fused chain) the subtraction is
         # inlined there and reads as ``ks.center`` within that phase.
-        out = _subtract_mean(jnp.asarray(x), self.mean)
         if self.std is not None:
-            out = out / self.std
-        return out
+            return _standardize(jnp.asarray(x), self.mean, self.std)
+        return _subtract_mean(jnp.asarray(x), self.mean)
 
     def batch_apply(self, data: Dataset) -> Dataset:
         return data.map_batch(self.apply)
@@ -74,22 +93,11 @@ class StandardScaler(Estimator):
         self.eps = eps
 
     def fit(self, data: Dataset) -> StandardScalerModel:
-        X = jnp.asarray(data.array)
-        n = data.n
         # Padding rows are zero: sums are exact; divide by the true count.
-        total = _column_sums(X)
-        mean = total / n
+        X = jnp.asarray(data.array)
         if not self.normalize_std_dev:
-            return StandardScalerModel(mean)
-        # Sample variance with the zero-padding correction:
-        # sum((x - mean)^2) over real rows = sum(x^2) - n*mean^2.
-        sumsq = jnp.sum(X * X, axis=0)
-        var = (sumsq - n * mean * mean) / max(n - 1, 1)
-        std = jnp.sqrt(jnp.maximum(var, 0.0))
-        std = jnp.where(
-            jnp.isnan(std) | jnp.isinf(std) | (jnp.abs(std) < self.eps), 1.0, std
-        )
-        return StandardScalerModel(mean, std)
+            return StandardScalerModel(_column_means(X, int(data.n)))
+        return StandardScalerModel(*_column_moments(X, int(data.n), float(self.eps)))
 
 
 # ---------------------------------------------------------------------------

@@ -262,13 +262,14 @@ class Shuffler(Transformer):
         return out.shard(data.mesh) if data.mesh is not None else out
 
 
-@functools.partial(jax.jit, static_argnames=("width",))
-def _column_block(arr, start, width: int):
-    """``arr[:, start:start + width]`` of a device array as the eager slice
-    was — one program for every ``start`` — under a name scope, which an
-    eagerly dispatched operation does not take from its caller."""
+@functools.partial(jax.jit, static_argnames=("bounds",))
+def _column_blocks(arr, bounds):
+    """``arr[:, lo:hi]`` for every ``(lo, hi)`` of ``bounds``, one program
+    for the whole split (static bounds: nothing crosses to the device), under
+    a name scope, which an eagerly dispatched operation does not take from
+    its caller."""
     with jax.named_scope("ks.split"):
-        return jax.lax.dynamic_slice_in_dim(arr, start, width, axis=1)
+        return tuple(arr[:, lo:hi] for lo, hi in bounds)
 
 
 class VectorSplitter(FunctionNode):
@@ -287,14 +288,12 @@ class VectorSplitter(FunctionNode):
     def apply(self, data: Dataset) -> List[Dataset]:
         arr = data.array
         d = self.num_features if self.num_features is not None else int(arr.shape[-1])
-        blocks = []
-        for start in range(0, d, self.block_size):
-            stop = min(start + self.block_size, d)
-            # a host dataset's blocks stay on the host
-            block = (_column_block(arr, start, stop - start)
-                     if isinstance(arr, jax.Array) else arr[:, start:stop])
-            blocks.append(Dataset(block, n=data.n, mesh=data.mesh))
-        return blocks
+        bounds = tuple((start, min(start + self.block_size, d))
+                       for start in range(0, d, self.block_size))
+        # a host dataset's blocks stay on the host
+        cut = (_column_blocks(arr, bounds) if isinstance(arr, jax.Array)
+               else [arr[:, lo:hi] for lo, hi in bounds])
+        return [Dataset(block, n=data.n, mesh=data.mesh) for block in cut]
 
     def split_vector(self, vec):
         """Split a single vector into per-block vectors."""

@@ -333,6 +333,13 @@ def bcd_least_squares(
 # ---------------------------------------------------------------------------
 
 
+@functools.partial(jax.jit, static_argnames=("shape", "dtype"))
+def _zeros(shape, dtype):
+    """Zeros made on the device by a program (an eager ``jnp.zeros`` sends
+    its fill value from the host)."""
+    return jnp.zeros(shape, dtype)
+
+
 # ``lam`` is a TRACED operand: λ-sweeps over one geometry reuse one
 # compiled sweep (it reaches the solves as a numeric jitter only).
 @functools.partial(
@@ -341,7 +348,15 @@ def bcd_least_squares(
 )
 @jax.named_scope("ks.bcd_step")  # the sweeps' own slices of the stacked blocks too
 def _bcd_fused_kernel(A_stack, B, W0, lam, num_iter: int,
-                      use_pallas: bool, sym: bool, cache_stash: bool = True):
+                      use_pallas: bool, sym: bool, cache_stash: bool = True,
+                      tail=None):
+    """The sweep over the stacked blocks and, where ``tail`` (an (n, d_t)
+    block) is given, one narrower last block after them in every epoch
+    (``d`` not a multiple of the block), from W_t = 0. Returns ``(W, R,
+    W_t)``."""
+    if tail is not None:
+        A_t, W_t = tail, jnp.zeros((tail.shape[1], B.shape[1]), B.dtype)
+
     def first_epoch_step(R, xs):
         """First sweep: compute (and, when caching, stash) each block's
         Gramian + Cholesky factor. Single-epoch runs — and models past the
@@ -367,26 +382,35 @@ def _bcd_fused_kernel(A_stack, B, W0, lam, num_iter: int,
         return R, Wb_new
 
     R, (W, grams, chols) = jax.lax.scan(first_epoch_step, B, (A_stack, W0))
+    if tail is not None:
+        R, W_t, gram_t, chol_t = _bcd_block_update(A_t, R, W_t, lam, use_pallas, sym)
+    else:
+        W_t = None
     if num_iter == 1:
-        return W, R
+        return W, R, W_t
 
     if cache_stash:
         def epoch(carry, _):
-            R, W = carry
+            R, W, W_t = carry
             R, W = jax.lax.scan(
                 later_epoch_step, R, (A_stack, W, grams, chols)
             )
-            return (R, W), None
+            if tail is not None:
+                R, W_t, _, _ = _bcd_block_update(
+                    A_t, R, W_t, lam, use_pallas, sym, gram=gram_t, chol=chol_t)
+            return (R, W, W_t), None
     else:
         # Over-budget stash: later epochs recompute Gramian + factor
         # (rematerialization economics — the same policy as the flat path).
         def epoch(carry, _):
-            R, W = carry
+            R, W, W_t = carry
             R, (W, _, _) = jax.lax.scan(first_epoch_step, R, (A_stack, W))
-            return (R, W), None
+            if tail is not None:
+                R, W_t, _, _ = _bcd_block_update(A_t, R, W_t, lam, use_pallas, sym)
+            return (R, W, W_t), None
 
-    (R, W), _ = jax.lax.scan(epoch, (R, W), None, length=num_iter - 1)
-    return W, R
+    (R, W, W_t), _ = jax.lax.scan(epoch, (R, W, W_t), None, length=num_iter - 1)
+    return W, R, W_t
 
 
 def _residual_dtype(feat_dtype, label_dtype):
@@ -613,8 +637,14 @@ def bcd_least_squares_fused(
     W_init=None,
     use_pallas: Optional[bool] = None,
     return_residual: bool = False,
+    tail=None,
 ):
     """Single-dispatch block coordinate descent over equal-sized blocks.
+
+    ``tail``: an (n, d_t) last block narrower than the stacked ones (the
+    feature count not a multiple of the block), swept after them in every
+    epoch inside the same program; its weights then come back as well:
+    ``(W, W_t)`` or ``(W, W_t, R)``.
 
     A_stack: (num_blocks, n, d_b) stacked feature blocks — may be bfloat16,
     in which case GEMMs run natively on the MXU with float32 accumulation
@@ -646,7 +676,7 @@ def bcd_least_squares_fused(
     W0 = (
         jnp.asarray(W_init, dtype=B.dtype)
         if W_init is not None
-        else jnp.zeros((nb, db, k), dtype=B.dtype)
+        else _zeros((nb, db, k), B.dtype)
     )
     if W_init is not None:
         # A_stack is already unified with B's dtype (bf16 features upcast
@@ -659,16 +689,25 @@ def bcd_least_squares_fused(
             for i in range(nb)
         )
     acc_itemsize = jnp.promote_types(A_stack.dtype, jnp.float32).itemsize
+    d_t = 0
+    if tail is not None:
+        if W_init is not None:
+            raise ValueError("a tail block starts from W = 0: no W_init with it")
+        tail = jnp.asarray(tail).astype(A_stack.dtype)
+        d_t = tail.shape[1]
     # x2: the stash holds Gramians AND their Cholesky factors (same budget
     # policy as the flat path).
     cache_stash = _gram_cache_ok(
-        int(num_iter), 2 * nb * db * db * acc_itemsize
+        int(num_iter), 2 * (nb * db * db + d_t * d_t) * acc_itemsize
     )
-    W, R = _bcd_fused_kernel(
+    W, R, W_t = _bcd_fused_kernel(
         A_stack, B, W0, lam, max(int(num_iter), 1),
-        bool(use_pallas), True, cache_stash,
+        bool(use_pallas), True, cache_stash, tail=tail,
     )
-    return (W, R) if return_residual else W
+    out = (W,) if tail is None else (W, W_t)
+    if return_residual:
+        out += (R,)
+    return out if len(out) > 1 else W
 
 
 # ---------------------------------------------------------------------------

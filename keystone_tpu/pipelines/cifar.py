@@ -16,26 +16,37 @@
 from __future__ import annotations
 
 import argparse
+import functools
 import logging
 import time
 from dataclasses import dataclass
+from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
-from keystone_tpu.data import Dataset, LabeledData
+from keystone_tpu import obs
+from keystone_tpu.data import Dataset
 from keystone_tpu.data.loaders import load_cifar_binary, synthetic_cifar
 from keystone_tpu.evaluation import (
     AugmentedExamplesEvaluator,
     MulticlassClassifierEvaluator,
 )
-from keystone_tpu.ops.images.conv import Convolver, Pooler, SymmetricRectifier
+from keystone_tpu.ops.images.conv import (
+    Convolver,
+    Pooler,
+    SymmetricRectifier,
+    normalize_patch_rows,
+)
 from keystone_tpu.ops.images.core import (
     CenterCornerPatcher,
     GrayScaler,
     ImageVectorizer,
     PixelScaler,
     RandomPatcher,
+    gather_patches,
+    patch_positions,
 )
 from keystone_tpu.ops.learning.block import BlockLeastSquaresEstimator
 from keystone_tpu.ops.learning.kernel import (
@@ -43,13 +54,14 @@ from keystone_tpu.ops.learning.kernel import (
     KernelRidgeRegression,
 )
 from keystone_tpu.ops.learning.linear import LinearMapEstimator
-from keystone_tpu.ops.learning.pca import ZCAWhitenerEstimator
+from keystone_tpu.ops.learning.pca import ZCAWhitener, ZCAWhitenerEstimator
 from keystone_tpu.ops.stats import StandardScaler
 from keystone_tpu.ops.util import (
     Cacher,
     ClassLabelIndicatorsFromIntLabels,
     MaxClassifier,
 )
+from keystone_tpu.utils.profiling import follow_profiler
 from keystone_tpu.workflow import Pipeline
 
 logger = logging.getLogger("keystone_tpu.pipelines.cifar")
@@ -99,41 +111,79 @@ def _load(config: CifarConfig):
     return train, test, True
 
 
-def _sample_whitened_filters(train: LabeledData, config: CifarConfig):
+PATCH_VAR_CONSTANT = 10.0  # Stats.normalizeRows(_, 10.0) in the Scala pipeline
+ZCA_EPS = 0.1
+
+
+@functools.partial(jax.jit, static_argnames=("n", "seed", "count", "num_filters",
+                                             "patch_size"))
+def _draw_whitened_filters(images, *, n: int, seed: int, count: int, num_filters: int,
+                           patch_size: int):
+    """The filter draw as one program (scope ``ks.patch_whiten``): patch
+    positions from ``jax.random.key(seed)``, only those patches gathered, rows normalized,
+    ZCA fitted on them, the first ``num_filters`` whitened, renormalized
+    and mapped back through the whitener. Returns (filters, whitener
+    matrix, whitener means)."""
+    with jax.named_scope("ks.patch_whiten"):
+        _, X, Y, _ = images.shape
+        k_img, k_pos = jax.random.split(jax.random.key(seed))
+        img = jax.random.randint(k_img, (count,), 0, n)
+        sx, sy = patch_positions(k_pos, (count,), X, Y, patch_size, patch_size)
+        patches = gather_patches(images, img, sx, sy, patch_size, patch_size)
+        patches = normalize_patch_rows(
+            patches.reshape(count, -1).astype(jnp.float32), PATCH_VAR_CONSTANT)
+        zca = ZCAWhitenerEstimator(eps=ZCA_EPS).fit_single(patches)
+        sampled = zca.apply(patches[:num_filters])
+        norms = jnp.sqrt(jnp.sum(sampled * sampled, axis=1, keepdims=True))
+        return (sampled / (norms + 1e-10)) @ zca.whitener.T, zca.whitener, zca.means
+
+
+def sample_whitened_filters(images: Dataset, config: CifarConfig):
     """Random training patches, row-normalized, ZCA-whitened, subsampled to a
-    conv filter bank (RandomPatchCifar.scala:36-58)."""
-    images = np.asarray(train.data.array, dtype=np.float64)[: train.data.n]
-    per_image = max(1, config.whitener_size // images.shape[0] + 1)
-    patcher = RandomPatcher(
-        per_image, config.patch_size, config.patch_size, seed=config.seed + 7
-    )
-    patches = np.asarray(patcher.batch_apply(train.data).array)
-    patches = patches.reshape(patches.shape[0], -1)[: config.whitener_size]
-    # Row normalization with the reference's variance floor (Stats.normalizeRows)
-    norms = np.sqrt(np.maximum(np.var(patches, axis=1) * patches.shape[1], 10.0))
-    patches = (patches - patches.mean(axis=1, keepdims=True)) / norms[:, None]
-    whitener = ZCAWhitenerEstimator(eps=0.1).fit_single(jnp.asarray(patches))
-    rng = np.random.default_rng(config.seed + 13)
-    idx = rng.choice(patches.shape[0], size=config.num_filters, replace=False)
-    sampled = np.array(whitener.apply(jnp.asarray(patches[idx])))
-    # Renormalize whitened filters (RandomPatchCifar.scala:52-57).
-    sampled /= np.linalg.norm(sampled, axis=1, keepdims=True) + 1e-12
-    filters = sampled.reshape(
-        config.num_filters, config.patch_size, config.patch_size, 3
-    )
-    return filters, whitener
+    conv filter bank (RandomPatchCifar.scala:36-58), on the device: returns
+    ``(filters (num_filters, patch²·C), ZCAWhitener)``.
+
+    THE draw contract (a reference restates it): ``k_img, k_pos =
+    jax.random.split(jax.random.key(config.seed))``; ``whitener_size``
+    patches, patch i from image ``randint(k_img, (whitener_size,), 0, n)[i]``
+    at ``patch_positions(k_pos, (whitener_size,), ...)``; each row minus its
+    mean over ``sqrt(var + 10)`` (variance over d - 1); ZCA with ε = 0.1 on
+    those rows; the filters are the FIRST ``num_filters`` rows whitened,
+    each over its norm + 1e-10, times the whitener's transpose (the Scala
+    pipeline samples its filter rows at random from the whitener's sample,
+    which is itself a uniform draw). Only the drawn patches are read."""
+    if config.whitener_size < config.num_filters:
+        raise ValueError(f"whitener_size {config.whitener_size} < num_filters "
+                         f"{config.num_filters}: the filters are rows of its sample")
+    filters, W, means = _draw_whitened_filters(
+        images.array, n=images.n, seed=config.seed, count=config.whitener_size, num_filters=config.num_filters,
+        patch_size=config.patch_size)
+    return filters, ZCAWhitener(W, means)
 
 
-def _conv_featurizer(filters, whitener, config: CifarConfig) -> Pipeline:
-    """Convolver → SymmetricRectifier → Pooler(sum) → vectorize."""
-    conv = Convolver(
+def pooled_features(config: CifarConfig, image_size: int = 32) -> int:
+    """Features a ``image_size``² image gives: filters × 2 (the two-sided
+    rectifier) × the pools ``Pooler(pool_stride, pool_size)`` lays on the
+    conv map in each axis."""
+    side = image_size - config.patch_size + 1
+    pools = -(-(side - config.pool_size // 2) // config.pool_stride)
+    return config.num_filters * 2 * pools * pools
+
+
+def _convolver(filters, whitener, image_size: int = 32) -> Convolver:
+    return Convolver(
         jnp.asarray(filters, jnp.float32).reshape(len(filters), -1),
-        img_x=32,
-        img_y=32,
+        img_x=image_size,
+        img_y=image_size,
         img_channels=3,
         whitener=whitener,
         normalize_patches=True,
+        var_constant=PATCH_VAR_CONSTANT,
     )
+
+
+def _conv_featurizer(conv: Convolver, config: CifarConfig) -> Pipeline:
+    """Convolver → SymmetricRectifier → Pooler(sum) → vectorize."""
     return (
         conv.to_pipeline()
         .and_then(SymmetricRectifier(alpha=config.alpha))
@@ -143,6 +193,30 @@ def _conv_featurizer(filters, whitener, config: CifarConfig) -> Pipeline:
         .and_then(ImageVectorizer())
         .and_then(Cacher())
     )
+
+
+def build_random_patch(config: CifarConfig, images: Dataset, labels: Dataset,
+                       lam: Optional[float] = None) -> Pipeline:
+    """RandomPatchCifar's fit graph over arrays on the device
+    (RandomPatchCifar.scala:21-86): the filters drawn from ``images``
+    (:func:`sample_whitened_filters`, every build draws them, as every
+    Scala run does), the conv featurizer, ``StandardScaler``, and
+    ``BlockLeastSquaresEstimator(block_size, 1, λ)`` — the scores, no
+    classifier. ``labels``: the ±1 class indicators. ``lam``: the
+    config's where None."""
+    follow_profiler()
+    with obs.span("pipeline.build", entry="random_patch",
+                  filters=config.num_filters,
+                  patches_sampled=config.whitener_size) as span:
+        filters, whitener = sample_whitened_filters(images, config)
+        image_size = int(images.array.shape[1])
+        conv = _convolver(filters, whitener, image_size)
+        span.set(features=pooled_features(config, image_size),
+                 image_batch=conv.device_row_batch())
+        return _conv_featurizer(conv, config).and_then(StandardScaler(), images).and_then(
+            BlockLeastSquaresEstimator(
+                config.block_size, 1, config.lam if lam is None else lam),
+            images, labels)
 
 
 def run_linear_pixels(config: CifarConfig):
@@ -184,7 +258,7 @@ def run_random_cifar(config: CifarConfig):
     ]
     labels = ClassLabelIndicatorsFromIntLabels(NUM_CLASSES)(train.labels)
     pipeline = (
-        _conv_featurizer(filters, None, config)
+        _conv_featurizer(_convolver(filters, None), config)
         .and_then(StandardScaler(), train.data)
         .and_then(
             BlockLeastSquaresEstimator(config.block_size, 1, config.lam),
@@ -205,25 +279,23 @@ def run_random_cifar(config: CifarConfig):
     return pipeline, train_eval, test_eval
 
 
+def _on_device(data: Dataset) -> Dataset:
+    """Loaded images as one float32 array on the device: moved once, read
+    by the filter draw, the featurizer and the scaler."""
+    return Dataset(jnp.asarray(data.array, jnp.float32), n=data.n)
+
+
 def run_random_patch_cifar(config: CifarConfig):
     """Whitened random-patch filters + block least squares
-    (RandomPatchCifar.scala:21-86)."""
+    (RandomPatchCifar.scala:21-86): :func:`build_random_patch` and a
+    classifier on its scores."""
     start = time.time()
     train, test, _ = _load(config)
-    filters, whitener = _sample_whitened_filters(train, config)
+    images = _on_device(train.data)
     labels = ClassLabelIndicatorsFromIntLabels(NUM_CLASSES)(train.labels)
-    pipeline = (
-        _conv_featurizer(filters, whitener, config)
-        .and_then(StandardScaler(), train.data)
-        .and_then(
-            BlockLeastSquaresEstimator(config.block_size, 1, config.lam),
-            train.data,
-            labels,
-        )
-        .and_then(MaxClassifier())
-    )
+    pipeline = build_random_patch(config, images, labels).and_then(MaxClassifier())
     evaluator = MulticlassClassifierEvaluator(NUM_CLASSES)
-    train_eval = evaluator.evaluate(pipeline.apply(train.data), train.labels)
+    train_eval = evaluator.evaluate(pipeline.apply(images), train.labels)
     test_eval = evaluator.evaluate(pipeline.apply(test.data), test.labels)
     logger.info(
         "RandomPatchCifar train %.2f%% test %.2f%% (%.1fs)",
@@ -239,10 +311,11 @@ def run_random_patch_cifar_kernel(config: CifarConfig):
     (RandomPatchCifarKernel.scala:33-76)."""
     start = time.time()
     train, test, _ = _load(config)
-    filters, whitener = _sample_whitened_filters(train, config)
+    images = _on_device(train.data)
+    filters, whitener = sample_whitened_filters(images, config)
     labels = ClassLabelIndicatorsFromIntLabels(NUM_CLASSES)(train.labels)
-    featurizer = _conv_featurizer(filters, whitener, config).and_then(
-        StandardScaler(), train.data
+    featurizer = _conv_featurizer(_convolver(filters, whitener), config).and_then(
+        StandardScaler(), images
     )
     pipeline = featurizer.and_then(
         KernelRidgeRegression(
@@ -253,11 +326,11 @@ def run_random_patch_cifar_kernel(config: CifarConfig):
             checkpoint_path=config.checkpoint_path or None,
             checkpoint_every_blocks=config.checkpoint_every_blocks,
         ),
-        train.data,
+        images,
         labels,
     ).and_then(MaxClassifier())
     evaluator = MulticlassClassifierEvaluator(NUM_CLASSES)
-    train_eval = evaluator.evaluate(pipeline.apply(train.data), train.labels)
+    train_eval = evaluator.evaluate(pipeline.apply(images), train.labels)
     test_eval = evaluator.evaluate(pipeline.apply(test.data), test.labels)
     logger.info(
         "RandomPatchCifarKernel train %.2f%% test %.2f%% (%.1fs)",
@@ -291,28 +364,12 @@ def run_random_patch_cifar_augmented(config: CifarConfig):
     per_image = test_patcher.patches_per_image
     test_names = list(np.repeat(np.arange(n_test), per_image))
 
-    filters, whitener = _sample_whitened_filters(
-        LabeledData(np.asarray(train_images.array), train_label_ints), config
-    )
+    filters, whitener = sample_whitened_filters(train_images, config)
     labels = ClassLabelIndicatorsFromIntLabels(NUM_CLASSES)(
         Dataset.of(train_label_ints)
     )
-
-    conv = Convolver(
-        jnp.asarray(filters, jnp.float32).reshape(len(filters), -1),
-        img_x=aug,
-        img_y=aug,
-        img_channels=3,
-        whitener=whitener,
-        normalize_patches=True,
-    )
-    featurizer = (
-        conv.to_pipeline()
-        .and_then(SymmetricRectifier(alpha=config.alpha))
-        .and_then(Pooler(config.pool_stride, config.pool_size, pool_function="sum"))
-        .and_then(ImageVectorizer())
-        .and_then(Cacher())
-        .and_then(StandardScaler(), train_images)
+    featurizer = _conv_featurizer(_convolver(filters, whitener, aug), config).and_then(
+        StandardScaler(), train_images
     )
     # Keep raw scores (no MaxClassifier) so the evaluator can vote.
     pipeline = featurizer.and_then(

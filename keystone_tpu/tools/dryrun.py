@@ -137,17 +137,15 @@ def _cifar_krr() -> Pipeline:
         NUM_CLASSES,
         CifarConfig,
         _conv_featurizer,
-        _sample_whitened_filters,
+        _convolver,
+        sample_whitened_filters,
     )
 
     config = CifarConfig(synthetic_n=32, num_filters=8, whitener_size=64)
     train = synthetic_cifar(config.synthetic_n, seed=0)
-    from keystone_tpu.data import LabeledData
-
-    labeled = LabeledData(train.data, train.labels)
-    filters, whitener = _sample_whitened_filters(labeled, config)
+    filters, whitener = sample_whitened_filters(train.data, config)
     labels = ClassLabelIndicatorsFromIntLabels(NUM_CLASSES)(train.labels)
-    featurizer = _conv_featurizer(filters, whitener, config).and_then(
+    featurizer = _conv_featurizer(_convolver(filters, whitener), config).and_then(
         StandardScaler(), train.data
     )
     return featurizer.and_then(

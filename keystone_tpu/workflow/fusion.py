@@ -274,12 +274,54 @@ def _compose_form(branches, combiner=None) -> Tuple[Callable, tuple, tuple]:
     )
 
 
+def _row_batch(members) -> Optional[int]:
+    """The fewest rows a member asks a fused program to take at a time
+    (``device_row_batch()``: a featurizer whose intermediates are many times
+    its output, the convolution's map), or None: all rows at once."""
+    asks = [m.device_row_batch() for m in members
+            if callable(getattr(m, "device_row_batch", None))]
+    asks = [b for b in asks if b]
+    return min(asks) if asks else None
+
+
+def _scope(members) -> str:
+    """The name scope of a fused program: the first member's
+    ``device_scope`` where one names it, else ``ks.featurize``."""
+    return next((m.device_scope for m in members
+                 if getattr(m, "device_scope", None)), "ks.featurize")
+
+
+def in_row_batches(fn: Callable, X, rows: Optional[int]):
+    """``fn(X)`` for a row-local ``fn``, ``rows`` rows at a time inside the
+    calling program: one loop whose every step writes its rows into the
+    output in place, so no intermediate of ``fn`` exists for more than
+    ``rows`` rows. The last step starts at ``n - rows`` and writes again,
+    with the same values, rows an earlier step wrote (row-local: a row's
+    output does not depend on its neighbours)."""
+    n = X.shape[0]
+    if rows is None or n <= rows:
+        return fn(X)
+    import jax.numpy as jnp
+
+    out = jax.eval_shape(fn, jax.ShapeDtypeStruct((rows,) + X.shape[1:], X.dtype))
+
+    def step(i, acc):
+        start = jnp.minimum(i * rows, n - rows)
+        block = jax.lax.dynamic_slice_in_dim(X, start, rows)
+        return jax.lax.dynamic_update_slice_in_dim(acc, fn(block), start, axis=0)
+
+    return jax.lax.fori_loop(0, -(-n // rows), step,
+                             jnp.zeros((n,) + out.shape[1:], out.dtype))
+
+
 class _FusedTransformer(Transformer):
     """What the two fused transformers share: the fused program in operand
     form — ``_program_form()`` -> ``(apply, static_key, params)`` with
     ``apply(static_key, params, X)`` a module-level function — kept in the
     table by ``(apply, static_key)`` and offered as this node's own
-    operand form."""
+    operand form. Members may ask for the program's name scope
+    (``device_scope``) and for the rows it takes at a time
+    (``device_row_batch``, :func:`in_row_batches`)."""
 
     def _program_form(self) -> Tuple[Callable, tuple, tuple]:
         raise NotImplementedError
@@ -287,16 +329,20 @@ class _FusedTransformer(Transformer):
     def _build_composed(self) -> None:
         apply, static_key, params = self._program_form()
         self._operands = ((apply, static_key), params)
+        members = fused_members(self)
+        rows, scope = _row_batch(members), _scope(members)
 
         def build():
             def composed(params, X):
                 # names the phase in a device profile (trace-time only)
-                with jax.named_scope("ks.featurize"):
-                    return apply(static_key, params, X)
+                with jax.named_scope(scope):
+                    return in_row_batches(
+                        functools.partial(apply, static_key, params), X, rows)
 
             return jax.jit(composed)
 
-        program, self._how = _kept_program((apply, static_key), build)
+        program, self._how = _kept_program((apply, static_key, rows, scope), build)
+        self._program = program  # jitted ``program(params, X)``: lower it to read it
         self._composed = lambda X: program(params, X)
 
     @property
@@ -312,7 +358,7 @@ class _FusedTransformer(Transformer):
     # members and rebuild the composition on load.
     def __getstate__(self):
         state = self.__dict__.copy()
-        for derived in ("_composed", "_operands", "_how"):
+        for derived in ("_composed", "_operands", "_how", "_program"):
             state.pop(derived, None)
         return state
 
@@ -361,6 +407,14 @@ class FusedBatchTransformer(_FusedTransformer):
             for m in self.members:
                 data = m.batch_apply(data)
             return data
+        # a member may count the rows its program takes (``rows_counter``)
+        counted = [m.rows_counter for m in self.members
+                   if getattr(m, "rows_counter", None)]
+        if counted:
+            from keystone_tpu import obs
+
+            for name in counted:
+                obs.counter_track(name, data.n)
         return data.map_batch(self._composed)
 
 

@@ -454,14 +454,55 @@ def _eval_shape(fn, spec):
         raise
 
 
+# Output shapes of operand-form nodes by what decides them: the node's
+# ``device_apply``, its static key, the shapes and dtypes of its arrays and
+# of the input. A sweep builds new nodes with new arrays of the same shapes
+# in every fit, and their checks then trace nothing again. Shapes only: no
+# array is held.
+_SHAPES: dict = {}
+_SHAPES_MAX = 256
+
+
+def _shape_key(fn, spec):
+    """The memo key of ``fn``'s output shape on ``spec``: ``fn`` as
+    ``Transformer.device_fn`` makes it (``device_apply`` bound to the static
+    key and the arrays), or None for any other function or a key that
+    cannot be hashed."""
+    import functools
+
+    import jax
+
+    if not isinstance(fn, functools.partial) or len(fn.args) != 2 or fn.keywords:
+        return None
+    static_key, params = fn.args
+    leaves, tree = jax.tree_util.tree_flatten(params)
+    key = (fn.func, static_key, tree,
+           tuple((np.shape(a), str(getattr(a, "dtype", type(a)))) for a in leaves),
+           spec.shape, str(spec.dtype), getattr(spec, "sharding", None))
+    try:
+        hash(key)
+    except TypeError:
+        return None
+    return key
+
+
 def _eval_device_fn(fn, sig: ArraySig):
     """jax.eval_shape the operator's batched function on the incoming
-    signature. Returns (result_struct, None) or (None, error_message)."""
+    signature (once for every node of equal operand shapes, :data:`_SHAPES`).
+    Returns (result_struct, None) or (None, error_message)."""
+    spec = _spec_for(sig)
+    key = _shape_key(fn, spec)
+    if key is not None and key in _SHAPES:
+        return _SHAPES[key], None
     try:
-        res = _eval_shape(fn, _spec_for(sig))
+        res = _eval_shape(fn, spec)
     except Exception as e:  # noqa: BLE001 — any trace failure is the finding
         msg = str(e).strip().split("\n")[0]
         return None, (msg[:300] or type(e).__name__)
+    if key is not None:
+        if len(_SHAPES) >= _SHAPES_MAX:
+            _SHAPES.pop(next(iter(_SHAPES)))
+        _SHAPES[key] = res
     return res, None
 
 
