@@ -10,9 +10,8 @@ full width of the widest configuration this code has run on a chip:
             prints platform, device kind and count, the jax / jaxlib /
             libtpu versions, the compile-cache directory in use and which
             host data plane (native C++ or NumPy) is serving.
-  kernels   the roll-call: each Pallas kernel the package dispatches (11;
-            the twelfth, ``pallas_images.conv_featurize``, is refused by
-            Mosaic and is not dispatched — ROADMAP S7) compiled by Mosaic
+  kernels   the roll-call: each Pallas kernel the package dispatches (12)
+            compiled by Mosaic
             (``interpret=False`` passed explicitly) at the tile shape its
             production caller uses, against the plain ``jax.numpy``
             expression beside it.
@@ -116,8 +115,9 @@ class KernelSizes:
     KRR at d=2048 (256x256x512 tiles), TIMIT blocks of 4,096 (512-wide f32
     and 1,024-wide bf16 column tiles, 512-row k tiles — the 48/64 MB
     ``vmem_limit_bytes`` requests), 147 classes (lane-padded to 256),
-    and the Amazon sketch chunk (256-row x 82-nnz tiles into 512x256
-    output tiles)."""
+    the Amazon sketch chunk (256-row x 82-nnz tiles into 512x256
+    output tiles), and one grid step of the image featurize (128 CIFAR
+    images, 6x6 patches, 1,600 filters, 2x2 pools of 14 every 13)."""
 
     rows: int = 2_048
     krr_dim: int = 2_048
@@ -130,6 +130,7 @@ class KernelSizes:
     sketch_nnz: int = 82
     sketch_m: int = 1_024
     sketch_d1: int = 641
+    conv_filters: int = 1_600
 
 
 class CheckFailed(AssertionError):
@@ -249,18 +250,6 @@ def phase_device() -> Dict[str, Any]:
 # ---------------------------------------------------------------------------
 
 _HI = jax.lax.Precision.HIGHEST
-
-# Kernels the package holds but does not dispatch, and why. The roll-call
-# does not compile them: a refusal would fail the smoke, and this one's
-# compilation does not come back in minutes.
-NOT_DISPATCHED = {
-    "conv_featurize": (
-        "Mosaic refuses it at CIFAR geometry (scoped VMEM 88.62M vs the "
-        "16.00M limit; with the limit raised to 100M compilation had not "
-        "finished after 470 s) — Convolver takes the XLA path, ROADMAP S7"
-    ),
-}
-
 
 def _mirror(G):
     """Both triangles from a buffer whose upper triangle is the valid one
@@ -410,6 +399,26 @@ def kernel_cases(sz: KernelSizes) -> List[Tuple[str, Callable[[bool], Tuple]]]:
         sketch_ref(), 1e-4,
     )))
 
+    # -- the image featurize (RandomPatchCifar: one grid step of 128 images) -
+    from keystone_tpu.ops.images.conv import Convolver, Pooler, SymmetricRectifier
+    from keystone_tpu.ops.pallas_images import LANES, conv_pool_features
+
+    images = jnp.asarray(rng.uniform(0, 255, size=(LANES, 32, 32, 3)), jnp.float32)
+    filters = normal(sz.conv_filters, 108)
+    means = normal(108)
+
+    def conv_pool_ref():
+        out = Convolver.device_apply((6, True, 10.0), (filters, means), images)
+        out = SymmetricRectifier.device_apply((0.0, 0.25), (), out)
+        out = Pooler.device_apply((13, 14, None, "sum"), (), out)
+        return out.reshape(LANES, -1)
+
+    cases.append(("conv_pool", lambda interpret: (
+        conv_pool_features(images, filters, means, patch_size=6, stride=13,
+                           pool_size=14, alpha=0.25, interpret=interpret),
+        conv_pool_ref(), 1e-4,
+    )))
+
     return cases
 
 
@@ -462,11 +471,8 @@ def phase_kernels(
               flush=True)
         if not entry["ok"]:
             print(f"kernels: {name}: {entry['error']}")
-    for name, why in NOT_DISPATCHED.items():
-        print(f"kernels: {name}: not dispatched — {why}")
     failed = [n for n, r in results.items() if not r["ok"]]
-    return {"ok": not failed, "failed": failed, "kernels": results,
-            "not_dispatched": NOT_DISPATCHED}
+    return {"ok": not failed, "failed": failed, "kernels": results}
 
 
 # ---------------------------------------------------------------------------

@@ -12,10 +12,18 @@ Layout note: the reference flattens patches/filters channel-fastest with its
 second spatial axis slowest (Convolver.scala:152-190). We flatten row-major
 over ``(x, y, c)`` — self-consistent between ``pack_filters`` and the patch
 extractor, and the natural order for XLA.
+
+In a fused chain a ``Convolver`` followed by a ``SymmetricRectifier``, a
+sum ``Pooler`` and optionally an ``ImageVectorizer`` runs as ONE Pallas
+kernel instead (:class:`PooledConvolution`, ``ops/pallas_images.py``): the
+conv map and the normalised patch matrix never leave VMEM. The fused
+program learns it from :meth:`Convolver.device_absorb`
+(``workflow/fusion.absorbed``); every other chain keeps the XLA program.
 """
 
 from __future__ import annotations
 
+import functools
 from typing import Callable, Optional, Union
 
 import jax
@@ -65,6 +73,17 @@ def normalize_patch_rows(patches, var_constant: float):
     return centered / jnp.sqrt(var + var_constant)
 
 
+def conv_form(members) -> str:
+    """How a fused program runs the convolution among ``members`` (a chain
+    in order): ``"pallas_pool"`` where it takes its successors into the
+    Pallas kernel (:meth:`Convolver.device_absorb`, through
+    ``workflow/fusion.absorbed``), else ``"xla"``."""
+    from keystone_tpu.workflow.fusion import absorbed
+
+    runs = absorbed(list(members))
+    return "pallas_pool" if any(isinstance(m, PooledConvolution) for m in runs) else "xla"
+
+
 # What a fused featurize program that starts with a convolution may hold
 # for its batch of images at once: the conv map (out_x · out_y · K float32
 # an image) and a two-sided rectified copy of it (twice that), three maps
@@ -85,7 +104,9 @@ class Convolver(Transformer):
     In a fused chain (``workflow/fusion.py``) the convolution names the
     program's scope, ``ks.conv_featurize``, asks it to take
     :meth:`device_row_batch` images at a time, and counts the images the
-    program takes on the counter track ``conv.images_featurized``.
+    program takes on the counter track ``conv.images_featurized``. Where
+    the members after it are a rectifier and sum pools the kernel takes,
+    it offers to run them with it (:meth:`device_absorb`).
     """
 
     device_scope = "ks.conv_featurize"
@@ -119,6 +140,13 @@ class Convolver(Transformer):
         out = (self.img_x - self.patch_size + 1) * (self.img_y - self.patch_size + 1)
         per_image = 3 * out * self.filters.shape[0] * 4
         return max(8, CONV_BATCH_BYTES // per_image // 8 * 8)
+
+    def device_absorb(self, successors) -> Optional[tuple]:
+        """What a fused program may run with this convolution as one Pallas
+        kernel: ``(how many of the members after it, the member that runs
+        them all)`` (:class:`PooledConvolution`), or None — the XLA
+        program. See :meth:`PooledConvolution.absorbing`."""
+        return PooledConvolution.absorbing(self, successors)
 
     @staticmethod
     def pack_filters(filter_images) -> jnp.ndarray:
@@ -187,9 +215,9 @@ class Convolver(Transformer):
         # einsum's preferred_element_type alone would otherwise leave the
         # patch normalization running in f64.
         images = jnp.asarray(X, jnp.float32)
-        # The XLA path is the stated path: Mosaic refuses the fused Pallas
-        # form (ops/pallas_images.py — chip run, PR 21), so it is not
-        # dispatched from here.
+        # A convolution alone takes this XLA path; followed by a rectifier
+        # and sum pools in a fused chain it runs as PooledConvolution's
+        # kernel instead.
         patches = im2col(images, patch_size)
         if normalize_patches:
             patches = normalize_patch_rows(patches, var_constant)
@@ -322,3 +350,104 @@ class SymmetricRectifier(Transformer):
         pos = jnp.maximum(max_val, X - alpha)
         neg = jnp.maximum(max_val, -X - alpha)
         return jnp.concatenate([pos, neg], axis=-1)
+
+
+class PooledConvolution(Transformer):
+    """``Convolver`` → ``SymmetricRectifier`` → ``Pooler`` (sum, no pixel
+    function) → optionally ``ImageVectorizer``, run by ONE Pallas kernel
+    per image batch (``pallas_images.conv_pool_features``): the windows,
+    their normalisation and whitening means, the filter product at float32
+    ``HIGHEST``, the two-sided rectifier and the sum pools, in VMEM. Made
+    by a fused program from the members it replaces
+    (:meth:`Convolver.device_absorb`), never by hand; its output is theirs
+    to float associativity.
+
+    It keeps the convolution's scope, ``ks.conv_featurize``, and counts the
+    images its kernel takes on ``conv.images_pooled_in_kernel``. Over a
+    mesh each device runs the kernel on its own rows (``shard_map`` over
+    the ``data`` axis). It is made only for images the kernel has a plan
+    for (:meth:`absorbing`); other images raise.
+    """
+
+    device_scope = Convolver.device_scope
+    rows_counter = "conv.images_pooled_in_kernel"
+
+    def __init__(self, conv: Convolver, rectifier: SymmetricRectifier,
+                 pooler: Pooler, vectorize: bool):
+        self.conv = conv
+        self.rectifier = rectifier
+        self.pooler = pooler
+        self.vectorize = vectorize
+
+    @classmethod
+    def absorbing(cls, conv: Convolver, successors) -> Optional[tuple]:
+        """``(members taken, the member)`` where the members after ``conv``
+        are, in order, a ``SymmetricRectifier``, a ``Pooler`` with
+        ``pool_function="sum"`` and no pixel function, and optionally an
+        ``ImageVectorizer``; the filters are float32; the Pallas kernels
+        are on (``pallas_ops.pallas_enabled``: the TPU); and the kernel has
+        a plan for the images the convolution declares. Else None."""
+        from keystone_tpu.ops import pallas_ops
+        from keystone_tpu.ops.images.core import ImageVectorizer
+        from keystone_tpu.ops.pallas_images import conv_pool_plan
+
+        after = list(successors[:3])
+        if len(after) < 2 or type(after[0]) is not SymmetricRectifier:
+            return None
+        pooler = after[1]
+        if (type(pooler) is not Pooler or pooler.pool_function != "sum"
+                or pooler.pixel_function is not None):
+            return None
+        if conv.filters.dtype != jnp.float32 or not pallas_ops.pallas_enabled():
+            return None
+        plan = conv_pool_plan((conv.img_x, conv.img_y, conv.img_channels),
+                              conv.filters.shape[0], conv.patch_size,
+                              pooler.stride, pooler.pool_size)
+        if plan is None:
+            return None
+        vectorize = len(after) > 2 and type(after[2]) is ImageVectorizer
+        return 2 + vectorize, cls(conv, after[0], pooler, vectorize)
+
+    def apply(self, img):
+        batch, single = _as_batch(img)
+        out = self.device_fn()(batch)
+        return out[0] if single else out
+
+    def device_operands(self):
+        conv_key, params = self.conv.device_operands()
+        rect_key, _ = self.rectifier.device_operands()
+        return (conv_key, rect_key, (self.pooler.stride, self.pooler.pool_size),
+                self.vectorize), params
+
+    @staticmethod
+    def device_apply(static_key, params, X):
+        from jax.sharding import PartitionSpec as P
+
+        from keystone_tpu.ops.pallas_images import axis_pools, conv_pool_features
+        from keystone_tpu.parallel import mesh as mesh_lib
+
+        conv_key, (max_val, alpha), (stride, pool_size), vectorize = static_key
+        patch_size, normalize_patches, var_constant = conv_key
+        filters, means = params
+        images = jnp.asarray(X, jnp.float32)
+        n, side_x, side_y, _ = images.shape
+        args = (images, filters) if means is None else (images, filters, means)
+        features = functools.partial(
+            conv_pool_features, patch_size=patch_size, stride=stride,
+            pool_size=pool_size, normalize_patches=normalize_patches,
+            var_constant=var_constant, max_val=max_val, alpha=alpha)
+        # a Pallas call is not partitioned: over a mesh each device runs it
+        # on its own rows
+        mesh = jax.typeof(images).sharding.mesh
+        rows = mesh_lib.axis_size(mesh, mesh_lib.DATA_AXIS)
+        if rows > 1 and n % rows == 0:
+            features = mesh_lib.shard_map(
+                features, mesh,
+                in_specs=(P(mesh_lib.DATA_AXIS),) + (P(),) * (len(args) - 1),
+                out_specs=P(mesh_lib.DATA_AXIS), check_vma=False)
+        out = features(*args)  # raises where the kernel has no plan for these images
+        if vectorize:
+            return out
+        npx = len(axis_pools(side_x - patch_size + 1, stride, pool_size)[1])
+        npy = len(axis_pools(side_y - patch_size + 1, stride, pool_size)[1])
+        return out.reshape(n, npx, npy, -1)

@@ -37,6 +37,7 @@ from keystone_tpu.ops.images.conv import (
     Convolver,
     Pooler,
     SymmetricRectifier,
+    conv_form,
     normalize_patch_rows,
 )
 from keystone_tpu.ops.images.core import (
@@ -182,17 +183,19 @@ def _convolver(filters, whitener, image_size: int = 32) -> Convolver:
     )
 
 
-def _conv_featurizer(conv: Convolver, config: CifarConfig) -> Pipeline:
+def _conv_members(conv: Convolver, config: CifarConfig) -> list:
     """Convolver → SymmetricRectifier → Pooler(sum) → vectorize."""
-    return (
-        conv.to_pipeline()
-        .and_then(SymmetricRectifier(alpha=config.alpha))
-        .and_then(
-            Pooler(config.pool_stride, config.pool_size, pool_function="sum")
-        )
-        .and_then(ImageVectorizer())
-        .and_then(Cacher())
-    )
+    return [conv, SymmetricRectifier(alpha=config.alpha),
+            Pooler(config.pool_stride, config.pool_size, pool_function="sum"),
+            ImageVectorizer()]
+
+
+def _conv_featurizer(conv: Convolver, config: CifarConfig) -> Pipeline:
+    """:func:`_conv_members` as one chain, its features cached."""
+    pipeline = conv.to_pipeline()
+    for member in _conv_members(conv, config)[1:]:
+        pipeline = pipeline.and_then(member)
+    return pipeline.and_then(Cacher())
 
 
 def build_random_patch(config: CifarConfig, images: Dataset, labels: Dataset,
@@ -211,8 +214,18 @@ def build_random_patch(config: CifarConfig, images: Dataset, labels: Dataset,
         filters, whitener = sample_whitened_filters(images, config)
         image_size = int(images.array.shape[1])
         conv = _convolver(filters, whitener, image_size)
-        span.set(features=pooled_features(config, image_size),
-                 image_batch=conv.device_row_batch())
+        # the form the fused featurize program takes for these members (the
+        # rule fusion applies), and the images it takes at a time: a grid
+        # step of the kernel, or a step of the XLA program's loop
+        form = conv_form(_conv_members(conv, config))
+        if form == "pallas_pool":
+            from keystone_tpu.ops import pallas_images
+
+            image_batch = pallas_images.LANES
+        else:
+            image_batch = conv.device_row_batch()
+        span.set(features=pooled_features(config, image_size), conv_form=form,
+                 image_batch=image_batch)
         return _conv_featurizer(conv, config).and_then(StandardScaler(), images).and_then(
             BlockLeastSquaresEstimator(
                 config.block_size, 1, config.lam if lam is None else lam),

@@ -230,10 +230,31 @@ def _member_form(member, combine: bool = False) -> Tuple[tuple, tuple]:
     return (type(member), static_key), tuple(params)
 
 
+def absorbed(members) -> list:
+    """A chain as its program runs it: where a member offers to take the
+    members after it (``device_absorb(successors)`` -> ``(how many, the
+    member that runs them all with it)``, or None), the offer stands in
+    for them — the convolution's Pallas kernel
+    (``ops/images/conv.PooledConvolution``). A chain with no such offer
+    is returned as it is, so its program and kept key do not change."""
+    runs, i = [], 0
+    while i < len(members):
+        offer = getattr(members[i], "device_absorb", None)
+        taken = offer(members[i + 1:]) if callable(offer) else None
+        if taken is None:
+            runs.append(members[i])
+            i += 1
+        else:
+            count, member = taken
+            runs.append(member)
+            i += 1 + count
+    return runs
+
+
 def chain_operands(members) -> Tuple[tuple, tuple]:
     """A chain of members as ``(identities, params)``, the arguments of
-    :func:`chain_apply`."""
-    forms = [_member_form(m) for m in members]
+    :func:`chain_apply`: of the chain as it runs (:func:`absorbed`)."""
+    forms = [_member_form(m) for m in absorbed(members)]
     return tuple(i for i, _ in forms), tuple(p for _, p in forms)
 
 
@@ -284,6 +305,13 @@ def _row_batch(members) -> Optional[int]:
     return min(asks) if asks else None
 
 
+def _counters(members) -> list:
+    """The counter tracks the members count their rows on
+    (``rows_counter``), each once."""
+    return list(dict.fromkeys(m.rows_counter for m in members
+                              if getattr(m, "rows_counter", None)))
+
+
 def _scope(members) -> str:
     """The name scope of a fused program: the first member's
     ``device_scope`` where one names it, else ``ks.featurize``."""
@@ -326,11 +354,15 @@ class _FusedTransformer(Transformer):
     def _program_form(self) -> Tuple[Callable, tuple, tuple]:
         raise NotImplementedError
 
+    def _runs(self) -> list:
+        """The members as the program runs them (:func:`absorbed`)."""
+        raise NotImplementedError
+
     def _build_composed(self) -> None:
         apply, static_key, params = self._program_form()
         self._operands = ((apply, static_key), params)
-        members = fused_members(self)
-        rows, scope = _row_batch(members), _scope(members)
+        runs = self._runs()
+        rows, scope = _row_batch(runs), _scope(runs)
 
         def build():
             def composed(params, X):
@@ -342,6 +374,9 @@ class _FusedTransformer(Transformer):
             return jax.jit(composed)
 
         program, self._how = _kept_program((apply, static_key, rows, scope), build)
+        # a member may count the rows its program takes (``rows_counter``),
+        # and so may one that runs members absorbed into it
+        self._counted = _counters(fused_members(self) + runs)
         self._program = program  # jitted ``program(params, X)``: lower it to read it
         self._composed = lambda X: program(params, X)
 
@@ -358,7 +393,7 @@ class _FusedTransformer(Transformer):
     # members and rebuild the composition on load.
     def __getstate__(self):
         state = self.__dict__.copy()
-        for derived in ("_composed", "_operands", "_how", "_program"):
+        for derived in ("_composed", "_operands", "_how", "_program", "_counted"):
             state.pop(derived, None)
         return state
 
@@ -393,6 +428,9 @@ class FusedBatchTransformer(_FusedTransformer):
     def _program_form(self):
         return _compose_form([self.members])
 
+    def _runs(self) -> list:
+        return absorbed(self.members)
+
     @property
     def label(self) -> str:
         return "Fused[" + " > ".join(m.label for m in self.members) + "]"
@@ -407,13 +445,10 @@ class FusedBatchTransformer(_FusedTransformer):
             for m in self.members:
                 data = m.batch_apply(data)
             return data
-        # a member may count the rows its program takes (``rows_counter``)
-        counted = [m.rows_counter for m in self.members
-                   if getattr(m, "rows_counter", None)]
-        if counted:
+        if self._counted:
             from keystone_tpu import obs
 
-            for name in counted:
+            for name in self._counted:
                 obs.counter_track(name, data.n)
         return data.map_batch(self._composed)
 
@@ -489,6 +524,9 @@ class FusedGatherTransformer(_FusedTransformer):
         self.branches = [list(b) for b in branches]
         self.combiner = combiner
         self._build_composed()
+
+    def _runs(self) -> list:
+        return [m for br in self.branches for m in absorbed(br)] + [self.combiner]
 
     def _program_form(self):
         # Shape-specialized lowering first: a gather of

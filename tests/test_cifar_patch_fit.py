@@ -119,7 +119,8 @@ def test_the_build_says_what_it_built_and_the_featurize_counts_its_images(toy):
     builds = [s["args"] for s in tracer.spans("pipeline.build")]
     assert len(builds) == 2
     assert builds[0] == {"entry": "random_patch", "filters": 16, "patches_sampled": 256,
-                         "features": 128, "image_batch": builds[0]["image_batch"]}
+                         "features": 128, "image_batch": builds[0]["image_batch"],
+                         "conv_form": "xla"}  # the Pallas kernels are off on the CPU
     assert builds[0]["image_batch"] >= IMAGES  # a toy set is one batch
     counted = [e["value"] for e in tracer.events
                if e.get("type") == "counter" and e["name"] == "conv.images_featurized"]

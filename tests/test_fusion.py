@@ -549,7 +549,12 @@ def _contract_cases():
     toy size except for ONE array of 1.2 MB where the node owns arrays
     (so a constant in its lowered program cannot hide): name -> (node,
     X)."""
-    from keystone_tpu.ops.images.conv import Convolver, Pooler, SymmetricRectifier
+    from keystone_tpu.ops.images.conv import (
+        Convolver,
+        PooledConvolution,
+        Pooler,
+        SymmetricRectifier,
+    )
     from keystone_tpu.ops.images.core import GrayScaler, ImageVectorizer, PixelScaler
     from keystone_tpu.ops.learning.block import BlockLinearMapper
     from keystone_tpu.ops.learning.linear import LinearMapper
@@ -581,6 +586,13 @@ def _contract_cases():
             Convolver(f32(6400, 48), 6, 5, 3,
                       whitener=ZCAWhitener(np.eye(48, dtype=np.float32), f32(48))),
             images),
+        "pooled_convolution": (  # the Pallas kernel, interpreted here
+            PooledConvolution(
+                Convolver(f32(2800, 108), 8, 8, 3,
+                          whitener=ZCAWhitener(np.eye(108, dtype=np.float32), f32(108))),
+                SymmetricRectifier(alpha=0.1), Pooler(2, 2, pool_function="sum"),
+                vectorize=True),
+            np.abs(f32(2, 8, 8, 3))),
         "pooler": (Pooler(2, 2, pixel_function=jnp.abs, pool_function="max"), images),
         "symmetric_rectifier": (SymmetricRectifier(alpha=0.1), images),
         "matrix_vectorizer": (MatrixVectorizer(), f32(3, 4, 5)),
@@ -636,7 +648,8 @@ def test_operand_form_is_the_nodes_one_device_form(name):
         rtol=1e-4, atol=1e-4)
     owned = [a for a in jax.tree_util.tree_leaves(params) if a.nbytes > 1 << 20]
     assert bool(owned) == (name in {
-        "cosine", "random_sign", "convolver", "linear", "linear_intercept_scaler",
+        "cosine", "random_sign", "convolver", "pooled_convolution", "linear",
+        "linear_intercept_scaler",
         "block", "block_intercept_scalers", "fused_chain", "fused_gather"})
     text = jax.jit(apply, static_argnums=0).lower(static_key, params, X).as_text()
     assert len(text) < 1 << 20
@@ -673,7 +686,7 @@ def test_device_fn_is_derived_and_cannot_be_overridden():
     offering = {c for c in found
                 if {"device_operands", "device_combine_operands"} & set(vars(c))}
     covered = {c for node, _ in _contract_cases().values() for c in type(node).__mro__}
-    assert len(offering) == 19 and offering <= covered, offering - covered
+    assert len(offering) == 20 and offering <= covered, offering - covered
 
     for name, form in [("device_fn", "device_operands"),
                        ("device_combine_fn", "device_combine_operands")]:

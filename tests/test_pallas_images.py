@@ -1,13 +1,6 @@
-"""Fused Pallas patch/conv featurizer parity tests (interpret mode on CPU).
-
-Pins the in-kernel im2col column order, the (d−1)-denominator patch
-normalization, whitening-mean subtraction and the filter GEMM against the
-XLA path in ops/images/conv.py through the Pallas interpreter (tolerance
-1e-5: the fused and XLA paths associate the mean/variance reductions
-differently). Mosaic refuses this kernel on the chip, so Convolver does not
-dispatch it (ops/pallas_images.py docstring); these tests keep the kernel
-as the reference for its rewrite.
-"""
+"""The convolution's XLA path and dtype contract, and the featurize's
+FLOP model. The Pallas kernel that takes a convolution with its rectifier
+and sum pools is tested in tests/test_conv_pool_kernel.py."""
 
 import numpy as np
 import jax.numpy as jnp
@@ -39,83 +32,16 @@ def _xla_reference(images, filters, means=None, *, patch_size,
 
 
 class TestConvFeaturizeKernel:
-    @pytest.mark.parametrize("normalize", [True, False])
-    def test_matches_xla_path(self, normalize):
-        images = rng.normal(size=(3, 12, 10, 3)).astype(np.float32)
-        filters = rng.normal(size=(5, 5 * 5 * 3)).astype(np.float32)
-        got = pi.conv_featurize(
-            images, filters, patch_size=5,
-            normalize_patches=normalize, interpret=True,
-        )
-        want = _xla_reference(
-            images, filters, patch_size=5, normalize_patches=normalize,
-        )
-        assert got.shape == (3, 8, 6, 5)
-        np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-5)
-
-    def test_whitening_means_subtracted(self):
-        images = rng.normal(size=(2, 9, 9, 2)).astype(np.float32)
-        filters = rng.normal(size=(4, 3 * 3 * 2)).astype(np.float32)
-        means = rng.normal(size=(3 * 3 * 2,)).astype(np.float32)
-        got = pi.conv_featurize(
-            images, filters, means, patch_size=3, interpret=True,
-        )
-        want = _xla_reference(images, filters, means, patch_size=3)
-        np.testing.assert_allclose(np.asarray(got), want, rtol=1e-5, atol=1e-5)
-
-    def test_column_order_is_px_py_c_row_major(self):
-        # One-hot filters read individual patch columns back out: filter j
-        # must select patch coordinate (px, py, c) with index
-        # (px·p + py)·C + c — the pack_filters contract.
-        p, C = 2, 3
-        d = p * p * C
-        images = rng.normal(size=(1, 4, 4, C)).astype(np.float32)
-        filters = np.eye(d, dtype=np.float32)  # (d, d) one-hot bank
-        got = np.asarray(
-            pi.conv_featurize(
-                images, filters, patch_size=p,
-                normalize_patches=False, interpret=True,
-            )
-        )
-        for px in range(p):
-            for py in range(p):
-                for c in range(C):
-                    j = (px * p + py) * C + c
-                    np.testing.assert_allclose(
-                        got[0, :, :, j],
-                        images[0, px:px + 3, py:py + 3, c],
-                        rtol=1e-6,
-                    )
-
-    def test_fold_composition_gram_accumulates(self):
-        # Fold-level composition: featurizing the stream chunk-by-chunk and
-        # accumulating Fᵀ F must equal the whole-batch gram — the exact
-        # shape of the bench row's featurize-then-solve fold.
-        images = rng.normal(size=(8, 8, 8, 3)).astype(np.float32)
-        filters = rng.normal(size=(6, 3 * 3 * 3)).astype(np.float32)
-
-        def feats(batch):
-            f = pi.conv_featurize(
-                batch, filters, patch_size=3, interpret=True,
-            )
-            return np.asarray(f).reshape(batch.shape[0], -1)
-
-        whole = feats(images)
-        gram_whole = whole.T @ whole
-        gram_folded = np.zeros_like(gram_whole)
-        for lo in range(0, 8, 3):  # ragged final chunk on purpose
-            gram_folded += (lambda f: f.T @ f)(feats(images[lo:lo + 3]))
-        np.testing.assert_allclose(gram_folded, gram_whole, rtol=1e-4, atol=1e-4)
-
     def test_flop_model(self):
         assert pi.conv_featurize_flops(2, 3, 4, 5, 6) == 2.0 * 2 * 3 * 4 * 5 * 6
 
 
 class TestConvolverTakesTheXlaPath:
     def test_kernel_is_not_dispatched(self, monkeypatch):
-        """Mosaic refuses the fused kernel on the chip (module docstring),
-        so Convolver never dispatches it — not even with kernels forced
-        on — and the XLA path is the stated path."""
+        """A convolution alone never dispatches a kernel — not even with
+        kernels forced on: the XLA path is its stated path (a fused chain
+        with a rectifier and sum pools takes ``conv_pool``:
+        tests/test_conv_pool_kernel.py)."""
         from keystone_tpu.ops import pallas_ops
 
         monkeypatch.setenv("KEYSTONE_PALLAS", "1")
